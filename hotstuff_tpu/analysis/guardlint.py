@@ -4,7 +4,7 @@ The verify engine's wedge protection rests on ONE structural invariant:
 no engine-side code may block unboundedly on a staged device launch —
 every dispatch/fetch future wait must route through the guard's
 deadline helper (``VerifyEngine._guarded`` / ``LaunchGuard.call``), so
-a hung tunneled device call becomes a declared wedge plus the
+a hung device call becomes a declared wedge plus the
 degradation ladder, never a parked engine thread with every queued
 consensus verify behind it.  The type system cannot hold that
 invariant; this checker holds it mechanically.

@@ -230,9 +230,9 @@ def main(argv=None):
     p.add_argument("--timeout", type=int, default=1_000)
     p.add_argument("--duration", type=int, default=30, help="seconds")
     p.add_argument("--sidecar-host-crypto", action="store_true",
-                   help="run the sidecar with --host-crypto (no device; "
-                        "also the automatic fallback when the device "
-                        "sidecar never becomes ready)")
+                   help="run the sidecar with --host-crypto (no device); "
+                        "only ever this explicit choice — a device "
+                        "sidecar that never becomes ready fails the run")
     p.add_argument("--tpu-sidecar", action="store_true",
                    help="route QC verification through the TPU sidecar")
     p.add_argument("--sidecar-mesh", type=int, default=0, metavar="N",

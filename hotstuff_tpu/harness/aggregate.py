@@ -71,7 +71,7 @@ class Result:
         self.mean_latency = mean_latency
         self.std_tps = std_tps
         self.std_latency = std_latency
-        # Repeatability (VERDICT r5 "do this" #4): how many same-settings
+        # Repeatability (round-5 review, item 4): how many same-settings
         # runs this mean±stdev aggregates — a band over one run is a
         # point estimate wearing a costume, and the artifacts must say
         # which one they are quoting.
@@ -240,7 +240,7 @@ class LogAggregator:
             organized[key].sort(key=lambda x: x[0])
         return "robustness", organized
 
-    # -- repeatability bands (VERDICT r5 "do this" #4) -----------------------
+    # -- repeatability bands (round-5 review, item 4) -------------------------
 
     def bands(self, min_runs: int = 2) -> list:
         """Per-setup repeatability bands from multi-run same-settings

@@ -18,6 +18,15 @@ class BenchError(Exception):
         self.cause = error
 
 
+def log_tail(path, lines=40):
+    """The last ``lines`` lines of a log file, for an error message."""
+    try:
+        with open(path, "r", errors="replace") as f:
+            return "".join(f.readlines()[-lines:])
+    except OSError as e:
+        return f"<unreadable: {e}>"
+
+
 class PathMaker:
     @staticmethod
     def binary_path():

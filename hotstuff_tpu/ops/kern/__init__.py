@@ -32,7 +32,10 @@ CPU story: each kernel selects ``interpret=`` off the backend at trace
 time (ops/kern/backend.interpret_default) — on anything but a TPU the
 kernels run through the Pallas interpreter, so tier-1 stays
 CPU-runnable and the property sweeps exercise the exact kernel bodies a
-TPU would compile.  Every pallas_call is wrapped in its own ``jax.jit``
+TPU compiles.  That all three DO compile under Mosaic is pinned by
+tests/test_tpu_compile.py (a described v5e, no chip attached); on a
+chip the route never falls back to lax — a kernel Mosaic refuses fails
+the launch.  None of them has run on a chip yet.  Every pallas_call is wrapped in its own ``jax.jit``
 so the per-call-site trace cost is paid once per shape, not once per
 call site (~0.4 s/site -> ~4 ms/site measured; the verify program has
 hundreds of mul sites).

@@ -104,17 +104,57 @@ def conv32(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
                    preferred_element_type=jnp.float32).astype(jnp.int32)
 
 
-def f_mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """a * b mod p, weak in / weak out — field25519.mul fused: one
-    conv, the wrap-38 fold (lane j += 38 * lane j+32), four parallel
-    carry steps.  Same op sequence, same carry counts: bit-identical."""
-    lane = lane_iota(a.shape)
-    acc = conv32(a, b)
+def conv32_const(a: jnp.ndarray, digits: list[int]) -> jnp.ndarray:
+    """Schoolbook product of padded (rows, 128) limb rows by a STATIC
+    limb vector: coefficient j = sum_i a_i * digits[j - i] lands in
+    lane j.  ONE dot against the 128 x 128 Toeplitz matrix of the
+    digits, synthesized in-kernel from iotas and scalar selects.
+
+    Why not conv32(a, const_row(...)): a row built only from a lane
+    iota is replicated along sublanes, and Mosaic ABORTS the compiling
+    process relaying out the outer product's broadcast of such a value
+    (``array.h:480 Check failed: limits[i] <= dim(i) (32 vs. 1)`` on
+    jax 0.9.0 / libtpu 0.0.34 for v5e) — a crash, not an exception.
+    The Toeplitz form never broadcasts the constant, and is one
+    (rows, 128) x (128, 128) MXU pass instead of a 1024-wide one.
+
+    Exactness: a_i < 2^9, digits < 2^8, 32 terms — sums < 2^22, exact
+    in f32 at HIGHEST precision (the conv32 argument)."""
+    i = jax.lax.broadcasted_iota(jnp.int32, (NLANES, NLANES), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (NLANES, NLANES), 1)
+    diff = jnp.where(i < NLIMBS, j - i, -1)   # lanes >= 32 of a: ignored
+    toeplitz = jnp.zeros((NLANES, NLANES), jnp.float32)
+    for k, d in enumerate(digits):
+        if d:
+            toeplitz = jnp.where(diff == k, float(d), toeplitz)
+    return jnp.dot(a.astype(jnp.float32), toeplitz,
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def _fold_carry(acc: jnp.ndarray) -> jnp.ndarray:
+    """63 product coefficients -> weak limbs: the wrap-38 fold (lane j
+    += 38 * lane j+32) and four parallel carry steps — the tail of
+    field25519.mul, same carry counts."""
+    lane = lane_iota(acc.shape)
     folded = acc + 38 * jnp.roll(acc, -NLIMBS, axis=-1)
     x = jnp.where(lane < NLIMBS, folded, 0)
     for _ in range(4):
         x = carry_step(x)
     return x
+
+
+def f_mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """a * b mod p, weak in / weak out — field25519.mul fused: one
+    conv, the wrap-38 fold, four parallel carry steps.  Same op
+    sequence, same carry counts: bit-identical."""
+    return _fold_carry(conv32(a, b))
+
+
+def f_mul_const(a: jnp.ndarray, digits: list[int]) -> jnp.ndarray:
+    """f_mul by a static field constant (conv32_const says why the
+    constant is not a const_row operand of f_mul)."""
+    return _fold_carry(conv32_const(a, digits))
 
 
 def f_add(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -142,8 +182,7 @@ def f_neg(a: jnp.ndarray) -> jnp.ndarray:
 def to_cached(p):
     """(x, y, z, t) -> cached (y+x, y-x, z, 2d*t) — ed25519.to_cached_t."""
     x, y, z, t = p
-    k2d = const_row(lane_iota(t.shape), _K2D_DIGITS)
-    return (f_add(y, x), f_sub(y, x), z, f_mul(t, k2d))
+    return (f_add(y, x), f_sub(y, x), z, f_mul_const(t, _K2D_DIGITS))
 
 
 def add_cached(p, qc):

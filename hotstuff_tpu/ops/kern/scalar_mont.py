@@ -70,18 +70,16 @@ def _mont_kernel(a_ref, b_ref, o_ref):
     a = a_ref[:]
     b = b_ref[:]
     lane = FK.lane_iota(a.shape)
-    l_row = FK.const_row(lane, _L_DIGITS)
-    lprime_row = FK.const_row(lane, _LPRIME_DIGITS)
     # T = a * b, canonical 64 bytes.
     t = _carry_bytes(FK.conv32(a, b), 64)
     # m = (T mod R) * L' mod R: coefficients at lane >= 32 carry weight
     # >= 2^256 == 0 (mod R) — dropped BEFORE the carry, like the lax
     # slice; the carry's own final out is dropped for the same reason.
     t_lo = jnp.where(lane < FK.NLIMBS, t, 0)
-    m_coeffs = FK.conv32(t_lo, lprime_row)
+    m_coeffs = FK.conv32_const(t_lo, _LPRIME_DIGITS)
     m = _carry_bytes(jnp.where(lane < FK.NLIMBS, m_coeffs, 0), FK.NLIMBS)
     # U = T + m*L < 2RL: 64 canonical bytes; U/R is the high lane slice.
-    u = _carry_bytes(FK.conv32(m, l_row) + t, 64)
+    u = _carry_bytes(FK.conv32_const(m, _L_DIGITS) + t, 64)
     hi = jnp.pad(u[..., FK.NLIMBS:64],
                  [(0, 0)] * (u.ndim - 1) + [(0, FK.NLANES - FK.NLIMBS)])
     o_ref[:] = _cond_sub_l(hi)

@@ -268,7 +268,8 @@ def test_scan_and_shard_map_bodies_are_hot():
 WIRE_FILES = (wirecheck.PROTOCOL, wirecheck.SIDECAR_CLIENT,
               wirecheck.CRYPTO_HPP, wirecheck.FIELD25519,
               wirecheck.INTMATH, wirecheck.FIELD381, wirecheck.BLS12381,
-              wirecheck.TXSIGN, wirecheck.TX_FRAME_HPP)
+              wirecheck.TXSIGN, wirecheck.TX_FRAME_HPP,
+              wirecheck.DAGWIRE, wirecheck.MEMPOOL_MSG_HPP)
 
 
 @pytest.fixture()
@@ -646,7 +647,7 @@ def test_timing_rule_fires_between_timer_reads():
         def stage(fn, x):
             t0 = time.perf_counter()
             out = fn(x)
-            out.block_until_ready()      # lies through the tunnel
+            out.block_until_ready()      # not the repo's fence
             return time.perf_counter() - t0
         """)
     assert rules(findings) == {"block-until-ready-in-timing"}

@@ -379,7 +379,7 @@ def test_local_bench_boot_flags_carry_chaos_and_sizing():
 
 def test_local_bench_boot_flags_carry_mesh():
     """--sidecar-mesh N boots the sidecar with --mesh N and the sharded
-    one-MSM warmup; a host-crypto degrade drops both (no device, no
+    one-MSM warmup; a host-crypto boot carries neither (no device, no
     mesh)."""
     from hotstuff_tpu.harness.config import BenchParameters
     from hotstuff_tpu.harness.local import LocalBench

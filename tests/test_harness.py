@@ -101,7 +101,7 @@ def test_node_parameters_validate_pacemaker_knobs():
 
 def test_aggregate_quotes_runs_and_bands(tmp_path, monkeypatch):
     """Multi-run same-settings result files aggregate into a band that
-    SAYS how many runs back it (VERDICT r5 "do this" #4): the plot-file
+    SAYS how many runs back it (round-5 review, item 4): the plot-file
     grammar keeps its frozen TPS prefix, matrix cells carry the run
     count, and bands() lists every repeated configuration."""
     from hotstuff_tpu.harness.aggregate import LogAggregator, Result
@@ -311,22 +311,27 @@ def test_bench_parameters_validation():
         })
 
 
-def test_node_parameters_chain_depth():
-    """chain_depth: absent -> fine (2-chain default); 3 -> fine; 4 -> error
-    (native/src/consensus/config.hpp accepts only 2 or 3)."""
-    import pytest
+@pytest.mark.parametrize("depth,ok", [(2, True), (3, True), (4, True),
+                                      (8, True), (1, False), (9, False),
+                                      ("3", False)])
+def test_node_parameters_chain_depth(depth, ok):
+    """chain_depth is an int in [2, 8] — the range harness/config.py and
+    native/src/consensus/config.hpp both accept (absent = the 2-chain
+    default); anything else is rejected before a node boots."""
+    from hotstuff_tpu.harness.config import ConfigError
 
     data = NodeParameters.default().json
-    data["consensus"]["chain_depth"] = 3
-    NodeParameters(dict(data))
-    data["consensus"]["chain_depth"] = 4
-    with pytest.raises(Exception):
+    data["consensus"]["chain_depth"] = depth
+    if ok:
         NodeParameters(dict(data))
+    else:
+        with pytest.raises(ConfigError):
+            NodeParameters(dict(data))
 
 
 # ---------------------------------------------------------------------------
-# Sidecar lifecycle (round-3 verdict: a failed readiness wait leaked a hung
-# sidecar process; the device sidecar must degrade to host crypto)
+# Sidecar lifecycle (a failed readiness wait once leaked a hung sidecar
+# process; a device sidecar that does not come up must fail the run)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.skipif(
@@ -358,45 +363,74 @@ def test_kill_nodes_sweeps_orphaned_sidecar():
             proc.kill()
 
 
-def test_sidecar_boot_degrades_to_host_crypto():
-    """Readiness failure on the device sidecar kills it and reboots with
-    --host-crypto; a second failure propagates."""
+@pytest.fixture
+def no_sidecar_listening(tmp_path, monkeypatch):
+    """Every readiness probe is refused, and the sidecar logs live under
+    tmp_path."""
+    from hotstuff_tpu.harness.utils import PathMaker
+    from hotstuff_tpu.sidecar.client import SidecarClient
+
+    monkeypatch.setattr(PathMaker, "logs_path",
+                        staticmethod(lambda: str(tmp_path)))
+
+    def refuse(self):
+        raise ConnectionRefusedError("nothing listening")
+
+    monkeypatch.setattr(SidecarClient, "__enter__", refuse)
+
+
+@pytest.mark.parametrize("fleet", [0, 2])
+def test_sidecar_boot_never_degrades_to_host_crypto(no_sidecar_listening,
+                                                    fleet):
+    """A device sidecar (or fleet member) that never becomes ready is a
+    BenchError carrying the tail of its log — never a silent reboot
+    with --host-crypto, which would hand back a run that did not
+    measure the device path."""
     from hotstuff_tpu.harness.local import LocalBench
     from hotstuff_tpu.harness.utils import BenchError
 
     bench = LocalBench.__new__(LocalBench)
     bench.scheme = "ed25519"
-    bench._degraded = False
     bench.nodes = 4
     bench.rate = 1000
     bench.fault_plan = None
-    booted, waits, kills = [], [], []
-    bench._background_run = \
-        lambda cmd, log, append=False: booted.append(cmd)
-    bench._kill_nodes = lambda: kills.append(True)
+    bench.sidecar_fleet = fleet
+    bench._sidecar_deadline_s = lambda host_crypto: 0
+    booted = []
 
-    def wait(deadline_s):
-        waits.append(deadline_s)
-        if len(waits) == 1:
-            raise BenchError("not ready", TimeoutError())
+    def background_run(cmd, log, append=False):
+        booted.append(cmd)
+        with open(log, "w") as f:
+            f.write("boot line\nRuntimeError: no chip for this member\n")
 
-    bench._wait_sidecar_ready = wait
-    bench._boot_sidecar(host_crypto=False)
-    assert len(booted) == 2
-    assert "--host-crypto" not in booted[0]
-    assert "--host-crypto" in booted[1]
-    assert kills, "failed sidecar was not killed before the retry"
+    bench._background_run = background_run
+    with pytest.raises(BenchError) as err:
+        bench._boot_sidecars(host_crypto=False)
+    assert "no chip for this member" in err.value.message
+    assert len(booted) == max(1, fleet)
+    assert not any("--host-crypto" in cmd for cmd in booted)
 
-    # host-crypto boot that still fails must raise, after a sweep
-    booted.clear(), waits.clear(), kills.clear()
 
-    def wait_fail(deadline_s):
-        raise BenchError("still not ready", TimeoutError())
+def test_sidecar_wait_stops_when_the_process_exited(no_sidecar_listening):
+    """An exited sidecar (no chip to bind, a warmup verdict of false)
+    ends the readiness wait at once, with its exit code and log tail —
+    not after the whole compile budget."""
+    import subprocess
+    import sys
 
-    bench._wait_sidecar_ready = wait_fail
-    with pytest.raises(BenchError):
-        bench._boot_sidecar(host_crypto=True)
-    assert kills
+    from hotstuff_tpu.harness.local import LocalBench
+    from hotstuff_tpu.harness.utils import BenchError, PathMaker
+
+    with open(PathMaker.sidecar_log_file(), "w") as f:
+        f.write("RuntimeError: warmup verify returned false\n")
+    proc = subprocess.Popen([sys.executable, "-c", "raise SystemExit(3)"])
+    proc.wait(timeout=30)
+    bench = LocalBench.__new__(LocalBench)
+    bench._sidecar_procs = {0: proc}
+    with pytest.raises(BenchError) as err:
+        bench._wait_sidecar_ready(deadline_s=3600)
+    assert "exited with code 3" in err.value.message
+    assert "warmup verify returned false" in err.value.message
 
 
 # ---------------------------------------------------------------------------

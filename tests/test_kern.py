@@ -28,7 +28,8 @@ from hotstuff_tpu.ops import kern  # noqa: E402
 from hotstuff_tpu.ops import scalar25519 as S  # noqa: E402
 from hotstuff_tpu.utils.intmath import L, P  # noqa: E402
 from hotstuff_tpu.utils.xla_cache import (  # noqa: E402
-    CompileManifest, CompileTracker, kernel_fingerprint)
+    CompileManifest, CompileTracker, configure_xla_cache,
+    kernel_fingerprint, repo_root)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -297,6 +298,26 @@ class TestMsmWindowChunk:
 # ---------------------------------------------------------------------------
 # Compile manifest + tracker (the persistent-cache accounting)
 # ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("env_dir", ["/somewhere/else/xla", None])
+def test_configure_xla_cache_one_location(monkeypatch, env_dir):
+    """One cache, placeable from outside: with JAX_COMPILATION_CACHE_DIR
+    set jax already persists there, so nothing is configured in code
+    and that path is what the manifest records; unset, the cache is the
+    fixed in-checkout directory."""
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        fixed = os.path.join(repo_root(), "results", "compile_cache", "xla")
+        assert configure_xla_cache() == fixed
+        assert updates == [("jax_compilation_cache_dir", fixed)]
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert configure_xla_cache() == env_dir
+        assert updates == []
 
 
 class TestCompileManifest:

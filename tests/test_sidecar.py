@@ -92,6 +92,81 @@ def test_sidecar_empty_batch(server):
         assert client.verify_batch([], [], []) == []
 
 
+@pytest.fixture
+def served(monkeypatch, tmp_path):
+    """Boot the real ``serve()`` entry in a thread; yields ``boot(**kw)
+    -> (errors, servers)``: what serve() raised, and every SidecarServer
+    the boot constructed (none = the socket never bound)."""
+    from hotstuff_tpu.sidecar import service
+
+    monkeypatch.setenv("HOTSTUFF_TPU_COMPILE_MANIFEST",
+                       str(tmp_path / "manifest.json"))
+    servers, threads = [], []
+
+    class Recording(service.SidecarServer):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            servers.append(self)
+
+    monkeypatch.setattr(service, "SidecarServer", Recording)
+
+    def boot(**kw):
+        ready = threading.Event()
+        errors = []
+
+        def run():
+            try:
+                service.serve(port=0, ready_event=ready, **kw)
+            except Exception as e:  # noqa: BLE001 — handed to the test
+                errors.append(e)
+                ready.set()
+
+        t = threading.Thread(target=run, daemon=True)
+        t.start()
+        threads.append(t)
+        assert ready.wait(600), "serve() never became ready"
+        return errors, servers
+
+    yield boot
+    for srv in servers:
+        srv.shutdown()
+    for t in threads:
+        t.join(timeout=30)
+        assert not t.is_alive()
+
+
+@pytest.mark.parametrize("use_host", [False, True])
+def test_stats_name_the_device_only_on_a_jax_boot(served, use_host):
+    """OP_STATS ``device``: which devices the engine launches on, as jax
+    reports them — so a client can tell a CPU sidecar from a TPU one.
+    Absent under --host-crypto, which holds no device."""
+    errors, servers = served(use_host=use_host, warm_max=8)
+    assert not errors
+    with SidecarClient(port=servers[0].server_address[1]) as client:
+        stats = client.stats()
+    if use_host:
+        assert "device" not in stats
+    else:
+        import jax
+
+        assert stats["device"] == {
+            "platform": "cpu", "kind": jax.devices()[0].device_kind,
+            "count": 1}
+        assert stats["compile"]["misses"] + stats["compile"]["hits"] == 1
+
+
+def test_false_warmup_verdict_aborts_serve_before_bind(served, monkeypatch):
+    """A device leg that judges a VALID signature false must not serve:
+    serve() raises out of the warmup, before any socket exists."""
+    monkeypatch.setattr(
+        VerifyEngine, "_verify",
+        lambda self, msgs, pks, sigs: np.zeros(len(msgs), bool))
+    errors, servers = served(warm_max=8)
+    assert len(errors) == 1 and isinstance(errors[0], RuntimeError)
+    assert "returned false for a valid signature" in str(errors[0])
+    assert servers == []
+
+
 @pytest.fixture(scope="module")
 def host_server():
     """Host-crypto server: exercises the BLS ops without device compiles."""

@@ -132,10 +132,13 @@ def test_admission_derate_tracks_overlap_with_hysteresis_counts():
     for _ in range(64):
         adm.note_pack(0.01, hidden=False)
     assert adm.snapshot()["derate"]["engagements"] == 2
-    # Partial overlap lands between the floor and 1.
-    for _ in range(32):
+    # Partial overlap lands between the floor and 1: 16 hidden of the
+    # 64-pack window is overlap 0.25, half way up to OVERLAP_KNEE (32
+    # hidden would sit exactly ON the knee, which is full cap).
+    for _ in range(16):
         adm.note_pack(0.01, hidden=True)
-    assert DERATE_FLOOR < adm.bulk_derate() < 1.0
+    assert adm.bulk_derate() == pytest.approx(
+        DERATE_FLOOR + (1.0 - DERATE_FLOOR) * 0.5)
 
 
 def test_admission_overlap_window_is_time_bounded():

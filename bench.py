@@ -981,14 +981,14 @@ def trace_headline_probe() -> dict:
     # the launch-level device span tagged ctxs — the protocol-v5 schema
     # the live sidecar emits.
     sidecar_spans = [
-        {"stage": "admit", "t": 1785751201.005, "dur_ms": 0.0, "rid": 1,
-         "cls": "latency", "ctx": "aaa="},
-        {"stage": "queue", "t": 1785751201.01, "dur_ms": 1.5, "rid": 1,
-         "cls": "latency", "ctx": "aaa="},
-        {"stage": "device", "t": 1785751201.02, "dur_ms": 18.0, "rid": 1,
-         "ctxs": ["aaa="]},
-        {"stage": "reply", "t": 1785751201.04, "dur_ms": 0.0, "rid": 1,
-         "cls": "latency", "ctx": "aaa="},
+        {"stage": "request", "t0": 1785751201.005, "t": 1785751201.04,
+         "dur_ms": 35.0, "rid": 1, "cls": "latency", "ctx": "aaa="},
+        {"stage": "queue", "t0": 1785751201.0085, "t": 1785751201.01,
+         "dur_ms": 1.5, "rid": 1, "cls": "latency", "ctx": "aaa="},
+        {"stage": "device", "t0": 1785751201.002, "t": 1785751201.02,
+         "dur_ms": 18.0, "rid": 1, "ctxs": ["aaa="]},
+        {"stage": "reply", "t0": 1785751201.0395, "t": 1785751201.04,
+         "dur_ms": 0.5, "rid": 1, "cls": "latency", "ctx": "aaa="},
     ]
     join, joined = obstrace.join_blocks(
         traces, obstrace.chain_spans(sidecar_spans))

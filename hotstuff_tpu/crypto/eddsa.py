@@ -23,6 +23,7 @@ import hashlib
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.spans import NO_LAUNCH
 from ..ops import ed25519 as E
 from ..utils.intmath import next_pow2  # noqa: F401  (re-export: THE
 # bucketing rule — sharded_verify and the sidecar import it from here)
@@ -184,7 +185,8 @@ def verify_batch_submit(msgs, pks, sigs, *, pad: bool = True):
     return verify_batch_pack(msgs, pks, sigs, pad=pad)()
 
 
-def verify_batch_pack(msgs, pks, sigs, *, pad: bool = True):
+def verify_batch_pack(msgs, pks, sigs, *, pad: bool = True,
+                      trace=NO_LAUNCH):
     """Pack stage of a batch verify: ALL host-side work — byte decode,
     canonicality checks, SHA-512 challenges, bucket padding and the
     h2d transfer — happens here, on the caller's thread.  The returned
@@ -197,13 +199,18 @@ def verify_batch_pack(msgs, pks, sigs, *, pad: bool = True):
     dispatch + fetch cost.  ``verify_batch_submit`` is the two-stage
     wrapper (pack + dispatch in one call) for callers without a pack
     thread.
+
+    ``trace`` is the engine's tracer bound to this launch
+    (``obs.spans.LaunchScope``; default the null scope): the staging and
+    fetch steps below write their ``h2d`` / ``fetch_wait`` / ``d2h``
+    spans through it.
     """
     n = len(msgs)
     if n == 0:
         return lambda: (lambda: np.zeros((0,), bool))
     prep = prepare_batch(msgs, pks, sigs)
     host_ok = prep["host_ok"]
-    dispatch_rows = _pack_rows(prep["packed"], n, pad)
+    dispatch_rows = _pack_rows(prep["packed"], n, pad, trace)
 
     def dispatch():
         fetch_rows = dispatch_rows()
@@ -212,12 +219,27 @@ def verify_batch_pack(msgs, pks, sigs, *, pad: bool = True):
     return dispatch
 
 
-def _pack_rows(packed: np.ndarray, n: int, pad: bool):
+def _fetch(dev, trace) -> np.ndarray:
+    """``np.asarray(dev)``; on a traced launch as two spans:
+    ``fetch_wait`` until the result is ready (the wait ``np.asarray``
+    would block on anyway) and ``d2h`` for the copy itself."""
+    if not trace.enabled:
+        return np.asarray(dev)
+    with trace.stage("fetch_wait"):
+        dev.block_until_ready()
+    with trace.stage("d2h") as tags:
+        out = np.asarray(dev)
+        tags["bytes"] = out.nbytes
+    return out
+
+
+def _pack_rows(packed: np.ndarray, n: int, pad: bool, trace=NO_LAUNCH):
     """(n, 128) prepared rows -> staged device input; returns
     dispatch() -> fetch() -> (n,) bool mask.  Single home of the
     bucket/pad/chunk policy shared by the eager, submit and pack paths.
     The h2d transfer happens HERE (pack stage); the donated program
-    launch happens inside dispatch()."""
+    launch happens inside dispatch().  The ``h2d`` span is the host time
+    of the staging call, not the wire time of the copy."""
     # The launches below DONATE their input buffer; forcing host-side
     # rows here guarantees each jnp.asarray is a fresh device copy, so a
     # caller's (possibly device-resident) array is never invalidated.
@@ -226,11 +248,14 @@ def _pack_rows(packed: np.ndarray, n: int, pad: bool):
         m = _bucket(n) if pad else n
         if m != n:
             packed = np.pad(packed, [(0, m - n), (0, 0)])
-        dev_in = jnp.asarray(packed)
+        with trace.stage("h2d") as tags:
+            dev_in = jnp.asarray(packed)
+            if tags is not None:
+                tags["bytes"] = packed.nbytes
 
         def dispatch():
             dev = E.verify_packed_donated(dev_in)
-            return lambda: np.asarray(dev)[:n]
+            return lambda: _fetch(dev, trace)[:n]
 
         return dispatch
     g = -(-n // MAX_SUBBATCH)
@@ -239,11 +264,14 @@ def _pack_rows(packed: np.ndarray, n: int, pad: bool):
     m = g * MAX_SUBBATCH
     if m != n:
         packed = np.pad(packed, [(0, m - n), (0, 0)])
-    dev_in = jnp.asarray(packed.reshape(g, MAX_SUBBATCH, 128))
+    with trace.stage("h2d") as tags:
+        dev_in = jnp.asarray(packed.reshape(g, MAX_SUBBATCH, 128))
+        if tags is not None:
+            tags["bytes"] = packed.nbytes
 
     def dispatch():
         dev = E.verify_packed_chunked_donated(dev_in)
-        return lambda: np.asarray(dev).reshape(m)[:n]
+        return lambda: _fetch(dev, trace).reshape(m)[:n]
 
     return dispatch
 
@@ -353,12 +381,14 @@ def verify_batch_rlc_submit(msgs, pks, sigs, *, pad: bool = True,
 
 
 def verify_batch_rlc_pack(msgs, pks, sigs, *, pad: bool = True,
-                          on_bisect=None):
+                          on_bisect=None, trace=NO_LAUNCH):
     """Pack stage of the combined RLC check: host preparation, the
     coefficient PRF, bucket padding and the h2d transfers happen here;
     the returned ``dispatch()`` fires the donated one-MSM program and
     returns the ``fetch`` described on :func:`verify_batch_rlc_submit`
-    (which is this function's two-stage wrapper)."""
+    (which is this function's two-stage wrapper).  ``trace`` as on
+    :func:`verify_batch_pack`; a failed combined check adds one
+    ``bisect`` span around the whole resolution."""
     n = len(msgs)
     if n == 0:
         return lambda: (lambda: np.zeros((0,), bool))
@@ -368,7 +398,7 @@ def verify_batch_rlc_pack(msgs, pks, sigs, *, pad: bool = True,
     m = len(idx)
     if m < RLC_MIN_MSM or m > MAX_SUBBATCH:
         rows = np.ascontiguousarray(packed[idx])
-        dispatch_rows = _pack_rows(rows, m, pad) if m else None
+        dispatch_rows = _pack_rows(rows, m, pad, trace) if m else None
 
         def dispatch_degenerate():
             fetch_rows = dispatch_rows() if dispatch_rows else None
@@ -390,21 +420,27 @@ def verify_batch_rlc_pack(msgs, pks, sigs, *, pad: bool = True,
         rows = np.pad(rows, [(0, bucket - m), (0, 0)])
     # Fresh host arrays -> fresh device buffers; the launch donates arg 0
     # (same discipline as _pack_rows).
-    dev_rows, dev_z = jnp.asarray(rows), jnp.asarray(z)
+    with trace.stage("h2d") as tags:
+        dev_rows, dev_z = jnp.asarray(rows), jnp.asarray(z)
+        if tags is not None:
+            tags["bytes"] = rows.nbytes + z.nbytes
 
     def dispatch():
         dev = E.verify_rlc_packed_donated(dev_rows, dev_z)
 
         def fetch():
             mask = np.zeros(n, bool)
-            if bool(np.asarray(dev)):
+            if bool(_fetch(dev, trace)):
                 mask[idx] = True
                 return mask
             if on_bisect is not None:
                 on_bisect()
             mid = m // 2
-            _rlc_resolve(packed, idx[:mid], mask, b"L", pad)
-            _rlc_resolve(packed, idx[mid:], mask, b"R", pad)
+            with trace.stage("bisect") as tags:
+                launches = _rlc_resolve(packed, idx[:mid], mask, b"L", pad) \
+                    + _rlc_resolve(packed, idx[mid:], mask, b"R", pad)
+                if tags is not None:
+                    tags["launches"] = launches
             return mask
 
         return fetch
@@ -413,16 +449,17 @@ def verify_batch_rlc_pack(msgs, pks, sigs, *, pad: bool = True,
 
 
 def _rlc_resolve(packed: np.ndarray, indices: np.ndarray,
-                 out: np.ndarray, salt: bytes, pad: bool) -> None:
+                 out: np.ndarray, salt: bytes, pad: bool) -> int:
     """Resolve ``out[indices]`` for host-canonical rows: combined RLC
-    check first, bisection on failure, per-signature floor."""
+    check first, bisection on failure, per-signature floor.  Returns the
+    number of device programs it ran."""
     n = len(indices)
     if n == 0:
-        return
+        return 0
     if n < RLC_MIN_MSM or n > MAX_SUBBATCH:
         rows = np.ascontiguousarray(packed[indices])
         out[indices] = verify_prepared_rows(rows, n, pad=pad)
-        return
+        return 1
     rows = np.ascontiguousarray(packed[indices])
     m = _bucket(n) if pad else n
     z = np.zeros((m, 32), np.uint8)
@@ -435,7 +472,7 @@ def _rlc_resolve(packed: np.ndarray, indices: np.ndarray,
         jnp.asarray(rows), jnp.asarray(z))))
     if ok:
         out[indices] = True
-        return
+        return 1
     mid = n // 2
-    _rlc_resolve(packed, indices[:mid], out, salt + b"L", pad)
-    _rlc_resolve(packed, indices[mid:], out, salt + b"R", pad)
+    return 1 + _rlc_resolve(packed, indices[:mid], out, salt + b"L", pad) \
+        + _rlc_resolve(packed, indices[mid:], out, salt + b"R", pad)

@@ -223,6 +223,10 @@ class LocalBench:
                 sleep(0.5)
 
     def _kill_nodes(self):
+        sidecars = [p for p in (getattr(self, "_sidecar_proc", None),
+                                *getattr(self, "_sidecar_procs", {}).values())
+                    if p is not None]
+        leaving = []
         for _, proc in self._procs:
             try:
                 pgid = os.getpgid(proc.pid)
@@ -231,8 +235,22 @@ class LocalBench:
                 # once continued; always chase with SIGCONT so teardown
                 # can never leave a stopped orphan holding the ports.
                 os.killpg(pgid, signal.SIGCONT)
+                if any(proc is p for p in sidecars):
+                    leaving.append((proc, pgid))
             except (ProcessLookupError, PermissionError):
                 pass
+        # A sidecar keeps its spans in memory and writes them out as
+        # SIGTERM ends serve() (obs/spans.py): give its group a bounded
+        # moment before the -9 sweep below.
+        deadline = monotonic() + 3.0
+        for proc, pgid in leaving:
+            while monotonic() < deadline:
+                proc.poll()
+                try:
+                    os.killpg(pgid, 0)
+                except (ProcessLookupError, PermissionError):
+                    break
+                sleep(0.05)
         self._procs = []
         self._node_procs = {}
         self._sidecar_proc = None

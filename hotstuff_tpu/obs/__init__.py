@@ -5,10 +5,11 @@ The repo's perf and chaos claims used to rest on end-of-run aggregates
 package makes every claim attributable to a *place in the pipeline*:
 
 ``spans``
-    The span record schema and the :class:`Tracer` JSONL writer the
-    sidecar threads its hot-path stages through (admit -> queue ->
-    pack -> dispatch -> device -> reply), tagged with the request rid
-    and scheduler class.  Timestamps always come from the injected
+    The span record schema and the buffered :class:`Tracer` the
+    sidecar threads its hot path through: one span tree a request
+    (request -> decode, queue, reply) and one a launch (pack, dispatch,
+    device -> h2d, fetch_wait, d2h, bisect), on one clock, ``t`` the
+    END and ``t0`` the start.  Timestamps always come from the injected
     clock — graftlint's ``unclosed-span`` checker enforces both that
     and the begin/end pairing discipline.
 

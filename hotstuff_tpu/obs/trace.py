@@ -138,10 +138,13 @@ def probe_host_offset(run_fn, host: str, clock, samples: int = 5) -> float:
 
 def apply_offset(spans, offset_s: float):
     """Shift spans from a skewed host onto the reference clock
-    (``local = remote - offset``); returns new dicts, input untouched."""
+    (``local = remote - offset``); returns new dicts, input untouched.
+    Node TRACE records have the one stamp ``t``; sidecar spans have
+    ``t0`` too."""
     if not offset_s:
         return list(spans)
-    return [dict(s, t=s["t"] - offset_s) for s in spans]
+    return [dict(s, t=s["t"] - offset_s, t0=s["t0"] - offset_s)
+            if "t0" in s else dict(s, t=s["t"] - offset_s) for s in spans]
 
 
 # -- stitching + critical path -----------------------------------------------
@@ -203,7 +206,7 @@ def critical_path(traces: dict) -> dict:
 def chain_spans(sidecar_spans) -> dict:
     """ctx-tagged sidecar spans -> ``{block_digest_b64: [spans]}``.
 
-    The sidecar tags per-request spans (admit/queue/reply) with ``ctx``
+    The sidecar tags per-request spans (request/queue/reply) with ``ctx``
     and per-launch spans (pack/dispatch/device) with a ``ctxs`` list —
     both carry the protocol-v5 context tag as the SAME base64 string the
     C++ node logs in ``block=`` (common/bytes.hpp base64_encode), so the
@@ -306,11 +309,14 @@ def chrome_trace(traces: dict, sidecar_spans=(), joined=None) -> dict:
     ``tid`` round (cat ``sidecar``, block in args), so opening a block
     in Perfetto shows device time as a sub-segment of its verify
     segment.  The flat sidecar-process timeline is kept too — it still
-    carries the un-joined spans (bulk traffic, zero-tag requests)."""
+    carries the un-joined spans (bulk traffic, zero-tag requests).
+
+    A sidecar span is drawn from its START, ``t0``: its ``t`` is its END
+    (obs/spans.py); a chain is emitted in start order."""
     events = []
     t0_candidates = [min(stages.values()) for stages in traces.values()
                      if stages]
-    t0_candidates += [s["t"] for s in sidecar_spans]
+    t0_candidates += [s["t0"] for s in sidecar_spans]
     t_base = min(t0_candidates) if t0_candidates else 0.0
 
     def us(t):
@@ -330,11 +336,11 @@ def chrome_trace(traces: dict, sidecar_spans=(), joined=None) -> dict:
                 })
     for (block, rnd), chain in sorted((joined or {}).items(),
                                       key=lambda kv: kv[0][1]):
-        for s in chain:
+        for s in sorted(chain, key=lambda s: s["t0"]):
             events.append({
                 "name": f"sidecar:{s['stage']}", "ph": "X",
                 "cat": "sidecar",
-                "ts": us(s["t"]),
+                "ts": us(s["t0"]),
                 "dur": max(0.0, float(s.get("dur_ms") or 0.0) * 1e3),
                 "pid": _PID_CONSENSUS, "tid": rnd,
                 "args": {"block": block, "round": rnd,
@@ -342,10 +348,10 @@ def chrome_trace(traces: dict, sidecar_spans=(), joined=None) -> dict:
             })
     for s in sidecar_spans:
         args = {k: v for k, v in s.items()
-                if k not in ("stage", "t", "dur_ms")}
+                if k not in ("stage", "t", "t0", "dur_ms")}
         events.append({
             "name": s["stage"], "ph": "X", "cat": "sidecar",
-            "ts": us(s["t"]),
+            "ts": us(s["t0"]),
             "dur": max(0.0, float(s.get("dur_ms") or 0.0) * 1e3),
             "pid": _PID_SIDECAR, "tid": 0,
             "args": args,

@@ -340,6 +340,13 @@ class GuardStats:
         self.poison_host_verified = 0
         self.last_reboot_wall_s = 0.0
         self.last_rewarm_wall_s = 0.0
+        self.calls = 0          # guarded calls that came back in time
+        self.hop_s_total = 0.0  # their thread hops (LaunchGuard.call)
+
+    def note_hop(self, hop_s: float):
+        with self._lock:
+            self.calls += 1
+            self.hop_s_total += hop_s
 
     def note_wedge(self, key: str):
         with self._lock:
@@ -392,12 +399,14 @@ class GuardStats:
                 "poison_host_verified": self.poison_host_verified,
                 "last_reboot_wall_s": round(self.last_reboot_wall_s, 3),
                 "last_rewarm_wall_s": round(self.last_rewarm_wall_s, 3),
+                "calls": self.calls,
+                "hop_s_total": round(self.hop_s_total, 6),
             }
 
 
 class _GuardedCall:
     __slots__ = ("key", "deadline_s", "started_at", "done", "result",
-                 "exc", "wedged")
+                 "exc", "wedged", "thunk_s")
 
     def __init__(self, key: str, deadline_s: float, started_at: float):
         self.key = key
@@ -407,6 +416,7 @@ class _GuardedCall:
         self.result = None
         self.exc = None
         self.wedged = False
+        self.thunk_s = 0.0      # the thunk's own time on its launch thread
 
 
 class LaunchGuard:
@@ -438,6 +448,7 @@ class LaunchGuard:
         self._clock = clock
         self._lock = threading.Lock()
         self._calls: set = set()
+        self._last = threading.local()  # per calling thread: last_hop_s
         self._stop = threading.Event()
         self._monitor = threading.Thread(
             target=self._monitor_loop, daemon=True, name="guard-monitor")
@@ -445,6 +456,12 @@ class LaunchGuard:
 
     def close(self):
         self._stop.set()
+
+    @property
+    def last_hop_s(self) -> float:
+        """Thread hop of the calling thread's last :meth:`call` that
+        came back in time (0.0 before the first)."""
+        return getattr(self._last, "hop_s", 0.0)
 
     # -- supervision ---------------------------------------------------------
 
@@ -464,10 +481,12 @@ class LaunchGuard:
                     call.done.set()
 
     def _run_call(self, call: _GuardedCall, thunk):
+        t0 = self._clock()
         try:
             call.result = thunk()
         except BaseException as e:  # noqa: BLE001 — re-raised by call()
             call.exc = e
+        call.thunk_s = self._clock() - t0
         if call.wedged:
             # Late completion of an abandoned launch: the engine already
             # answered its batch from the ladder — the result is
@@ -482,7 +501,12 @@ class LaunchGuard:
         """Run ``thunk`` on a disposable launch thread under the shape's
         deadline; returns its result, re-raises its exception, or
         raises :class:`WedgedLaunch` when the monitor declared an
-        overrun (the thread is abandoned — crash-only)."""
+        overrun (the thread is abandoned — crash-only).
+
+        The call measures its own thread hop — its wall time minus the
+        thunk's time on the launch thread: thread start, the two
+        wake-ups — into :attr:`last_hop_s` (the calling thread's last
+        call) and the snapshot's ``hop_s_total`` / ``calls``."""
         call = _GuardedCall(key, self.deadlines.deadline_s(key),
                             self._clock())
         with self._lock:
@@ -507,7 +531,11 @@ class LaunchGuard:
         if call.wedged:
             self.stats.note_wedge(key)
             raise WedgedLaunch(key, call.deadline_s)
-        self.deadlines.observe(key, self._clock() - call.started_at)
+        wall = self._clock() - call.started_at
+        hop = max(0.0, wall - call.thunk_s)
+        self._last.hop_s = hop
+        self.stats.note_hop(hop)
+        self.deadlines.observe(key, wall)
         if call.exc is not None:
             raise call.exc
         return call.result

@@ -115,7 +115,7 @@ _TENANT_OK = frozenset(
 # the BLS trace-parity work, OP_BLS_VERIFY_VOTES / OP_BLS_VERIFY_MULTI —
 # requests may carry a 32-byte CONTEXT TAG between the fixed header and
 # the records: the block digest whose certificate this verify serves.
-# The sidecar tags its admit/queue/pack/dispatch/device/reply spans with
+# The sidecar tags its request/queue/pack/dispatch/device/reply spans with
 # it, which is what lets obs/trace.py nest the sidecar stage chain
 # (device time included) inside that block's verify segment in
 # logs/trace.json — for scheme=bls runs exactly like EdDSA ones.

@@ -67,6 +67,7 @@ import threading
 from collections import deque
 from time import monotonic
 
+from ..obs.spans import NO_LAUNCH
 from . import sched as vsched
 from .guard import BusyReply, WedgedLaunch
 
@@ -296,13 +297,15 @@ class RingSlot:
 
 class _Flight:
     """An armed launch in the device pipeline: the slot + generation it
-    was armed under, the batch, and the guarded fetch closure."""
+    was armed under, the batch, and the guarded fetch closure.
+    ``traced`` is None, or (launch scope, dispatch end on the tracer's
+    clock, dispatch guard hop) for the flight's ``device`` span."""
 
     __slots__ = ("slot", "generation", "batch", "fetch", "key",
-                 "dispatched_at", "dispatch_s", "sigs")
+                 "dispatched_at", "dispatch_s", "sigs", "traced")
 
     def __init__(self, slot, generation, batch, fetch, key,
-                 dispatched_at, dispatch_s, sigs):
+                 dispatched_at, dispatch_s, sigs, traced=None):
         self.slot = slot
         self.generation = generation
         self.batch = batch
@@ -311,6 +314,7 @@ class _Flight:
         self.dispatched_at = dispatched_at
         self.dispatch_s = dispatch_s
         self.sigs = sigs
+        self.traced = traced
 
 
 class CadenceRing:
@@ -446,20 +450,22 @@ class CadenceRing:
         drain (a QC aggregate is one check — nothing to keep resident);
         Ed25519 quotas arm a ring slot."""
         engine = self.engine
-        engine._trace_queue_waits(launch)
+        scope = engine._begin_launch(launch)
         if launch.kind == "bls":
             while self._pending:
                 self._collect_oldest()
                 if not self.enabled:
                     return False
             (item,) = launch.items
+            tags = {"lid": scope.lid, "parent": None} \
+                if scope.enabled else {}
             with engine._tracer.span("device", kind="bls",
-                                     rid=item.request.request_id):
+                                     rid=item.request.request_id, **tags):
                 engine._execute_bls(item)
             return True
-        return self._arm(launch)
+        return self._arm(launch, scope)
 
-    def _arm(self, launch) -> bool:
+    def _arm(self, launch, scope=NO_LAUNCH) -> bool:
         """Arm the next ring slot with this launch: stream the batch
         through the engine's pack worker, dispatch under the ``tick:``
         guard class, and tag the flight with the slot's new
@@ -471,12 +477,14 @@ class CadenceRing:
         self._next_slot = (self._next_slot + 1) % max(RingDepth.DEPTHS)
         slot.generation += 1
         gen = slot.generation
-        fut = engine._pack_pool.submit(engine._pack, batch)
+        fut = engine._pack_pool.submit(engine._pack, batch, scope)
         t0 = self._clock()
+        span_t0 = scope.now() if scope.enabled else 0.0
         try:
             # pack wait + device dispatch under one guarded deadline —
             # the identical discipline to the staged _dispatch_one.
-            fetch = engine._guarded(key, lambda: fut.result()())
+            with scope.annotate("dispatch"):
+                fetch = engine._guarded(key, lambda: fut.result()())
         except WedgedLaunch:
             slot.generation += 1  # invalidate before the ladder answers
             self._fallback(batch, key, stage="dispatch")
@@ -486,20 +494,21 @@ class CadenceRing:
             slot.generation += 1
             for p in batch:
                 p.reply_fn([False] * len(p.request.msgs))
-            engine._trace_replies(batch)
             return False
         dispatch_s = self._clock() - t0
         sigs = sum(len(p.request.msgs) for p in batch)
+        # One schema with the staged loop: the engine's own helpers
+        # write the ring's dispatch and device spans.
+        traced = (scope,) + engine._trace_dispatch(
+            scope, batch, span_t0, cadence=True) if scope.enabled else None
         self._pending.append(_Flight(slot, gen, batch, fetch, key,
-                                     self._clock(), dispatch_s, sigs))
+                                     self._clock(), dispatch_s, sigs,
+                                     traced))
         fill = launch.items[len(launch.items) - launch.fill_count:]
         now = self._clock()
         self.stats.note_dispatch(
             sigs, sum(len(p.request.msgs) for p in fill),
             [now - p.enqueued_at for p in batch])
-        if engine._tracer.enabled:
-            engine._tracer.event("dispatch", reqs=len(batch),
-                                 cadence=True)
         return True
 
     def _collect_oldest(self) -> None:
@@ -525,7 +534,6 @@ class CadenceRing:
             fl.slot.generation += 1
             for p in fl.batch:
                 p.reply_fn([False] * len(p.request.msgs))
-            engine._trace_replies(fl.batch)
             return
         if fl.generation != fl.slot.generation:
             # Re-armed or expired since dispatch: the verdict is stale
@@ -534,16 +542,15 @@ class CadenceRing:
             return
         wall = self._clock() - fl.dispatched_at
         self.depth.observe(fl.dispatch_s, wall)
-        if engine._tracer.enabled:
-            engine._tracer.event("device", dur_ms=wall * 1e3,
-                                 reqs=len(fl.batch), sigs=fl.sigs,
+        if fl.traced is not None:
+            scope, dispatched_at, hop_s = fl.traced
+            engine._trace_device(scope, fl.batch, dispatched_at, hop_s,
                                  cadence=True)
         off = 0
         for p in fl.batch:
             n = len(p.request.msgs)
             p.reply_fn([bool(b) for b in mask[off:off + n]])
             off += n
-        engine._trace_replies(fl.batch)
 
     # -- expiry / fallback ---------------------------------------------------
 
@@ -584,7 +591,6 @@ class CadenceRing:
             p.reply_fn([bool(ref.verify(pk, m, s))
                         for m, pk, s in zip(p.request.msgs, p.request.pks,
                                             p.request.sigs)])
-        engine._trace_replies(batch)
 
     def _fallback(self, batch, key: str, stage: str) -> None:
         """A cadence launch wedged: ride the engine's existing ladder for

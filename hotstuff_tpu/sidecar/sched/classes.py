@@ -45,20 +45,23 @@ def class_of_opcode(opcode: int) -> str:
 class Pending:
     """One admitted request: the decoded dataclass, its reply callback,
     its class, its tenant (graftfleet: the third scheduling key; the
-    connection's HELLO identity or the default), and the admission
-    timestamp (queue-wait telemetry)."""
+    connection's HELLO identity or the default), the admission
+    timestamp (queue-wait telemetry), and — traced runs only — the
+    request's span bookkeeping (``span``; opaque here, None untraced)."""
 
     __slots__ = ("request", "reply_fn", "cls", "enqueued_at", "is_bls",
-                 "tenant")
+                 "tenant", "span")
 
     def __init__(self, request, reply_fn, cls: str = LATENCY,
-                 is_bls: bool = False, tenant: str | None = None):
+                 is_bls: bool = False, tenant: str | None = None,
+                 span=None):
         from .tenantq import DEFAULT_TENANT
 
         self.request = request
         self.reply_fn = reply_fn
         self.cls = cls
         self.is_bls = is_bls
+        self.span = span
         self.tenant = DEFAULT_TENANT if tenant is None else tenant
         self.enqueued_at = monotonic()
 

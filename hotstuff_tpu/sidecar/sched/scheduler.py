@@ -180,7 +180,8 @@ class Scheduler:
     # -- admission (connection threads) -------------------------------------
 
     def offer(self, request, reply_fn, cls: str = LATENCY,
-              is_bls: bool = False, tenant: str | None = None) -> bool:
+              is_bls: bool = False, tenant: str | None = None,
+              span=None) -> bool:
         """Admit one request; False means queue-full (the caller must
         reply explicitly — nothing was retained; ``retry_after_ms``
         gives the hint the BUSY reply should carry).
@@ -205,7 +206,7 @@ class Scheduler:
         makes unreachable; ``tenant_starvation`` is the proof counter
         the strict parser reads."""
         pending = Pending(request, reply_fn, cls, is_bls=is_bls,
-                          tenant=tenant)
+                          tenant=tenant, span=span)
         adm = self.admission
         with self._cond:
             if cls == BULK:

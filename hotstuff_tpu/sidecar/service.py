@@ -27,8 +27,10 @@ from __future__ import annotations
 import argparse
 import logging
 import queue
+import signal
 import socket
 import socketserver
+import sys
 import threading
 from time import monotonic
 
@@ -36,6 +38,7 @@ import numpy as np
 
 from . import protocol as proto
 from . import sched as vsched
+from ..obs.spans import NO_LAUNCH
 from .guard import BusyReply, WedgedLaunch, bisect_poison
 
 log = logging.getLogger("sidecar")
@@ -83,6 +86,51 @@ def _ctx_tags(batch):
     tags = {_ctx_tag(p.request) for p in batch}
     tags.discard(None)
     return sorted(tags)
+
+
+class _RequestSpan:
+    """One traced request from its frame to its reply (traced runs only):
+    the id its ``request`` root span will carry — handed out when the
+    frame is read, so the ``decode``, ``queue`` and ``reply`` children
+    can name it before it is written — and the stamps the root needs.
+    The connection thread makes it, ``submit()`` carries it to the
+    engine on the Pending, the connection's writer closes it."""
+
+    __slots__ = ("tracer", "id", "rid", "t0", "admitted", "tags")
+
+    def __init__(self, tracer, rid: int, t0: float, id=None,  # noqa: A002
+                 **tags):
+        self.tracer = tracer
+        self.id = id            # None: an embedder's request, no root span
+        self.rid = rid
+        self.t0 = t0
+        self.admitted = t0
+        self.tags = tags
+
+    def child(self, stage: str, t0: float, t: float | None = None, **tags):
+        for key in ("cls", "ctx"):  # a child joins its block as the root does
+            if key in self.tags:
+                tags[key] = self.tags[key]
+        self.tracer.record(stage, t0, t, rid=self.rid, parent=self.id,
+                           **tags)
+
+    def close(self, ok: bool, t: float | None = None):
+        self.tracer.record("request", self.t0, t, id=self.id, rid=self.rid,
+                           parent=None, ok=ok, **self.tags)
+
+    def framed(self, frame: bytes, called: float):
+        """The outbox item of a traced reply: ``(frame, sent)``; the
+        writer calls ``sent(dequeued, t)`` when ``sendall`` returned, and
+        that writes the ``reply`` span (``reply_fn`` called -> sent) and
+        closes the root."""
+        enqueued = self.tracer.now()
+
+        def sent(dequeued: float, t: float):
+            self.child("reply", called, t, bytes=len(frame),
+                       outbox_ms=round((dequeued - enqueued) * 1e3, 3))
+            self.close(True, t)
+
+        return frame, sent
 
 
 class ChaosState:
@@ -218,13 +266,16 @@ class VerifyEngine:
                                        bulk_cap_sigs=bulk_cap,
                                        committee=committee)
         self._use_host = use_host
-        # grafttrace: span emission through every engine stage (admit ->
-        # queue -> pack -> dispatch -> device -> reply), tagged with the
-        # request rid and scheduler class.  The null tracer short-circuits
-        # every call, so the un-traced hot path pays only a method call.
+        # grafttrace: one span tree per request (request -> decode,
+        # queue, reply; the connection handler writes the socket side)
+        # and per launch (pack, dispatch, device -> h2d, fetch_wait, d2h,
+        # bisect), on the tracer's one clock (obs/spans.py).  The null
+        # tracer short-circuits every call; sites that would read its
+        # clock or build tags gate on ``enabled``.
         from ..obs.spans import Tracer
 
         self._tracer = tracer if tracer is not None else Tracer.disabled()
+        self._launches = 0   # launch counter: a launch's ``lid``
         # Device multi-digest pairing programs compile one shape per vote
         # count (minutes each); only counts warmed via _warmup_bls_multi
         # may launch on device — others verify on host so a surprise TC
@@ -316,11 +367,13 @@ class VerifyEngine:
         self._thread.start()
 
     def submit(self, request, reply_fn, cls: str = vsched.LATENCY,
-               is_bls: bool = False, tenant: str | None = None) -> bool:
+               is_bls: bool = False, tenant: str | None = None,
+               span: _RequestSpan | None = None) -> bool:
         """Admit one request into its class queue.  Returns False on
         queue-full — nothing was retained and the CALLER must reply
         (the handler sends the explicit empty-mask backpressure reply);
-        never blocks the calling connection thread."""
+        never blocks the calling connection thread.  ``span`` is the
+        connection's bookkeeping for a traced request (None untraced)."""
         if cls == vsched.BULK and not is_bls:
             # graftingress feed mix: an admission-verify batch carries
             # the pinned ingress ctx tag; everything else on the bulk
@@ -340,18 +393,13 @@ class VerifyEngine:
             if self._guard is not None:
                 self._guard.stats.note_busy()
             return False
-        ok = self._sched.offer(request, reply_fn, cls=cls, is_bls=is_bls,
-                               tenant=tenant)
         if self._tracer.enabled:
-            tags = {}
-            ctx = _ctx_tag(request)
-            if ctx:
-                tags["ctx"] = ctx
-            self._tracer.event("admit", rid=request.request_id, cls=cls,
-                               ok=ok,
-                               n=len(getattr(request, "msgs", ()) or ())
-                               or 1, **tags)
-        return ok
+            now = self._tracer.now()
+            if span is None:
+                span = _RequestSpan(self._tracer, request.request_id, now)
+            span.admitted = now
+        return self._sched.offer(request, reply_fn, cls=cls, is_bls=is_bls,
+                                 tenant=tenant, span=span)
 
     def retry_after_ms(self, cls: str) -> int:
         """Hint for a BUSY reply after a shed of class ``cls`` (the
@@ -501,9 +549,11 @@ class VerifyEngine:
         import collections
         from concurrent import futures as cfut
 
-        packing = collections.deque()   # (batch, Future[dispatch_fn])
-        inflight = collections.deque()  # (batch, fetch_fn,
-                                        #  dispatched_at, guard_key)
+        packing = collections.deque()   # (batch, Future[dispatch_fn],
+                                        #  launch scope)
+        inflight = collections.deque()  # (batch, fetch_fn, guard_key,
+                                        #  launch scope, dispatched_at,
+                                        #  dispatch hop)
         while not self._stopped.is_set():
             # 1) A FINISHED pack moves onto the device whenever there is
             #    dispatch room.  Unfinished packs are waited out in step
@@ -522,7 +572,7 @@ class VerifyEngine:
                 launch = self._sched.next_launch(timeout=0.25) if idle \
                     else self._sched.next_launch(block=False)
                 if launch is not None:
-                    self._trace_queue_waits(launch)
+                    scope = self._begin_launch(launch)
                     # BLS requests run individually (a QC aggregate is
                     # one check; there is nothing to coalesce) on the
                     # same device thread, after the whole Ed25519
@@ -532,7 +582,8 @@ class VerifyEngine:
                         while inflight:
                             self._drain_one(inflight)
                         tags = {}
-                        if self._tracer.enabled:
+                        if scope.enabled:
+                            tags = {"lid": scope.lid, "parent": None}
                             ctx = _ctx_tag(item.request)
                             if ctx:
                                 # v5 context tag: scheme=bls device spans
@@ -554,7 +605,8 @@ class VerifyEngine:
                         continue
                     batch = launch.items
                     packing.append(
-                        (batch, self._pack_pool.submit(self._pack, batch)))
+                        (batch, self._pack_pool.submit(self._pack, batch,
+                                                       scope), scope))
                     continue
                 if idle:
                     continue
@@ -578,30 +630,62 @@ class VerifyEngine:
             self._drain_one(inflight)
         self._pack_pool.shutdown(wait=False)
 
-    def _trace_queue_waits(self, launch):
-        """One ``queue`` span per launched item (duration = admission ->
-        launch assembly, the same wait the OP_STATS reservoirs sample)."""
+    def _begin_launch(self, launch):
+        """Number the launch the scheduler just assembled (its ``lid``)
+        and, when tracing, bind the tracer to it and write one ``queue``
+        span per item (admission -> launch assembly, the same wait the
+        OP_STATS reservoirs sample) naming the ``lid`` it left for.
+        Returns the launch's scope (the null scope untraced)."""
+        self._launches += 1
         if not self._tracer.enabled:
-            return
-        now = monotonic()
+            return NO_LAUNCH
+        scope = self._tracer.launch(self._launches)
+        now = scope.now()
         for p in launch.items:
             tags = {}
             ctx = _ctx_tag(p.request)
             if ctx:
                 tags["ctx"] = ctx
-            self._tracer.event("queue", dur_ms=(now - p.enqueued_at) * 1e3,
-                               rid=p.request.request_id, cls=p.cls, **tags)
+            span = p.span
+            self._tracer.record(
+                "queue", span.admitted if span is not None else now, now,
+                rid=p.request.request_id, cls=p.cls, lid=scope.lid,
+                parent=span.id if span is not None else None, **tags)
+        return scope
 
-    def _trace_replies(self, batch):
-        if not self._tracer.enabled:
-            return
-        for p in batch:
-            tags = {}
-            ctx = _ctx_tag(p.request)
-            if ctx:
-                tags["ctx"] = ctx
-            self._tracer.event("reply", rid=p.request.request_id,
-                               cls=p.cls, **tags)
+    def _last_hop_s(self) -> float:
+        """Thread hop of this thread's last guarded call."""
+        return self._guard.last_hop_s if self._guard is not None else 0.0
+
+    def _trace_dispatch(self, scope, batch, t0: float, **tags):
+        """The ``dispatch`` span of a traced launch, written by the
+        staged loop and the ring alike: the engine took the pack at
+        ``t0`` and ``_guarded`` has just returned the fetch closure
+        (pack-future wait, guard hop and the jitted call included).
+        Returns (now, the dispatch's guard hop in seconds)."""
+        t = scope.now()
+        hop_s = self._last_hop_s()
+        ctxs = _ctx_tags(batch)
+        if ctxs:
+            tags["ctxs"] = ctxs
+        waited = scope.pack_end - t0 if scope.pack_end is not None else 0.0
+        scope.record("dispatch", t0, t, reqs=len(batch),
+                     wait_pack_ms=round(max(0.0, waited) * 1e3, 3),
+                     hop_ms=round(hop_s * 1e3, 3), **tags)
+        return t, hop_s
+
+    def _trace_device(self, scope, batch, t0: float, hop_s: float, **tags):
+        """The ONE ``device`` span of a traced launch (dispatch returned
+        at ``t0`` -> fetch returned, now): it includes the d2h copy,
+        exactly what the engine pays.  ``hop_ms`` is the guard hop of
+        the dispatch (``hop_s``) and of the fetch together."""
+        ctxs = _ctx_tags(batch)
+        if ctxs:
+            tags["ctxs"] = ctxs
+        scope.record(
+            "device", t0, id=scope.device_id, reqs=len(batch),
+            sigs=sum(len(p.request.msgs) for p in batch),
+            hop_ms=round((hop_s + self._last_hop_s()) * 1e3, 3), **tags)
 
     def _guard_key(self, batch) -> str:
         """Launch-shape key for the guard's per-shape deadlines: the
@@ -648,13 +732,15 @@ class VerifyEngine:
 
     def _dispatch_one(self, packing, inflight):
         """Move the oldest staged pack onto the device (engine thread)."""
-        batch, fut = packing.popleft()
+        batch, fut, scope = packing.popleft()
+        t0 = scope.now() if scope.enabled else 0.0
         key = self._guard_key(batch)
         try:
             # wait for pack, then device dispatch — both touch the
             # device (pack stages the h2d transfer), so both run under
             # the one guarded deadline
-            fetch = self._guarded(key, lambda: fut.result()())
+            with scope.annotate("dispatch"):
+                fetch = self._guarded(key, lambda: fut.result()())
         except WedgedLaunch:
             self._wedge_ladder(batch, key, stage="dispatch")
             return
@@ -662,19 +748,14 @@ class VerifyEngine:
             log.exception("verify batch pack/dispatch failed")
             for p in batch:
                 p.reply_fn([False] * len(p.request.msgs))
-            self._trace_replies(batch)
             return
-        if self._tracer.enabled:
-            tags = {}
-            ctxs = _ctx_tags(batch)
-            if ctxs:
-                tags["ctxs"] = ctxs
-            self._tracer.event("dispatch", reqs=len(batch), **tags)
-        inflight.append((batch, fetch, monotonic(), key))
+        dispatched_at, hop_s = self._trace_dispatch(scope, batch, t0) \
+            if scope.enabled else (0.0, 0.0)
+        inflight.append((batch, fetch, key, scope, dispatched_at, hop_s))
         self._inflight_n = len(inflight)
 
     def _drain_one(self, inflight):
-        batch, fetch, dispatched_at, key = inflight.popleft()
+        batch, fetch, key, scope, dispatched_at, hop_s = inflight.popleft()
         self._inflight_n = len(inflight)
         try:
             mask = self._guarded(key, fetch)
@@ -685,25 +766,14 @@ class VerifyEngine:
             log.exception("verify batch failed")
             for p in batch:
                 p.reply_fn([False] * len(p.request.msgs))
-            self._trace_replies(batch)
             return
-        # The device stage spans dispatch -> fetch completion: it
-        # includes the d2h copy, exactly what the engine pays.
-        if self._tracer.enabled:
-            tags = {}
-            ctxs = _ctx_tags(batch)
-            if ctxs:
-                tags["ctxs"] = ctxs
-            self._tracer.event(
-                "device", dur_ms=(monotonic() - dispatched_at) * 1e3,
-                reqs=len(batch),
-                sigs=sum(len(p.request.msgs) for p in batch), **tags)
+        if scope.enabled:
+            self._trace_device(scope, batch, dispatched_at, hop_s)
         off = 0
         for p in batch:
             n = len(p.request.msgs)
             p.reply_fn([bool(b) for b in mask[off:off + n]])
             off += n
-        self._trace_replies(batch)
 
     # -- graftguard: the wedge degradation ladder ---------------------------
 
@@ -749,7 +819,6 @@ class VerifyEngine:
                                             p.request.sigs)]
                 guard.stats.note_host_fallback(len(mask))
                 p.reply_fn(mask)
-            self._trace_replies(batch)
 
         # The host fallback runs OFF the engine thread: a wedged batch
         # at the coalesced cap is tens of seconds of pure-python
@@ -932,13 +1001,16 @@ class VerifyEngine:
         concatenated mask."""
         return self._pack(batch)()
 
-    def _pack(self, batch):
+    def _pack(self, batch, scope=NO_LAUNCH):
         """Host-side pack stage of one coalesced batch (runs on the pack
         worker): byte concat, verdict-cache lookups, in-batch dedup,
         route selection, host preparation and the h2d transfers.  Returns
         ``dispatch() -> fetch()`` — dispatch fires the (donated) device
         program from the engine thread; the host path computes eagerly
-        here instead.
+        here instead.  ``scope`` is the tracer bound to this launch
+        (``_begin_launch``): the ``pack`` span is written here, and the
+        single-chip pack functions write the launch's ``h2d``,
+        ``fetch_wait``, ``d2h`` and ``bisect`` spans through it.
 
         Verdict cache: signature validity is a pure function of the
         (msg, pk, sig) bytes, so records already verified are answered
@@ -950,6 +1022,7 @@ class VerifyEngine:
         engine thread, same dict-read-under-GIL safety as the connection
         threads' fast path; the engine thread stays the only writer.)"""
         t0 = monotonic()
+        span_t0 = scope.now() if scope.enabled else 0.0
         hidden = self._inflight_n > 0  # device busy while we pack
         msgs, pks, sigs = [], [], []
         for p in batch:
@@ -1021,7 +1094,7 @@ class VerifyEngine:
             from ..crypto import eddsa
 
             dispatchers = [eddsa.verify_batch_rlc_pack(
-                m_msgs, m_pks, m_sigs, on_bisect=on_bisect)]
+                m_msgs, m_pks, m_sigs, on_bisect=on_bisect, trace=scope)]
         elif path in (vsched.PATH_RLC_SHARDED, vsched.PATH_LADDER_SHARDED,
                       vsched.PATH_SCAN_SHARDED, vsched.PATH_MESH):
             dispatchers = self._pack_sharded(path, m_msgs, m_pks, m_sigs,
@@ -1047,7 +1120,8 @@ class VerifyEngine:
             step = self._shapes.launch_cap
             dispatchers = [eddsa.verify_batch_pack(m_msgs[i:i + step],
                                                    m_pks[i:i + step],
-                                                   m_sigs[i:i + step])
+                                                   m_sigs[i:i + step],
+                                                   trace=scope)
                            for i in range(0, len(m_msgs), step)]
         if poisoned:
             # Poison lane: quarantined records verify on HOST, eagerly,
@@ -1058,14 +1132,17 @@ class VerifyEngine:
                             for m, pk, s in poisoned])
             dispatchers.append(lambda res=res: (lambda: res))
         stats.note_pack(monotonic() - t0, hidden)
-        if self._tracer.enabled:
+        if scope.enabled:
             pack_tags = {}
             pack_ctxs = _ctx_tags(batch)
             if pack_ctxs:
                 pack_tags["ctxs"] = pack_ctxs
-            self._tracer.event("pack", dur_ms=(monotonic() - t0) * 1e3,
-                               reqs=len(batch), uniq=len(uniq_records),
-                               path=path, hidden=hidden, **pack_tags)
+            scope.pack_end = scope.now()
+            scope.record("pack", span_t0, scope.pack_end,
+                         reqs=len(batch), uniq=len(uniq_records),
+                         path=path, hidden=hidden,
+                         rids=[p.request.request_id for p in batch],
+                         **pack_tags)
 
         def dispatch():
             fetchers = [d() for d in dispatchers]
@@ -1375,23 +1452,38 @@ class VerifyEngine:
 class _Handler(socketserver.BaseRequestHandler):
     """Reader loop per connection; replies go through a dedicated writer
     thread so a client that stops draining its socket stalls only its own
-    connection, never the shared verify-engine thread."""
+    connection, never the shared verify-engine thread.
+
+    Tracing (``engine._tracer`` enabled): every verify/sign request gets
+    a ``request`` root span — last byte of its frame read -> ``sendall``
+    of its reply returned, or the refusal — with ``decode`` and ``reply``
+    children written here and ``queue`` by the engine; its reply rides
+    the outbox as ``(frame, sent)`` (``_RequestSpan.framed``).  Untraced,
+    the outbox carries bare frames."""
 
     def handle(self):
         sock = self.request
         sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         engine: VerifyEngine = self.server.engine  # type: ignore[attr-defined]
-        outbox: "queue.Queue[bytes | None]" = queue.Queue(maxsize=1024)
+        tracer = engine._tracer
+        outbox: "queue.Queue[bytes | tuple | None]" = \
+            queue.Queue(maxsize=1024)
 
         def writer():
             while True:
                 frame = outbox.get()
                 if frame is None:
                     return
+                sent = None
+                if type(frame) is tuple:
+                    frame, sent = frame
+                    dequeued = tracer.now()
                 try:
                     sock.sendall(frame)
                 except OSError:
                     return
+                if sent is not None:
+                    sent(dequeued, tracer.now())
 
         wt = threading.Thread(target=writer, daemon=True,
                               name="sidecar-conn-writer")
@@ -1407,11 +1499,14 @@ class _Handler(socketserver.BaseRequestHandler):
                     payload = proto.read_frame(sock)
                 except (ConnectionError, OSError):
                     return
+                traced = tracer.enabled
+                t_read = tracer.now() if traced else 0.0
                 try:
                     opcode, req = proto.decode_request(payload)
                 except Exception:
                     log.exception("bad frame; closing connection")
                     return
+                t_decoded = tracer.now() if traced else 0.0
                 if opcode == proto.OP_HELLO:
                     # Tenant registration.  The reply echoes the server's
                     # protocol version + the accepted tenant id, so the
@@ -1454,6 +1549,18 @@ class _Handler(socketserver.BaseRequestHandler):
                     outbox.put(proto.encode_reply(
                         opcode, req.request_id, [1]))
                     continue
+                span = None
+                if traced:
+                    tags = {}
+                    ctx = _ctx_tag(req)
+                    if ctx:
+                        tags["ctx"] = ctx
+                    span = _RequestSpan(
+                        tracer, req.request_id, t_read, id=tracer.next_id(),
+                        cls=vsched.class_of_opcode(opcode),
+                        n=len(getattr(req, "msgs", ()) or ()) or 1, **tags)
+                    span.child("decode", t_read, t_decoded,
+                               bytes=len(payload))
                 delay_s = 0.0
                 if chaos is not None:
                     # Scripted misbehavior for verify/sign traffic only
@@ -1470,6 +1577,8 @@ class _Handler(socketserver.BaseRequestHandler):
                         outbox.put(proto.encode_busy_reply(
                             req.request_id, engine.retry_after_ms(
                                 vsched.class_of_opcode(opcode))))
+                        if span is not None:
+                            span.close(False)
                         continue
 
                 def send(frame, _delay=delay_s):
@@ -1500,26 +1609,30 @@ class _Handler(socketserver.BaseRequestHandler):
                 # the device.  Dict reads under the GIL are safe against
                 # the engine thread's insert/evict writes.
                 is_bls = False
+                verdicts = None
                 if opcode in (proto.OP_VERIFY_BATCH, proto.OP_VERIFY_BULK):
                     verdicts = engine.cached_verdicts(req)
-                    if verdicts is not None:
-                        send(proto.encode_reply(
-                            opcode, req.request_id, verdicts))
-                        continue
                 elif opcode in (proto.OP_BLS_VERIFY_AGG,
                                 proto.OP_BLS_VERIFY_VOTES,
                                 proto.OP_BLS_VERIFY_MULTI):
                     is_bls = True
                     verdicts = engine.cached_bls_verdict(req)
-                    if verdicts is not None:
-                        send(proto.encode_reply(
-                            opcode, req.request_id, verdicts))
-                        continue
                 elif opcode == proto.OP_BLS_SIGN:
                     is_bls = True
+                if verdicts is not None:
+                    if span is not None:
+                        span.tags["cached"] = True
+                        called = tracer.now()
+                    frame = proto.encode_reply(
+                        opcode, req.request_id, verdicts)
+                    send(frame if span is None
+                         else span.framed(frame, called))
+                    continue
 
                 def reply(result, _rid=req.request_id, _op=opcode,
-                          _send=send):
+                          _send=send, _span=span):
+                    if _span is not None:
+                        called = _span.tracer.now()
                     if isinstance(result, BusyReply):
                         # graftguard wedge ladder: a bulk request whose
                         # launch wedged gets the honest OP_BUSY with the
@@ -1533,7 +1646,8 @@ class _Handler(socketserver.BaseRequestHandler):
                         frame = proto.encode_reply(
                             _op, _rid, result if result is not None
                             else [False])
-                    _send(frame)
+                    _send(frame if _span is None
+                          else _span.framed(frame, called))
 
                 # Admission is bounded: a full class queue is answered
                 # HERE with an explicit OP_BUSY reply carrying the
@@ -1544,9 +1658,11 @@ class _Handler(socketserver.BaseRequestHandler):
                 # engine.
                 cls = vsched.class_of_opcode(opcode)
                 if not engine.submit(req, reply, cls=cls, is_bls=is_bls,
-                                     tenant=tenant):
+                                     tenant=tenant, span=span):
                     outbox.put(proto.encode_busy_reply(
                         req.request_id, engine.retry_after_ms(cls)))
+                    if span is not None:
+                        span.close(False)
         finally:
             outbox.put(None)
 
@@ -1603,7 +1719,12 @@ def serve(host: str = "127.0.0.1", port: int = 7100,
     if trace_path:
         from ..obs.spans import Tracer
 
-        tracer = Tracer(trace_path)
+        annotation = None
+        if not use_host:
+            # Device boots: the launch-scope stages also go into a
+            # profiler session's host plane, on the trace's own clock.
+            from jax.profiler import TraceAnnotation as annotation
+        tracer = Tracer(trace_path, annotation=annotation)
         log.info("grafttrace span emission -> %s", trace_path)
     # graftguard: chaos state is built BEFORE the engine so the wedge
     # knob can reach the dispatch path, and every boot gets a launch
@@ -1691,6 +1812,7 @@ def serve(host: str = "127.0.0.1", port: int = 7100,
             # did not prove itself.
             engine.stop()
             guard.close()
+            tracker.close()
             raise
         tracker.finish()
         log.info(
@@ -1750,6 +1872,8 @@ def serve(host: str = "127.0.0.1", port: int = 7100,
         if tcp_server is not None:
             tcp_server.shutdown()
             tcp_server.server_close()
+        if tracker is not None:
+            tracker.close()
         if tracer is not None:
             tracer.close()
     return server
@@ -1820,9 +1944,9 @@ def _warmed(engine, key: str, thunk):
     one is attached (device boots) so the manifest hit/miss accounting
     sees every shape; bare otherwise (tests, host mode)."""
     tracker = getattr(engine, "compile_tracker", None)
-    if tracker is not None:
-        return tracker.warm(key, thunk)
-    return thunk()
+    if tracker is None:
+        return thunk()
+    return tracker.warm(key, thunk)
 
 
 def _warm_shapes(engine, start: int, stop: int, label: str):
@@ -2040,6 +2164,19 @@ def _warmup_rlc(engine, warm_max: int = MAX_SUBBATCH):
         n *= 2
 
 
+class _ExitOnSigterm:
+    """``serve()``'s ``ready_event`` for a traced sidecar process: from
+    the moment it serves, SIGTERM (the harness's teardown) ends
+    ``serve()`` through its ``finally``, which writes the buffered spans
+    out, and the process exits 0.  Until then — the whole warm-up, whose
+    compiles would hold a Python-level handler back for tens of seconds —
+    and in every untraced sidecar, SIGTERM keeps its default action: the
+    process dies by the signal at once, as it always did."""
+
+    def set(self):
+        signal.signal(signal.SIGTERM, lambda *_: sys.exit(0))
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--host", default="127.0.0.1")
@@ -2083,10 +2220,12 @@ def main(argv=None):
                          "socket; protocol v6 HELLO frames carry the "
                          "tenant id on either listener")
     ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="append grafttrace JSONL spans (admit/queue/"
-                         "pack/dispatch/device/reply, tagged rid + "
-                         "scheduler class) to PATH; obs/trace.py merges "
-                         "them into the run's trace.json")
+                    help="append grafttrace JSONL spans (one tree a "
+                         "request: request/decode/queue/reply; one a "
+                         "launch: pack/dispatch/device and its children; "
+                         "obs/spans.py) to PATH, written when the sidecar "
+                         "shuts down; obs/trace.py merges them into the "
+                         "run's trace.json")
     ap.add_argument("--cadence", action="store_true",
                     help="run the graftcadence resident verify ring "
                          "(continuous batching: depth-k dispatch at a "
@@ -2113,6 +2252,7 @@ def main(argv=None):
         format="%(asctime)s.%(msecs)03dZ %(levelname)s [%(name)s] %(message)s",
         datefmt="%Y-%m-%dT%H:%M:%S")
     serve(args.host, args.port, mesh_devices=args.mesh or None,
+          ready_event=_ExitOnSigterm() if args.trace else None,
           use_host=args.host_crypto, warm_max=args.warm,
           warm_bls=args.warm_bls, warm_bls_multi=args.warm_bls_multi,
           warm_bulk=args.warm_bulk, warm_rlc=args.warm_rlc,

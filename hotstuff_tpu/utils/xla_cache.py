@@ -26,6 +26,7 @@ import hashlib
 import json
 import logging
 import os
+import threading
 import time
 
 log = logging.getLogger("xla-cache")
@@ -211,12 +212,26 @@ class CompileTracker:
     against a populated cache therefore reports ``misses == 0`` with a
     measurably lower warmup wall time, which is exactly what the
     OP_STATS ``compile`` section (:meth:`snapshot`) and
-    ``scripts/warmup_report.py`` surface.  ``clock`` is injectable for
+    ``scripts/warmup_report.py`` surface.
+
+    What a shape's seconds WERE comes from jax itself: the tracker
+    registers one ``jax.monitoring`` duration listener and, while a shape
+    is inside :meth:`warm`, adds the events to that shape — ``lower_s``
+    (jaxpr tracing + lowering to MLIR) and ``backend_s`` (the backend
+    compile, or the read-back from the persistent cache).  After
+    :meth:`finish` every backend-compile event counts under
+    ``in_service``: a program built while serving, the operator's answer
+    to "which step recompiled".  ``clock`` and ``register`` (what takes
+    the listener; default ``jax.monitoring``'s) are injectable for
     tests."""
+
+    LOWER_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                    "/jax/core/compile/jaxpr_to_mlir_module_duration")
+    BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
 
     def __init__(self, cache_dir: str | None = None,
                  manifest_path: str | None = None,
-                 clock=None, kernel: str | None = None):
+                 clock=None, kernel: str | None = None, register=None):
         self.cache_dir = cache_dir
         self._clock = clock or time.monotonic
         self.kernel = kernel or kernel_fingerprint()
@@ -226,6 +241,46 @@ class CompileTracker:
         self.shapes: dict[str, dict] = {}
         self._t0 = self._clock()
         self._wall_s: float | None = None
+        # Monitoring events arrive on whichever thread compiles.
+        self._events_lock = threading.Lock()
+        self._warming: dict | None = None   # the shape inside warm()
+        self._boot_split = {"lower_s": 0.0, "backend_s": 0.0}
+        self.in_service = {"count": 0, "seconds": 0.0, "last_at": None}
+        self._listens_to_jax = register is None
+        if register is None:
+            from jax import monitoring
+
+            register = monitoring.register_event_duration_secs_listener
+        register(self._on_event)
+
+    def close(self):
+        """Take the listener off ``jax.monitoring`` again (idempotent):
+        a process that boots ``serve()`` more than once keeps one
+        listener a live tracker."""
+        if self._listens_to_jax:
+            from jax import monitoring
+
+            self._listens_to_jax = False
+            monitoring.unregister_event_duration_listener(self._on_event)
+
+    def _on_event(self, event: str, duration: float, **_):
+        """The ``jax.monitoring`` duration listener."""
+        if event == self.BACKEND_EVENT:
+            field = "backend_s"
+        elif event in self.LOWER_EVENTS:
+            field = "lower_s"
+        else:
+            return
+        with self._events_lock:
+            if self._wall_s is not None:
+                if field == "backend_s":
+                    self.in_service["count"] += 1
+                    self.in_service["seconds"] += duration
+                    self.in_service["last_at"] = time.time()
+                return
+            self._boot_split[field] += duration
+            if self._warming is not None:
+                self._warming[field] += duration
 
     def warm(self, key: str, thunk):
         """Run one warmup shape under hit/miss + wall-time accounting;
@@ -238,14 +293,23 @@ class CompileTracker:
         hit = self.manifest.seen(self.kernel, key,
                                  cache_dir=self.cache_dir
                                  if self.cache_dir is not None else "")
+        split = {"lower_s": 0.0, "backend_s": 0.0}
+        with self._events_lock:
+            self._warming = split
         t0 = self._clock()
-        out = thunk()
+        try:
+            out = thunk()
+        finally:
+            with self._events_lock:
+                self._warming = None
         dt = self._clock() - t0
         if hit:
             self.hits += 1
         else:
             self.misses += 1
-        self.shapes[key] = {"s": round(dt, 3), "hit": hit}
+        self.shapes[key] = {"s": round(dt, 3), "hit": hit,
+                            "lower_s": round(split["lower_s"], 3),
+                            "backend_s": round(split["backend_s"], 3)}
         self.manifest.record(self.kernel, key, dt,
                              cache_dir=self.cache_dir)
         return out
@@ -259,7 +323,8 @@ class CompileTracker:
         """Close out the warmup: stamp the run into the manifest and
         persist it (idempotent)."""
         if self._wall_s is None:
-            self._wall_s = self._clock() - self._t0
+            with self._events_lock:
+                self._wall_s = self._clock() - self._t0
             self.manifest.record_run(self.kernel, self.hits, self.misses,
                                      self._wall_s)
             self.manifest.save()
@@ -275,4 +340,10 @@ class CompileTracker:
             "warm_boot": self.misses == 0 and (self.hits > 0),
             "warmup_wall_s": round(self.wall_s(), 3),
             "shapes": {k: v["s"] for k, v in sorted(self.shapes.items())},
+            "lower_s": round(self._boot_split["lower_s"], 3),
+            "backend_s": round(self._boot_split["backend_s"], 3),
+            "split": {k: [v["lower_s"], v["backend_s"]]
+                      for k, v in sorted(self.shapes.items())},
+            "in_service": dict(self.in_service,
+                               seconds=round(self.in_service["seconds"], 3)),
         }

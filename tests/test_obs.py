@@ -55,6 +55,12 @@ def _trace_line(sec, stage, block="aaa=", rnd=2, ms="000"):
             f"TRACE stage={stage} block={block} round={rnd}")
 
 
+def _sc(stage, t, dur_ms, **tags):
+    """A hand-written sidecar span ending at ``t``."""
+    return {"stage": stage, "t0": t - dur_ms / 1e3, "t": t,
+            "dur_ms": dur_ms, **tags}
+
+
 # ---------------------------------------------------------------------------
 # span writer / parser
 # ---------------------------------------------------------------------------
@@ -67,7 +73,7 @@ def test_tracer_writes_jsonl_spans(tmp_path):
     tok = tracer.begin_span("pack", rid=7, cls="latency")
     now[0] += 0.005
     tracer.end_span(tok)
-    tracer.event("device", dur_ms=18.5, rid=7)
+    tracer.record("device", now[0] - 0.0185, rid=7)
     with tracer.span("bls", rid=9):
         now[0] += 0.002
     tracer.close()
@@ -76,15 +82,22 @@ def test_tracer_writes_jsonl_spans(tmp_path):
     assert [s["stage"] for s in spans] == ["pack", "device", "bls"]
     assert spans[0]["rid"] == 7 and spans[0]["cls"] == "latency"
     assert spans[0]["dur_ms"] == pytest.approx(5.0)
-    assert spans[1]["dur_ms"] == 18.5
+    assert spans[1]["dur_ms"] == pytest.approx(18.5)
     assert spans[2]["dur_ms"] == pytest.approx(2.0)
+    # One meaning however the span was written: ``t`` is the END,
+    # ``t0`` the start, on the one injected clock.
+    assert (spans[0]["t0"], spans[0]["t"]) == pytest.approx((100.0, 100.005))
+    assert (spans[1]["t0"], spans[1]["t"]) == \
+        pytest.approx((100.005 - 0.0185, 100.005))
+    assert (spans[2]["t0"], spans[2]["t"]) == pytest.approx((100.005, 100.007))
+    assert len({s["id"] for s in spans}) == 3
 
 
 def test_disabled_tracer_is_noop(tmp_path):
     tracer = Tracer.disabled()
     tok = tracer.begin_span("pack")
     tracer.end_span(tok)
-    tracer.event("device", dur_ms=1.0)
+    tracer.record("device", 0.0)
     with tracer.span("x"):
         pass
     assert not tracer.enabled and tracer.dropped == 0
@@ -94,22 +107,24 @@ def test_tracer_survives_dead_sink(tmp_path):
     # A directory as the sink path: open() fails -> tracer disables
     # itself and the caller never sees an exception.
     tracer = Tracer(str(tmp_path))
-    tracer.event("pack", dur_ms=1.0)
+    tracer.record("pack", tracer.now())
+    tracer.close()  # the write-out is where a buffered sink finds out
     assert not tracer.enabled and tracer.dropped == 1
-    tracer.event("pack", dur_ms=1.0)  # still silent
+    tracer.record("pack", tracer.now())  # still silent
 
 
 def test_parse_spans_skips_torn_lines():
-    text = (json.dumps({"stage": "pack", "t": 1.0, "dur_ms": 2.0})
+    text = (json.dumps(_sc("pack", 1.0, 2.0))
             + "\n{\"stage\": \"dev"              # torn mid-write
             + "\nnot json at all\n"
-            + json.dumps({"no_stage": True, "t": 2.0}) + "\n"
-            + json.dumps({"stage": "device", "t": "bad"}) + "\n"
-            + json.dumps({"stage": "device", "t": 3.0, "dur_ms": 1.0})
+            + json.dumps({"no_stage": True, "t0": 2.0, "t": 2.0}) + "\n"
+            + json.dumps({"stage": "device", "t0": 1.0, "t": "bad"}) + "\n"
+            + json.dumps({"stage": "device", "t": 2.5}) + "\n"  # no start
+            + json.dumps(_sc("device", 3.0, 1.0))
             + "\n")
     spans, malformed = parse_spans(text)
     assert [s["stage"] for s in spans] == ["pack", "device"]
-    assert malformed == 4
+    assert malformed == 5
 
 
 # ---------------------------------------------------------------------------
@@ -251,8 +266,7 @@ def test_sidecar_breakdown_percentiles():
 
 def test_chrome_trace_schema_roundtrip():
     traces = stitch_blocks(_full_block("a=", 2, 100.0))
-    sc = [{"stage": "device", "t": 100.015, "dur_ms": 12.0, "rid": 3,
-           "cls": "latency"}]
+    sc = [_sc("device", 100.015, 12.0, rid=3, cls="latency")]
     chrome = chrome_trace(traces, sc)
     decoded = json.loads(json.dumps(chrome))
     assert decoded["displayTimeUnit"] == "ms"
@@ -287,7 +301,7 @@ def test_build_and_write_run_trace_directory(tmp_path):
     (tmp_path / "clock-offsets.json").write_text(
         json.dumps({"node-1.log": 0.2}))
     (tmp_path / "sidecar-spans.jsonl").write_text(
-        json.dumps({"stage": "pack", "t": 1785751201.0, "dur_ms": 3.0})
+        json.dumps(_sc("pack", 1785751201.0, 3.0))
         + "\ntorn lin")
     summary, chrome = build_run_trace(str(tmp_path))
     assert summary["blocks"] == 1 and summary["complete"] == 1
@@ -498,12 +512,55 @@ def test_recovery_curve_cites_the_gap():
 # ---------------------------------------------------------------------------
 
 
+class _Served:
+    """A SidecarServer over ``engine`` on a loopback port, in a thread:
+    ``with _Served(engine) as port`` — the socket path ``serve()`` binds,
+    minus the warm-up, with the test's own tracer on the engine."""
+
+    def __init__(self, engine):
+        from hotstuff_tpu.sidecar.service import SidecarServer
+
+        self.engine = engine
+        self.server = SidecarServer(("127.0.0.1", 0), engine)
+        self._thread = threading.Thread(
+            target=lambda: self.server.serve_forever(poll_interval=0.05),
+            daemon=True)
+
+    def __enter__(self):
+        self._thread.start()
+        return self.server.server_address[1]
+
+    def __exit__(self, *exc):
+        self.server.shutdown()
+        self.server.server_close()
+        self._thread.join(timeout=30)
+        self.engine.stop()
+        self.engine._thread.join(timeout=30)
+        assert not self._thread.is_alive()
+        assert not self.engine._thread.is_alive()
+
+
+def _await_spans(tracer, stage, n, timeout=30.0):
+    """The connection's writer records ``reply``/``request`` after the
+    client already holds its reply: wait for them."""
+    import time
+
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        with tracer._lock:
+            if sum(1 for r in tracer._buf if r["stage"] == stage) >= n:
+                return
+        time.sleep(0.005)
+    raise AssertionError(f"fewer than {n} {stage!r} span(s) after {timeout}s")
+
+
 def test_verify_engine_emits_stage_spans(tmp_path):
-    """A host-mode VerifyEngine with a live tracer: one latency verify
-    must leave the whole admit -> queue -> pack -> dispatch -> device ->
-    reply chain in the span file, tagged with the rid and class."""
+    """A host-mode VerifyEngine with a live tracer behind its socket: one
+    latency verify must leave the whole request -> decode -> queue ->
+    pack -> dispatch -> device -> reply chain in the span file, tagged
+    with the rid and class."""
     from hotstuff_tpu.crypto import ref_ed25519 as ref
-    from hotstuff_tpu.sidecar import protocol as proto
+    from hotstuff_tpu.sidecar.client import SidecarClient
     from hotstuff_tpu.sidecar.service import VerifyEngine
 
     sk = bytes(range(32))
@@ -512,36 +569,425 @@ def test_verify_engine_emits_stage_spans(tmp_path):
     sig = ref.sign(sk, msg)
 
     path = str(tmp_path / "spans.jsonl")
-    engine = VerifyEngine(use_host=True, tracer=Tracer(path))
-    try:
-        done = []
-        cond = threading.Condition()
-
-        def reply(mask):
-            with cond:
-                done.append(mask)
-                cond.notify()
-
-        assert engine.submit(
-            proto.VerifyRequest(42, [msg], [pk], [sig]), reply)
-        with cond:
-            assert cond.wait_for(lambda: done, timeout=60.0)
-        assert done[0] == [True]
-    finally:
-        engine.stop()
-        engine._tracer.close()
+    tracer = Tracer(path)
+    with _Served(VerifyEngine(use_host=True, tracer=tracer)) as port:
+        with SidecarClient(port=port) as client:
+            assert client.verify_batch([msg], [pk], [sig]) == [True]
+            _await_spans(tracer, "request", 1)
+    tracer.close()
     spans, malformed = parse_spans((tmp_path / "spans.jsonl").read_text())
     assert malformed == 0
     stages = [s["stage"] for s in spans]
-    for stage in ("admit", "queue", "pack", "dispatch", "device", "reply"):
+    for stage in ("request", "decode", "queue", "pack", "dispatch",
+                  "device", "reply"):
         assert stage in stages, f"missing {stage} span in {stages}"
-    admit = next(s for s in spans if s["stage"] == "admit")
-    assert admit["rid"] == 42 and admit["cls"] == "latency" \
-        and admit["ok"] is True
+    request = next(s for s in spans if s["stage"] == "request")
+    assert request["cls"] == "latency" and request["ok"] is True \
+        and request["n"] == 1
     queue = next(s for s in spans if s["stage"] == "queue")
-    assert queue["rid"] == 42 and queue["dur_ms"] >= 0
+    assert queue["rid"] == request["rid"] and queue["dur_ms"] >= 0 \
+        and queue["parent"] == request["id"]
     pack = next(s for s in spans if s["stage"] == "pack")
-    assert pack["path"] == "host" and pack["uniq"] == 1
+    assert pack["path"] == "host" and pack["uniq"] == 1 \
+        and pack["rids"] == [request["rid"]] and pack["lid"] == queue["lid"]
+
+
+# ---------------------------------------------------------------------------
+# one span tree per request and per launch (device-route engine, fake
+# device programs, virtual clock, real socket)
+# ---------------------------------------------------------------------------
+
+
+class _FakeDev:
+    """What a device program returns, as far as the fetch closures use
+    it: ``block_until_ready()`` (counted) and ``np.asarray``."""
+
+    waits = 0
+
+    def __init__(self, value):
+        import numpy as np
+
+        self._value = np.asarray(value)
+
+    def block_until_ready(self):
+        _FakeDev.waits += 1
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return self._value
+
+
+def _fake_programs(mp, forged_rows):
+    """Stand the two single-chip device programs in ``crypto/eddsa`` on
+    the host: a row is valid unless its (A, R, S) bytes are in
+    ``forged_rows``.  Nothing compiles; ``jnp.asarray`` stays real."""
+    import numpy as np
+
+    from hotstuff_tpu.crypto import eddsa
+
+    def bad(rows):
+        rows = np.asarray(rows)
+        return np.array([r[:96].tobytes() in forged_rows for r in rows])
+
+    mp.setattr(eddsa.E, "verify_rlc_packed_donated",
+               lambda rows, z: _FakeDev(not bad(rows).any()))
+    mp.setattr(eddsa.E, "verify_packed_donated",
+               lambda rows: _FakeDev(~bad(rows)))
+
+
+def _votes(n, forged=(), salt=0):
+    """n signatures of one key over distinct messages (``salt`` keeps
+    two certificates' records apart: the verdict cache is keyed on
+    them); the indices in ``forged`` carry another message's signature.
+    Returns (msgs, pks, sigs, expected mask, the forged rows' (A, R, S)
+    bytes)."""
+    from hotstuff_tpu.crypto import ref_ed25519 as ref
+
+    sk = bytes(range(32))
+    _, pk = ref.generate_keypair(sk)
+    msgs = [bytes([salt, i]) * 16 for i in range(n)]
+    sigs = [ref.sign(sk, m) for m in msgs]
+    wrong = ref.sign(sk, b"\xee" * 32)
+    for i in forged:
+        sigs[i] = wrong
+    rows = {pk + sigs[i] for i in forged}
+    return msgs, [pk] * n, sigs, [i not in forged for i in range(n)], rows
+
+
+def _device_route_engine(tracer):
+    """A device-mode engine whose registry says bucket 16 is warmed for
+    the one-MSM route, under a real launch guard."""
+    from hotstuff_tpu.sidecar.guard import LaunchGuard
+    from hotstuff_tpu.sidecar.service import VerifyEngine
+
+    guard = LaunchGuard()
+    engine = VerifyEngine(use_host=False, tracer=tracer, guard=guard)
+    for n in (8, 16):
+        engine._shapes.mark_bucket(n)
+        engine._shapes.mark_rlc(n)
+    return engine, guard
+
+
+SPAN_STAGES = ("request", "decode", "queue", "pack", "h2d", "dispatch",
+               "device", "fetch_wait", "d2h", "bisect", "reply")
+
+
+@pytest.fixture(scope="module")
+def span_tree(tmp_path_factory):
+    """One traced round trip over the socket on a virtual clock: a valid
+    16-vote certificate, the same one again (verdict cache), one with a
+    forged vote (bisection).  Yields (spans, annotations opened,
+    replies)."""
+    import itertools
+    from contextlib import contextmanager
+
+    from hotstuff_tpu.sidecar.client import SidecarClient
+
+    tmp = tmp_path_factory.mktemp("span_tree")
+    ticks = itertools.count()
+    annotations = []
+
+    @contextmanager
+    def annotation(name, **kw):
+        annotations.append((name, kw))
+        yield
+
+    tracer = Tracer(str(tmp / "spans.jsonl"),
+                    clock=lambda: 1000.0 + next(ticks) * 1e-4,
+                    annotation=annotation)
+    valid = _votes(16)
+    forged = _votes(16, forged=(5,), salt=1)
+    replies = {}
+    with pytest.MonkeyPatch.context() as mp:
+        _fake_programs(mp, forged[4])
+        engine, guard = _device_route_engine(tracer)
+        try:
+            with _Served(engine) as port:
+                with SidecarClient(port=port) as client:
+                    replies["valid"] = client.verify_batch(*valid[:3])
+                    replies["cached"] = client.verify_batch(*valid[:3])
+                    replies["forged"] = client.verify_batch(*forged[:3])
+                    _await_spans(tracer, "request", 3)
+        finally:
+            guard.close()
+    tracer.close()
+    spans, malformed = parse_spans((tmp / "spans.jsonl").read_text())
+    assert malformed == 0
+    assert replies == {"valid": valid[3], "cached": valid[3],
+                       "forged": forged[3]}
+    return spans, annotations, replies
+
+
+@pytest.mark.parametrize("stage", SPAN_STAGES)
+def test_span_tree_has_every_stage_on_one_clock(span_tree, stage):
+    spans, annotations, _ = span_tree
+    by_id = {s["id"]: s for s in spans}
+    assert len(by_id) == len(spans) and all(
+        isinstance(i, int) for i in by_id)
+    mine = [s for s in spans if s["stage"] == stage]
+    assert mine, f"no {stage!r} span in {sorted({s['stage'] for s in spans})}"
+    devices = [s for s in spans if s["stage"] == "device"]
+    for s in mine:
+        assert s["t0"] <= s["t"]
+        assert s["dur_ms"] == pytest.approx((s["t"] - s["t0"]) * 1e3,
+                                            abs=1e-3)
+        if stage == "request":
+            assert s["parent"] is None and s["ok"] is True \
+                and s["cls"] == "latency" and s["n"] == 16
+        elif stage in ("decode", "queue", "reply"):
+            root = by_id[s["parent"]]
+            assert root["stage"] == "request" and root["rid"] == s["rid"]
+            assert root["t0"] <= s["t0"] and s["t"] <= root["t"]
+        elif stage in ("pack", "dispatch", "device"):
+            assert s["parent"] is None and isinstance(s["lid"], int)
+        elif stage in ("h2d", "fetch_wait", "d2h", "bisect"):
+            parent = by_id[s["parent"]]
+            assert parent["stage"] == "device" and parent["lid"] == s["lid"]
+            assert (f"sidecar:{stage}", {"lid": s["lid"]}) in annotations
+    if stage == "queue":
+        for s in mine:  # the launch it left for, and the pack that took it
+            assert [d for d in devices if d["lid"] == s["lid"]]
+            pack = next(p for p in spans if p["stage"] == "pack"
+                        and p["lid"] == s["lid"])
+            assert s["rid"] in pack["rids"]
+    if stage == "device":
+        # ONE device span a launch, the bisected launch included.
+        assert sorted(d["lid"] for d in mine) == [1, 2]
+        assert all(d["sigs"] == 16 and d["hop_ms"] >= 0 for d in mine)
+    if stage == "dispatch":
+        assert all(s["wait_pack_ms"] >= 0 and s["hop_ms"] >= 0
+                   and ("sidecar:dispatch", {"lid": s["lid"]}) in annotations
+                   for s in mine)
+    if stage == "bisect":
+        assert len(mine) == 1 and mine[0]["launches"] >= 2
+    if stage in ("h2d", "d2h", "decode", "reply"):
+        assert all(s["bytes"] > 0 for s in mine)
+    if stage == "reply":
+        assert all(s["outbox_ms"] >= 0 for s in mine)
+    if stage == "request":
+        # The verdict-cache answer: decode and reply children only.
+        (cached,) = [s for s in mine if s.get("cached")]
+        kids = sorted(s["stage"] for s in spans
+                      if s.get("parent") == cached["id"])
+        assert kids == ["decode", "reply"]
+
+
+@pytest.mark.parametrize("forged", [(), (3,)], ids=["valid", "bisected"])
+def test_disabled_tracer_reads_no_clock_and_buffers_nothing(forged):
+    """trace_path=None: a full verify round trip over the socket reads
+    the tracer's clock zero times, buffers nothing and never calls
+    block_until_ready; the guard's hop counters still count."""
+    from hotstuff_tpu.sidecar.client import SidecarClient
+
+    reads = []
+    tracer = Tracer(None, clock=lambda: reads.append(1) or 0.0)
+    msgs, pks, sigs, expect, rows = _votes(16, forged=forged)
+    _FakeDev.waits = 0
+    with pytest.MonkeyPatch.context() as mp:
+        _fake_programs(mp, rows)
+        engine, guard = _device_route_engine(tracer)
+        try:
+            with _Served(engine) as port:
+                with SidecarClient(port=port) as client:
+                    assert client.verify_batch(msgs, pks, sigs) == expect
+                    snap = client.stats()
+        finally:
+            guard.close()
+    assert reads == [] and tracer._buf == [] and _FakeDev.waits == 0
+    assert snap["paths"]["rlc"] == 1
+    assert snap["paths"].get("rlc_bisect", 0) == (1 if forged else 0)
+    assert snap["guard"]["calls"] >= 2 and snap["guard"]["hop_s_total"] >= 0
+
+
+@pytest.mark.parametrize("how", ["close", "bound", "dead", "busy"])
+def test_sink_buffers_until_close_or_bound(tmp_path, how):
+    """Nothing reaches the file before close() or the buffer bound,
+    everything after; a dead sink disables the tracer at its first
+    write-out and the caller never sees an exception; a call that finds
+    a write-out under way appends and returns."""
+    if how == "dead":
+        tracer = Tracer(str(tmp_path))  # a directory: open() fails
+        for _ in range(3):
+            tracer.record("pack", tracer.now())
+        assert tracer.enabled and tracer.dropped == 0
+        tracer.close()
+        assert not tracer.enabled and tracer.dropped == 3
+        return
+    path = tmp_path / "spans.jsonl"
+    tracer = Tracer(str(path))
+    tracer.BUFFER_SPANS = 4
+    if how == "busy":
+        # Another thread is mid write-out (it holds the I/O lock, not the
+        # tracer's): span sites neither wait for it nor write.
+        with tracer._io_lock:
+            for i in range(6):
+                tracer.record("pack", tracer.now(), i=i)
+            assert not path.exists() and len(tracer._buf) == 6
+        tracer.record("pack", tracer.now(), i=6)  # the next call writes
+        assert len(path.read_text().splitlines()) == 7
+        tracer.close()
+        spans, _ = parse_spans(path.read_text())
+        assert [s["i"] for s in spans] == list(range(7))
+        return
+    for i in range(3):
+        tracer.record("pack", tracer.now(), i=i)
+    assert not path.exists()
+    if how == "bound":
+        tracer.record("pack", tracer.now(), i=3)  # the call that fills it
+        assert len(path.read_text().splitlines()) == 4
+        tracer.record("pack", tracer.now(), i=4)
+        assert len(path.read_text().splitlines()) == 4
+    tracer.close()
+    spans, malformed = parse_spans(path.read_text())
+    assert malformed == 0
+    assert [s["i"] for s in spans] == list(range(5 if how == "bound" else 3))
+    tracer.record("pack", 0.0)  # closed: a silent no-op
+    assert tracer._buf == []
+
+
+@pytest.mark.parametrize("thunk_s", [0.0, 0.02])
+def test_guard_call_reports_its_own_hop(thunk_s):
+    import time
+
+    from hotstuff_tpu.sidecar.guard import LaunchGuard
+
+    guard = LaunchGuard()
+    try:
+        t0 = time.monotonic()
+        assert guard.call("launch:8", lambda: time.sleep(thunk_s) or 7) == 7
+        wall = time.monotonic() - t0
+        assert 0.0 <= guard.last_hop_s <= wall - thunk_s + 1e-3
+        snap = guard.snapshot()
+        assert snap["calls"] == 1
+        assert snap["hop_s_total"] == pytest.approx(guard.last_hop_s,
+                                                    abs=1e-6)
+    finally:
+        guard.close()
+
+
+@pytest.mark.parametrize("phase", ["warming", "in_service"])
+def test_compile_tracker_attributes_monitoring_events(tmp_path, phase):
+    from hotstuff_tpu.utils.xla_cache import CompileTracker
+
+    listeners = []
+    tracker = CompileTracker(cache_dir=str(tmp_path),
+                             manifest_path=str(tmp_path / "m.json"),
+                             kernel="k", register=listeners.append)
+    (emit,) = listeners
+    trace_ev, mlir_ev = CompileTracker.LOWER_EVENTS
+    backend_ev = CompileTracker.BACKEND_EVENT
+
+    def one_shape():
+        emit(trace_ev, 1.5, fun_name="verify_rlc_packed")
+        emit(mlir_ev, 0.5)
+        emit(backend_ev, 2.0)
+        emit("/jax/compilation_cache/cache_retrieval_time_sec", 9.0)
+
+    tracker.warm("rlc:8", one_shape)
+    emit(backend_ev, 0.25)           # before finish(), outside any shape
+    tracker.warm("rlc:16", lambda: emit(backend_ev, 1.0))
+    if phase == "warming":
+        snap = tracker.snapshot()
+        assert snap["split"] == {"rlc:8": [2.0, 2.0], "rlc:16": [0.0, 1.0]}
+        assert snap["lower_s"] == 2.0 and snap["backend_s"] == 3.25
+        assert set(snap["shapes"]) == {"rlc:8", "rlc:16"}
+        assert snap["in_service"] == {"count": 0, "seconds": 0.0,
+                                      "last_at": None}
+        return
+    tracker.finish()
+    emit(trace_ev, 4.0)              # tracing alone builds no program
+    emit(backend_ev, 0.75)
+    snap = tracker.snapshot()
+    assert snap["in_service"]["count"] == 1 \
+        and snap["in_service"]["seconds"] == 0.75 \
+        and snap["in_service"]["last_at"] > 0
+    assert snap["lower_s"] == 2.0 and snap["backend_s"] == 3.25
+    json.dumps(snap)
+
+
+@pytest.mark.parametrize("how", ["serving", "untraced", "warming"])
+def test_sigterm_writes_the_spans_of_a_serving_sidecar(tmp_path, how):
+    """SIGTERM on ``python -m hotstuff_tpu.sidecar --trace``, once it
+    serves, ends serve() through its ``finally``: the buffered spans are
+    in the file and the exit code is 0.  Without ``--trace``, and during
+    a traced sidecar's warm-up, SIGTERM keeps its default action (the
+    process dies by the signal, exit code -15)."""
+    import os
+    import signal
+    import subprocess
+    import sys
+    import time
+
+    from hotstuff_tpu.sidecar import service
+
+    if how == "warming":
+        seen = {}
+
+        def fake_serve(*args, ready_event=None, trace_path=None, **kw):
+            seen["warming"] = signal.getsignal(signal.SIGTERM)
+            ready_event.set()
+            seen["serving"] = signal.getsignal(signal.SIGTERM)
+
+        before = signal.getsignal(signal.SIGTERM)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(service, "serve", fake_serve)
+            try:
+                service.main(["--host-crypto", "--trace",
+                              str(tmp_path / "spans.jsonl")])
+            finally:
+                installed = signal.signal(signal.SIGTERM, before)
+        assert seen["warming"] is before and seen["serving"] is installed
+        with pytest.raises(SystemExit) as exc:
+            installed(signal.SIGTERM, None)
+        assert exc.value.code == 0
+        return
+
+    from hotstuff_tpu.sidecar.client import SidecarClient
+
+    from conftest import REPO, free_port
+
+    path = tmp_path / "spans.jsonl"
+    port = free_port()
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    cmd = [sys.executable, "-m", "hotstuff_tpu.sidecar", "--host-crypto",
+           "--port", str(port)]
+    if how == "serving":
+        cmd += ["--trace", str(path)]
+    with open(tmp_path / "sidecar.log", "w") as log:
+        proc = subprocess.Popen(cmd, cwd=tmp_path, stdout=log, stderr=log,
+                                env=env)
+        try:
+            deadline = time.monotonic() + 120
+            while True:
+                try:
+                    with SidecarClient(port=port, timeout=30) as client:
+                        msgs, pks, sigs, expect, _ = _votes(4)
+                        assert client.verify_batch(msgs, pks, sigs) == expect
+                        # The connection's writer closes the request's
+                        # spans after its sendall and before it sends
+                        # the next frame: one more round trip orders the
+                        # SIGTERM behind them.
+                        assert client.ping()
+                    break
+                except OSError:
+                    assert proc.poll() is None and \
+                        time.monotonic() < deadline, "sidecar never served"
+                    time.sleep(0.1)
+            proc.send_signal(signal.SIGTERM)
+            rc = proc.wait(timeout=30)
+        finally:
+            if proc.poll() is None:
+                proc.kill()
+                proc.wait()
+    if how == "untraced":
+        assert rc == -signal.SIGTERM and not path.exists()
+        return
+    assert rc == 0
+    spans, malformed = parse_spans(path.read_text())
+    assert malformed == 0
+    assert {"request", "decode", "queue", "pack", "device", "reply"} <= \
+        {s["stage"] for s in spans}
 
 
 # ---------------------------------------------------------------------------
@@ -632,14 +1078,14 @@ def test_protocol_v5_ctx_rides_bls_ops():
 
 
 def test_verify_engine_spans_carry_ctx(tmp_path):
-    """An engine-path verify tagged with a block digest must leave the
-    ctx on its per-request spans (admit/queue/reply) and the b64 tag in
-    the per-launch ctxs lists (pack/dispatch/device) — the exact schema
+    """A verify tagged with a block digest must leave the ctx on its
+    per-request spans (request/queue/reply) and the b64 tag in the
+    per-launch ctxs lists (pack/dispatch/device) — the exact schema
     obs/trace.py joins on."""
     from base64 import b64encode
 
     from hotstuff_tpu.crypto import ref_ed25519 as ref
-    from hotstuff_tpu.sidecar import protocol as proto
+    from hotstuff_tpu.sidecar.client import SidecarClient
     from hotstuff_tpu.sidecar.service import VerifyEngine
 
     sk = bytes(range(32))
@@ -650,35 +1096,24 @@ def test_verify_engine_spans_carry_ctx(tmp_path):
     ctx_b64 = b64encode(ctx).decode()
 
     path = str(tmp_path / "spans.jsonl")
-    engine = VerifyEngine(use_host=True, tracer=Tracer(path))
-    try:
-        done = []
-        cond = threading.Condition()
-
-        def reply(mask):
-            with cond:
-                done.append(mask)
-                cond.notify()
-
-        assert engine.submit(
-            proto.VerifyRequest(9, [msg], [pk], [sig], ctx=ctx), reply)
-        with cond:
-            assert cond.wait_for(lambda: done, timeout=60.0)
-        assert done[0] == [True]
-    finally:
-        engine.stop()
-        engine._tracer.close()
+    tracer = Tracer(path)
+    with _Served(VerifyEngine(use_host=True, tracer=tracer)) as port:
+        with SidecarClient(port=port) as client:
+            assert client.verify_batch([msg], [pk], [sig], ctx=ctx) == [True]
+            _await_spans(tracer, "request", 1)
+    tracer.close()
     spans, malformed = parse_spans((tmp_path / "spans.jsonl").read_text())
     assert malformed == 0
     by_stage = {s["stage"]: s for s in spans}
-    for stage in ("admit", "queue", "reply"):
+    for stage in ("request", "decode", "queue", "reply"):
         assert by_stage[stage]["ctx"] == ctx_b64, by_stage[stage]
     for stage in ("pack", "dispatch", "device"):
         assert by_stage[stage]["ctxs"] == [ctx_b64], by_stage[stage]
     # The chain machinery joins them all onto the one tag.
     chains = chain_spans(spans)
     assert set(s["stage"] for s in chains[ctx_b64]) == \
-        {"admit", "queue", "pack", "dispatch", "device", "reply"}
+        {"request", "decode", "queue", "pack", "dispatch", "device",
+         "reply"}
 
 
 # ---------------------------------------------------------------------------
@@ -688,16 +1123,11 @@ def test_verify_engine_spans_carry_ctx(tmp_path):
 
 def _chain(block, t0, rid=1):
     return [
-        {"stage": "admit", "t": t0, "dur_ms": 0.0, "rid": rid,
-         "cls": "latency", "ctx": block},
-        {"stage": "queue", "t": t0 + 0.001, "dur_ms": 1.0, "rid": rid,
-         "cls": "latency", "ctx": block},
-        {"stage": "pack", "t": t0 + 0.002, "dur_ms": 2.0, "reqs": 1,
-         "ctxs": [block]},
-        {"stage": "device", "t": t0 + 0.005, "dur_ms": 12.0, "reqs": 1,
-         "ctxs": [block]},
-        {"stage": "reply", "t": t0 + 0.02, "dur_ms": 0.0, "rid": rid,
-         "cls": "latency", "ctx": block},
+        _sc("request", t0 + 0.021, 21.0, rid=rid, cls="latency", ctx=block),
+        _sc("queue", t0 + 0.001, 1.0, rid=rid, cls="latency", ctx=block),
+        _sc("pack", t0 + 0.003, 2.0, reqs=1, ctxs=[block]),
+        _sc("device", t0 + 0.017, 12.0, reqs=1, ctxs=[block]),
+        _sc("reply", t0 + 0.021, 0.5, rid=rid, cls="latency", ctx=block),
     ]
 
 
@@ -733,8 +1163,7 @@ def test_join_shared_launch_spans_both_blocks():
     # device spans list both ctxs and land in BOTH chains.
     traces = stitch_blocks(_full_block("a=", 2, 100.0)
                            + _full_block("b=", 3, 100.5))
-    shared = {"stage": "device", "t": 100.02, "dur_ms": 9.0,
-              "ctxs": ["a=", "b="]}
+    shared = _sc("device", 100.02, 9.0, ctxs=["a=", "b="])
     join, joined = join_blocks(traces, chain_spans([shared]))
     assert join["joined"] == 2 and join["rate"] == 1.0
     assert all(shared in chain for chain in joined.values())

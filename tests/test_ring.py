@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from hotstuff_tpu.crypto import eddsa, ref_ed25519 as ref
-from hotstuff_tpu.obs.spans import Tracer
+from hotstuff_tpu.obs.spans import NO_LAUNCH, Tracer
 from hotstuff_tpu.sidecar import protocol as proto
 from hotstuff_tpu.sidecar import sched as vsched
 from hotstuff_tpu.sidecar.guard import (BusyReply, LaunchDeadlines,
@@ -266,7 +266,7 @@ class FakeEngine:
         self.wedge_next_guarded = False
         self.laddered = []  # (batch, key, stage) from _wedge_ladder
 
-    def _pack(self, batch):
+    def _pack(self, batch, scope=None):
         msgs = [m for p in batch for m in p.request.msgs]
         pks = [k for p in batch for k in p.request.pks]
         sigs = [s for p in batch for s in p.request.sigs]
@@ -296,11 +296,8 @@ class FakeEngine:
         for p in batch:
             p.reply_fn([False] * len(p.request.msgs))
 
-    def _trace_queue_waits(self, launch):
-        pass
-
-    def _trace_replies(self, batch):
-        pass
+    def _begin_launch(self, launch):
+        return NO_LAUNCH
 
     def close(self):
         self._pack_pool.shutdown(wait=False)

@@ -60,7 +60,9 @@ def server():
 
 def test_sidecar_end_to_end(server):
     port = server.server_address[1]
-    with SidecarClient(port=port) as client:
+    # The first verify compiles its bucket on the CPU: ~30 s alone, past
+    # the client's default 60 s under six loaded workers.
+    with SidecarClient(port=port, timeout=300.0) as client:
         assert client.ping()
         msgs, pks, sigs = _sigs(10, tamper={3, 7})
         mask = client.verify_batch(msgs, pks, sigs)

@@ -175,13 +175,13 @@ def test_mixed_priority_soak_through_engine():
 
     orig_pack = engine._pack
 
-    def spying_pack(batch):
+    def spying_pack(batch, scope):
         # _pack is the launch-admission surface of the double-buffered
         # engine (the single pack worker preserves scheduler assembly
         # order, so this records the true launch order).
         launches.append([(p.cls, admit_idx[p.request.request_id])
                          for p in batch])
-        return orig_pack(batch)
+        return orig_pack(batch, scope)
 
     engine._verify_submit = fake_verify_submit
     engine._pack = spying_pack

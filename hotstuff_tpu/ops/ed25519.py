@@ -605,11 +605,18 @@ verify_prepared_jit = jax.jit(verify_prepared)
 # _MSM_WINDOW_CHUNK inside one lax.scan — chunking trades conv group
 # count (chunk * 2n per level) against scan depth, keeping groups inside
 # the ~1024-group compile-time envelope at quorum sizes while the scan
-# body still compiles once.  Window sums combine by a 63-step Horner
-# ladder (4 doublings + 1 add per window, batch 1), and the fixed-base
-# [c]B side reuses the zero-doubling comb.  Total point-op work is
-# ~78n + 330 versus ~350n for n per-signature ladders — the arithmetic
-# win the RLC check exists for.
+# body still compiles once.  Window sums combine by a 64-step Horner
+# fold (4 doublings + 1 add per window, on ONE point), and the
+# fixed-base [c]B side reuses the zero-doubling comb (32 adds on one
+# point).  Total point-op work is ~78n + 352 versus ~350n for n
+# per-signature ladders — the arithmetic win the RLC check exists for.
+# That one-point tail is a latency chain, not arithmetic: as lax scans
+# (msm_horner, comb_mul_base) it is ~2,650 one-row convolutions with
+# their carries, issued one after the other (38 a Horner step) — 38 ms
+# of a v5e's time whatever the batch (PERF.md, PR 28).  On a TPU
+# rlc_finish therefore runs it as ONE Pallas kernel (rlc_tail below,
+# ops/kern/rlc_tail: 0.58 ms); the scans stay as the reference the
+# kernel is held to limb for limb, and as the tail off the chip.
 #
 # Pippenger-style shared buckets (15 buckets per window, scatter by
 # digit) were considered and rejected for this substrate: point adds
@@ -727,7 +734,8 @@ def _window_sums_lax(table: jnp.ndarray, digits: jnp.ndarray) -> jnp.ndarray:
 
 def msm_horner(wsums: jnp.ndarray) -> jnp.ndarray:
     """(64, 4, 32) MSB-first window sums -> (4, 32) ext total:
-    63 x (4 doublings + 1 add) at batch 1."""
+    64 x (4 doublings + 1 add) on one point.  The lax reference of the
+    rlc_tail kernel (and msm_straus' fold)."""
     def horner(acc, w):
         acc = point_dbl(acc, with_t=False)
         acc = point_dbl(acc, with_t=False)
@@ -748,7 +756,8 @@ def msm_straus(points: jnp.ndarray, digits: jnp.ndarray) -> jnp.ndarray:
 def comb_mul_base(c_digits: jnp.ndarray) -> jnp.ndarray:
     """[c]B for one scalar given as (32,) int32 base-256 little-endian
     digits: the fixed-base comb at batch shape () — 32 adds, zero
-    doublings."""
+    doublings.  The lax reference of the rlc_tail kernel's second
+    sum."""
     comb = jnp.asarray(comb_table())                 # (32, 256, 4, 32)
 
     def body(acc, xs):
@@ -758,6 +767,25 @@ def comb_mul_base(c_digits: jnp.ndarray) -> jnp.ndarray:
     acc, _ = jax.lax.scan(body, identity_ext(()),
                           (comb, c_digits.astype(jnp.int32)))
     return acc
+
+
+def rlc_tail(wsums: jnp.ndarray, c_digits: jnp.ndarray):
+    """(msm_horner(wsums), comb_mul_base(c_digits)), limb for limb, as
+    ONE kernel (ops/kern/rlc_tail): both are serial chains on a single
+    point, which as lax scans cost ~2,650 one-row convolutions issued
+    one after the other.  What is batched stays outside the chain: the
+    64 window sums go to cached form in one op, and the 32 comb entries
+    come from one gather."""
+    if _kern.interpret_default():
+        # Not on a TPU: the Pallas interpreter inlines the kernel body
+        # into every rlc program, ~16 s more XLA:CPU compile a program
+        # than the two scans, so off the chip the lax reference IS the
+        # tail.  The kernel body itself still runs on the CPU in
+        # tests/test_kern.py.
+        return msm_horner(wsums), comb_mul_base(c_digits)
+    comb = jnp.asarray(comb_table())                 # (32, 256, 4, 32)
+    entries = comb[jnp.arange(_COMB_POSITIONS), c_digits.astype(jnp.int32)]
+    return _kern.rlc_tail(to_cached(wsums), entries)
 
 
 def rlc_partials(packed: jnp.ndarray, z: jnp.ndarray):
@@ -824,11 +852,11 @@ def rlc_partials(packed: jnp.ndarray, z: jnp.ndarray):
 def rlc_finish(wsums: jnp.ndarray, u_limbsum: jnp.ndarray,
                bad: jnp.ndarray) -> jnp.ndarray:
     """Combine (possibly mesh-reduced) RLC partials into the () bool
-    verdict: Horner-fold the window sums, comb [c]B from the reduced
-    scalar sum, compare projectively, and veto on any bad point."""
+    verdict: Horner-fold the window sums and comb [c]B from the reduced
+    scalar sum (rlc_tail: one kernel on a TPU), compare projectively,
+    and veto on any bad point."""
     c = S.reduce_limbsum_mod_l(u_limbsum)
-    msm = msm_horner(wsums)            # sum [w_i]A_i + [z_i]R_i
-    cb = comb_mul_base(c)              # [c]B
+    msm, cb = rlc_tail(wsums, c)       # sum [w_i]A_i + [z_i]R_i, [c]B
 
     x1, y1, z1, _ = _unpack(cb)
     x2, y2, z2, _ = _unpack(msm)

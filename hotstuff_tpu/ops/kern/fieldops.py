@@ -162,10 +162,14 @@ def f_add(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return carry_step(a + b)
 
 
-def f_sub(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """field25519.sub: add the 8p bias, two carry steps."""
-    x = a + const_row(lane_iota(a.shape), _SUB_BIAS_DIGITS) - b
-    return carry_step(carry_step(x))
+def f_sub(a: jnp.ndarray, b: jnp.ndarray,
+          bias: jnp.ndarray | None = None) -> jnp.ndarray:
+    """field25519.sub: add the 8p bias, two carry steps.  ``bias``: the
+    const_row of _SUB_BIAS_DIGITS, for a caller that subtracts inside a
+    loop and builds the row (32 selects) once outside it."""
+    if bias is None:
+        bias = const_row(lane_iota(a.shape), _SUB_BIAS_DIGITS)
+    return carry_step(carry_step(a + bias - b))
 
 
 def f_neg(a: jnp.ndarray) -> jnp.ndarray:

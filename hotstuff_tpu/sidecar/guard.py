@@ -42,7 +42,7 @@ gets the generous ``compile_budget_s``.  Env knobs:
     HOTSTUFF_TPU_GUARD_COMPILE_BUDGET_S   cold/first-compile deadline (180)
     HOTSTUFF_TPU_GUARD_WARM_GRACE_S       warmed-shape fallback deadline (30)
     HOTSTUFF_TPU_GUARD_P99_MULTIPLE      deadline = multiple x observed p99 (8)
-    HOTSTUFF_TPU_GUARD_MIN_DEADLINE_S    floor under the p99 rule (1.0)
+    HOTSTUFF_TPU_GUARD_MIN_DEADLINE_S    floor under the p99 rule (3.0)
     HOTSTUFF_TPU_GUARD_MAX_REBOOTS       canary failures before the engine
                                          stays on the host path (3)
     HOTSTUFF_TPU_GUARD_MAX_BISECT_PROBES poison-bisection probe budget (64)
@@ -168,8 +168,14 @@ class LaunchDeadlines:
             else _env_float("HOTSTUFF_TPU_GUARD_WARM_GRACE_S", 30.0)
         self.p99_multiple = p99_multiple if p99_multiple is not None \
             else _env_float("HOTSTUFF_TPU_GUARD_P99_MULTIPLE", 8.0)
+        # 3 s, not 1: on a v5e host a launch's completion reaches the
+        # process a second or more late now and then (three times in
+        # ~345 s of serving, none over 0.2 s in the next 360: PERF.md,
+        # PR 28), and a false wedge costs a host fallback, a device
+        # reboot and a ~200 s re-warm.  Until PR 28 the rlc route never
+        # ran at the floor: 8 x its p99 (a 0.33 s bisection) was 2.6 s.
         self.min_deadline_s = min_deadline_s if min_deadline_s is not None \
-            else _env_float("HOTSTUFF_TPU_GUARD_MIN_DEADLINE_S", 1.0)
+            else _env_float("HOTSTUFF_TPU_GUARD_MIN_DEADLINE_S", 3.0)
         self._lock = threading.Lock()
         self._samples: dict[str, list] = {}
 

@@ -17,6 +17,9 @@
 #      compiles in ~70 s at n=8 plus ~55 s for its bisection floor, and
 #      the B=1024 window-accumulator agreement costs ~90 s — ~4 min
 #      total, far inside the default 900 s budget.
+#   3. The rlc_tail kernel inside whole rlc programs (tests/test_rlc.py:
+#      one device, and a two-device mesh) is two more interpreter
+#      compiles, ~70 s and ~60 s.
 #
 # KERN_GATE_BUDGET_S overrides the window; the gate FAILS (rc 124) if
 # the budget is exceeded, so a kernel-compile-time regression is a loud
@@ -34,8 +37,9 @@ cd "$ROOT"
 start=$(date +%s)
 rc=0
 timeout -k 10 "$BUDGET" env JAX_PLATFORMS=cpu HOTSTUFF_TPU_SLOW_TESTS=1 \
-    python -m pytest "$ROOT/tests/test_kern.py" -q \
-    -p no:cacheprovider "$@" || rc=$?
+    python -m pytest "$ROOT/tests/test_kern.py" \
+    "$ROOT/tests/test_rlc.py::test_rlc_tail_kernel_keeps_the_torsion_verdicts" \
+    -q -p no:cacheprovider "$@" || rc=$?
 if [ "$rc" -ne 0 ]; then
   if [ "$rc" -eq 124 ]; then
     echo "kern_gate: exceeded the ${BUDGET}s budget" >&2

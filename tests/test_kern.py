@@ -296,6 +296,103 @@ class TestMsmWindowChunk:
 
 
 # ---------------------------------------------------------------------------
+# Kernel 4: rlc_tail
+# ---------------------------------------------------------------------------
+
+
+def _ext_limbs(pt) -> np.ndarray:
+    """Python-int ext point (ref_ed25519) -> (4, 32) limbs, as given."""
+    return np.stack([F.to_limbs(c % P) for c in pt])
+
+
+def _torsion8():
+    ty = int.from_bytes(eddsa._SMALL_ORDER_Y[3].tobytes(), "little")
+    return ref.decode_point(ty.to_bytes(32, "little"))
+
+
+def _window_sum_case(name: str) -> np.ndarray:
+    """(64, 4, 32) MSB-first window sums for one case of the fold."""
+    rng = np.random.default_rng(28)
+    wsums = _arr(E.identity_ext((64,))).copy()
+    if name == "real_msm":
+        pts = np.stack([_ext_limbs(ref.scalar_mult(
+            int.from_bytes(rng.bytes(32), "little") % L or 1, ref.B))
+            for _ in range(6)])
+        scalars = rng.integers(0, 256, (6, 32))
+        scalars[:, 31] &= 0x0F                       # < 2^252 < L
+        digits = E.unpack_nibbles_msb(jnp.asarray(scalars, jnp.int32))
+        return _arr(E.msm_window_sums(jnp.asarray(pts), digits))
+    if name == "first_window_only":
+        wsums[0] = _ext_limbs(ref.scalar_mult(7, ref.B))
+    elif name == "last_window_only":
+        wsums[63] = _ext_limbs(ref.scalar_mult(7, ref.B))
+    elif name == "torsion8":
+        # every window a point of the prime-order group plus a multiple
+        # of an order-8 point: the fold must carry the torsion part
+        # through all 256 doublings as the complete formulas do.
+        t8 = _torsion8()
+        for j in range(64):
+            wsums[j] = _ext_limbs(ref.pt_add(
+                ref.scalar_mult(j + 2, ref.B), ref.scalar_mult(j % 8, t8)))
+    elif name == "weak_bound_511":
+        wsums[:] = 511                # no point: the worst carry chains
+    elif name != "all_identity":
+        raise AssertionError(name)
+    return wsums
+
+
+def _comb_entries(c: int):
+    digits = jnp.asarray(F.to_limbs(c))              # base-256, LE
+    return digits, jnp.asarray(E.comb_table())[jnp.arange(32), digits]
+
+
+_horner_ref = jax.jit(E.msm_horner)
+
+
+@pytest.mark.parametrize("case", [
+    "real_msm", "all_identity", "first_window_only", "last_window_only",
+    "torsion8", "weak_bound_511"])
+def test_rlc_tail_fold_bit_identical_to_msm_horner(case):
+    wsums = jnp.asarray(_window_sum_case(case))
+    got, _ = kern.rlc_tail(E.to_cached(wsums), _comb_entries(0)[1])
+    want = _arr(_horner_ref(wsums))
+    assert np.array_equal(_arr(got), want)
+    only = {"first_window_only": 16 ** 63, "last_window_only": 1}.get(case)
+    if only:                # and the value is right, not just consistent
+        x, y, z = (F.from_limbs(_arr(F.canonical(got[c]))) for c in range(3))
+        assert ref.pt_equal((x, y, z, 0),
+                            ref.scalar_mult(7 * only % L, ref.B))
+
+
+@pytest.mark.parametrize("c", [
+    0, 1, L - 1, 1 << 252,
+    int.from_bytes(np.random.default_rng(29).bytes(32), "little") % L],
+    ids=["0", "1", "L-1", "2^252", "random"])
+def test_rlc_tail_comb_equals_comb_mul_base(c):
+    digits, entries = _comb_entries(c)
+    ident = E.to_cached(E.identity_ext((64,)))
+    _, got = kern.rlc_tail(ident, entries)
+    want = E.comb_mul_base(digits)
+    # as a point: cross-multiplied, canonical (what rlc_finish compares)
+    cross = _arr(F.canonical(F.mul(
+        jnp.stack([got[0], want[0], got[1], want[1]]),
+        jnp.stack([want[2], got[2], want[2], got[2]]))))
+    assert np.array_equal(cross[0], cross[1])
+    assert np.array_equal(cross[2], cross[3])
+    x, y, z = (F.from_limbs(_arr(F.canonical(got[k]))) for k in range(3))
+    assert ref.pt_equal((x, y, z, 0), ref.scalar_mult(c, ref.B))
+    # the kernel adds the 32 entries in comb_mul_base's order, so even
+    # the representative is the same
+    assert np.array_equal(_arr(got), _arr(want))
+
+
+def test_rlc_tail_refuses_other_shapes():
+    with pytest.raises(ValueError, match="rlc_tail takes"):
+        kern.rlc_tail(jnp.zeros((32, 4, 32), jnp.int32),
+                      jnp.zeros((32, 4, 32), jnp.int32))
+
+
+# ---------------------------------------------------------------------------
 # Compile manifest + tracker (the persistent-cache accounting)
 # ---------------------------------------------------------------------------
 

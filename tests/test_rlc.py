@@ -204,6 +204,82 @@ def test_torsion_in_r_rejected_by_both_paths():
     assert eddsa.verify_batch_rlc([m], [pk], [sig2]).tolist() == [False]
 
 
+def _mixed_order_vote(trial: int):
+    """The vector of test_mixed_order_pubkey_agrees_with_per_signature:
+    (msg, pk, sig, k) with pk = A' + T, T of order 8, signed with A''s
+    secret — valid for the cofactorless check iff k = 0 (mod 8)."""
+    import hashlib
+
+    from hotstuff_tpu.utils.intmath import L
+
+    ty = int.from_bytes(eddsa._SMALL_ORDER_Y[3].tobytes(), "little")
+    t_pt = ref.decode_point(ty.to_bytes(32, "little"))
+    h = hashlib.sha512(b"\x09" * 32).digest()
+    a = ref._clamp(int.from_bytes(h[:32], "little"))
+    pk = ref.encode_point(ref.pt_add(ref.scalar_mult(a, ref.B), t_pt))
+    msg = b"grind-%d" % trial
+    r = ref._h(h[32:] + msg) % L
+    r_enc = ref.encode_point(ref.scalar_mult(r, ref.B))
+    k = ref._h(r_enc + pk + msg) % L
+    return msg, pk, r_enc + ((r + k * a) % L).to_bytes(32, "little"), k
+
+
+@pytest.mark.parametrize("program", [
+    # ~70 s of XLA:CPU compile each; the mesh program holds all that the
+    # single-device one does, so that one waits in the slow lane.
+    pytest.param("verify_rlc_packed", marks=pytest.mark.slow),
+    "sharded"])
+def test_rlc_tail_kernel_keeps_the_torsion_verdicts(monkeypatch, program):
+    """The combined check with its serial tail as the rlc_tail KERNEL
+    (the route a TPU takes; here through the Pallas interpreter) gives
+    the verdicts of the lax tail on the torsion vectors above — on one
+    device, and replicated over a two-device mesh.  The route is steered
+    HERE: ops/ed25519.rlc_tail reads kern.interpret_default at trace
+    time, the kernel's own module keeps its copy (-> interpreter)."""
+    import jax
+
+    from hotstuff_tpu.ops import ed25519 as E, kern
+    from hotstuff_tpu.parallel.mesh import make_mesh
+    from hotstuff_tpu.parallel.sharded_verify import make_sharded_rlc_verifier
+
+    votes = {}
+    for trial in range(24):                 # one k = 0 (mod 8), one not
+        vote = _mixed_order_vote(trial)
+        votes.setdefault(vote[3] % 8 == 0, vote[:3])
+    m, pk, sig = POOL[0]
+    ty = int.from_bytes(eddsa._SMALL_ORDER_Y[3].tobytes(), "little")
+    r_mix = ref.pt_add(ref.decode_point(sig[:32]),
+                       ref.decode_point(ty.to_bytes(32, "little")))
+    batches = {
+        "all valid": (POOL[:4], True),
+        "mixed-order key, k = 0 mod 8": ([votes[True]] + POOL[:3], True),
+        "mixed-order key, k != 0 mod 8": ([votes[False]] + POOL[:3], False),
+        "torsion in R": ([(m, pk, ref.encode_point(r_mix) + sig[32:])]
+                         + POOL[1:4], False),
+    }
+
+    def inputs(batch):
+        prep = eddsa.prepare_batch(*map(list, zip(*batch)))
+        assert prep["host_ok"].all()
+        rows = np.pad(prep["packed"], [(0, 8 - len(batch)), (0, 0)])
+        z = np.zeros((8, 32), np.uint8)
+        z[:len(batch)] = eddsa._rlc_coeffs(prep["packed"], b"")
+        return rows, z
+
+    before = {name: bool(E.verify_rlc_packed_jit(*inputs(batch)))
+              for name, (batch, _) in batches.items()}
+    assert before == {name: want for name, (_, want) in batches.items()}
+
+    monkeypatch.setattr(kern, "interpret_default", lambda: False)
+    # Fresh function objects: jit caches traces by function, and the
+    # route is read at trace time.
+    verifier = make_sharded_rlc_verifier(make_mesh(2)) \
+        if program == "sharded" \
+        else jax.jit(lambda p, z: E.verify_rlc_packed(p, z))
+    for name, (batch, _) in batches.items():
+        assert bool(verifier(*inputs(batch))) == before[name], name
+
+
 @pytest.mark.slow
 def test_rlc_at_quorum_256_matches_and_is_measured():
     """The n=256 MSM bench shape: one combined check over a full large

@@ -75,12 +75,35 @@ _VERIFY_PROGRAMS = {
 }
 
 
+@pytest.fixture
+def rlc_tail_on_chip(monkeypatch):
+    """The rlc programs run their serial tail as the rlc_tail kernel on
+    a TPU and as the lax scans elsewhere (ops/ed25519.rlc_tail reads
+    kern.interpret_default at trace time, the kernel's own module reads
+    its copy).  The process here is a CPU one, so both are steered HERE,
+    as for the kernels below: the programs then compile as the chip
+    runs them, Mosaic kernel inside."""
+    mod = sys.modules["hotstuff_tpu.ops.kern.rlc_tail"]
+    monkeypatch.setattr(kern, "interpret_default", lambda: False)
+    monkeypatch.setattr(mod, "interpret_default", lambda: False)
+    # The launcher under a jit of its own, dropped with the patch: its
+    # trace holds the route, and this process's other tests (one xdist
+    # worker runs several files) must keep finding the interpreter's.
+    inner = mod._tail.__wrapped__
+    monkeypatch.setattr(mod, "_tail", jax.jit(lambda w, c: inner(w, c)))
+
+
 @pytest.mark.parametrize("name", sorted(_VERIFY_PROGRAMS))
 def test_verify_program_compiles_for_v5e(one_chip, no_persistent_cache,
-                                         name):
+                                         rlc_tail_on_chip, name):
     fn, shapes = _VERIFY_PROGRAMS[name]
     args = [_shaped(one_chip, s, jnp.uint8) for s in shapes]
-    compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    # A fresh function object: jit caches traces by function, and an
+    # earlier test may have traced this one with the lax tail.
+    compiled = jax.jit(lambda *a: fn(*a), donate_argnums=0) \
+        .lower(*args).compile()
+    # The rlc programs, and only they, hold the kernel.
+    assert ("tpu_custom_call" in compiled.as_text()) == ("rlc" in name)
     mem = compiled.memory_analysis()
     # One program's footprint against one v5e chip's 16 GB of HBM.
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
@@ -103,6 +126,9 @@ _KERNELS = {
     # batch: the widest, and the one whose 16 MB table must fit VMEM.
     "msm_window_accum:2048": ("msm_accum", "_accum",
                               [(2048, 16, 4, 32), (2048, 64)]),
+    # The one shape every rlc program runs: 64 cached window sums and
+    # the 32 selected comb entries.
+    "rlc_tail:64": ("rlc_tail", "_tail", [(64, 4, 32), (32, 4, 32)]),
 }
 
 
@@ -122,7 +148,7 @@ def test_pallas_kernel_compiles_under_mosaic(one_chip, no_persistent_cache,
 
 
 def test_sharded_rlc_program_splits_its_arguments_four_ways(
-        topo, no_persistent_cache):
+        topo, no_persistent_cache, rlc_tail_on_chip):
     """The >1k-validator path: verify_rlc_sharded's program for a Mesh
     over the four described chips, at the per-shard bucket (256) of an
     N=1000 committee's 667-vote QC.  Code that has only seen forced-host
@@ -146,3 +172,5 @@ def test_sharded_rlc_program_splits_its_arguments_four_ways(
     assert compiled.memory_analysis().argument_size_in_bytes == whole // 4
     text = compiled.as_text()
     assert "all-gather" in text and "all-reduce" in text
+    # ... and every chip finishes with the rlc_tail kernel.
+    assert "tpu_custom_call" in text

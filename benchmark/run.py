@@ -266,6 +266,42 @@ def check_served_by_device(stats: dict, before: dict, config: dict,
     return bad
 
 
+def compared_numbers(stats: dict, before: dict, config: dict, pool: dict,
+                     window: dict, requests: list) -> dict:
+    """Every number ``correct`` rests on, beside its limit: {name:
+    {"value", "limit"}}.  All are exact counts; ``limit`` is the value a
+    sound run reads (0) or, for ``route_launches``, its least (">=1")."""
+    def moved(section, key):
+        return (stats.get(section) or {}).get(key, 0) - \
+            (before.get(section) or {}).get(key, 0)
+
+    def of_status(*statuses):
+        return sum(r["status"] in statuses for r in requests)
+
+    guard = stats.get("guard") or {}
+    counts = {
+        "sample_disagreements": len(pool["sample"]["disagreements"]),
+        "unmeasured_wrong": window["unmeasured_wrong"],
+        "replies_wrong": of_status("mismatch"),
+        "never_answered": of_status("unanswered", "error"),
+        "cache_hits": moved("dedup", "cache_hits"),
+        "host_path_launches": (stats.get("paths") or {}).get("host", 0),
+        "wedges": guard.get("wedges", 0),
+        "host_fallback_records": guard.get("host_fallback_records", 0),
+    }
+    out = {k: {"value": v, "limit": 0} for k, v in counts.items()}
+    out["route_launches"] = {"value": moved("paths", config["route"]),
+                             "limit": ">=1"}
+    return out
+
+
+def within(check: dict) -> bool:
+    """Whether a compared number keeps to its limit."""
+    if check["limit"] == ">=1":
+        return check["value"] >= 1
+    return check["value"] == check["limit"]
+
+
 def end_to_end_values(requests, t_start, t_end, setup_s) -> dict:
     lat = arith.latencies_ms(requests, t_start, t_end)
     out = {"setup_s": setup_s,
@@ -290,6 +326,18 @@ def per_layer_values(cell: dict, run: dict, notes: dict) -> dict:
         if value is not None:
             out[name] = value
     return out
+
+
+def chips_found(devices, chips: int) -> bool:
+    """The harness's look for a chip."""
+    return devices[0].platform == "tpu" and len(devices) >= chips
+
+
+def memory_peak_bytes(devices):
+    """The peak on the fullest chip; None where the backend reports none."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in devices]
+    return max((p for p in peaks if p), default=None)
 
 
 def main(argv=None) -> int:
@@ -339,7 +387,7 @@ def run(args) -> int:
 
         counter = CompileCounter()
         devices = jax.devices()
-        platform_ok = devices[0].platform == "tpu" and len(devices) >= chips
+        platform_ok = chips_found(devices, chips)
         if not platform_ok:
             say(f"no TPU with {chips} chip(s) here "
                 f"({devices[0].platform} x{len(devices)}): rehearsal only, "
@@ -399,20 +447,16 @@ def run(args) -> int:
                  unmeasured=window["unmeasured"],
                  undrained=result["undrained_connections"])
     problems = check_served_by_device(stats, before, config, chips)
-    if window["unmeasured_wrong"]:
-        problems.append(f"{window['unmeasured_wrong']} unmeasured reply(ies) "
-                        "differ from the ground truth")
-    wrong = [r for r in requests if r["status"] == "mismatch"]
-    if wrong:
-        problems.append(f"{len(wrong)} reply(ies) differ from the ground "
-                        f"truth, first at request {wrong[0]['index']}")
+    checks = compared_numbers(stats, before, config, pool, window, requests)
+    # The replies' own counts; OP_STATS' are worded above.
+    problems += [f"{name} is {checks[name]['value']}, not 0"
+                 for name in ("unmeasured_wrong", "replies_wrong",
+                              "never_answered") if not within(checks[name])]
     compiled = counter.inside(t_start, t_done)
     notes["compiles_in_window"] = compiled
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
-             for d in devices[:chips]]
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": chips,
-              "memory_peak_bytes": max((p for p in peaks if p), default=None)}
+              "memory_peak_bytes": memory_peak_bytes(devices[:chips])}
 
     line = {"correct": not problems, "attempted": attempted,
             "failed": failed, "metrics": {}, "device": device}
@@ -478,7 +522,14 @@ def run(args) -> int:
                       f"{notes.get('profile_error')}")
     if not line["metrics"]:
         raise Refused("no metric could be read")
+    # What `correct` compared, each number beside its limit: the last
+    # key of the line and the last lines on standard error.
+    line["checks"] = checks
     print(json.dumps(line), flush=True)
+    for name, c in line["checks"].items():
+        print(f"check {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

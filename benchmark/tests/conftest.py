@@ -1,4 +1,4 @@
-"""``pytest benchmark/tests`` (CPU, seconds): the yardstick's own checks."""
+"""``pytest benchmark/tests`` (CPU; seconds, test_correct.py minutes): the yardstick's own checks."""
 
 import os
 import sys

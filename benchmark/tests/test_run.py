@@ -82,6 +82,43 @@ def test_each_way_the_device_did_not_answer_is_named(edit, word):
     assert len(problems) == 1 and word in problems[0]
 
 
+POOL = {"sample": {"checked": 258, "forged": 2, "disagreements": []}}
+WINDOW = {"unmeasured_wrong": 0}
+REQUESTS = [{"status": "ok", "index": 0}, {"status": "ok", "index": 1}]
+
+
+def test_a_sound_run_keeps_every_compared_number_to_its_limit():
+    checks = run.compared_numbers(GOOD, BEFORE, CONFIG, POOL, WINDOW,
+                                  REQUESTS)
+    assert all(run.within(c) for c in checks.values())
+    assert checks["route_launches"] == {"value": 458, "limit": ">=1"}
+    assert {c["limit"] for k, c in checks.items()
+            if k != "route_launches"} == {0}
+
+
+@pytest.mark.parametrize("name,edit", [
+    ("replies_wrong", lambda s, p, w, r: r[1].update(status="mismatch")),
+    ("never_answered", lambda s, p, w, r: r[0].update(status="unanswered")),
+    ("never_answered", lambda s, p, w, r: r[0].update(status="error")),
+    ("unmeasured_wrong", lambda s, p, w, r: w.update(unmeasured_wrong=1)),
+    ("sample_disagreements",
+     lambda s, p, w, r: p["sample"]["disagreements"].append([3, 4])),
+    ("cache_hits", lambda s, p, w, r: s["dedup"].update(cache_hits=67)),
+    ("host_path_launches", lambda s, p, w, r: s["paths"].update(host=2)),
+    ("wedges", lambda s, p, w, r: s["guard"].update(wedges=1)),
+    ("host_fallback_records",
+     lambda s, p, w, r: s["guard"].update(host_fallback_records=67)),
+    ("route_launches", lambda s, p, w, r: s["paths"].update(rlc=2)),
+])
+def test_each_compared_number_leaves_its_limit_alone(name, edit):
+    args = copy.deepcopy((GOOD, POOL, WINDOW, REQUESTS))
+    edit(*args)
+    stats, pool, window, requests = args
+    checks = run.compared_numbers(stats, BEFORE, CONFIG, pool, window,
+                                  requests)
+    assert [k for k, c in checks.items() if not run.within(c)] == [name]
+
+
 def test_end_to_end_values_leave_out_what_has_no_sample():
     reqs = [{"t_send": 1.0, "t_reply": 1.5, "sigs": 8, "status": "ok"}]
     full = run.end_to_end_values(reqs, 0.0, 2.0, 12.5)

@@ -134,13 +134,27 @@ def test_new_layer_files_load_and_agree_with_the_manifests(metric, mix):
                                          "BENCHMARK.tracing.json")}
     for which, manifest in manifests.items():
         entries = {e["name"]: e for e in _load(manifest)["per_layer"]}
-        if mix == "flood":     # layer file only, until its cell lands
-            assert layer["name"] not in entries
+        if mix == "flood" and which == "tracing":
+            assert layer["name"] not in entries   # that manifest is solo's
             continue
         entry = entries[layer["name"]]
         for key in ("name", "unit", "better", "source", "layer", "moves"):
             assert layer[key] == entry[key], (which, key)
         assert len(entry["workloads"]) == 1
+        if mix == "flood":
+            assert entry["workloads"] == ["eddsa1024.flood"]
+
+
+def test_every_flood_layer_file_has_its_entry():
+    """The cell has landed: every ``*.flood.json`` is in BENCHMARK.json."""
+    names = {os.path.basename(p)[:-5] for p in
+             glob.glob(os.path.join(BENCH, "layers", "*.flood.json"))}
+    entries = {e["name"]: e for e in
+               _load(os.path.join(REPO, "BENCHMARK.json"))["per_layer"]}
+    assert len(names) == 15 and names <= set(entries)
+    for name in names:
+        assert entries[name]["workloads"] == ["eddsa1024.flood"]
+        assert entries[name]["moves"] == "verify_sigs_per_s"
 
 
 def test_the_tracing_rehearsal_manifest_is_whole():

@@ -346,8 +346,12 @@ def verify_batch_rlc(msgs, pks, sigs, *, pad: bool = True) -> np.ndarray:
     batch bisects (fresh coefficients per sub-batch) down to
     RLC_MIN_MSM, below which the per-signature path pinpoints each bad
     vote — so the returned mask always matches verify_batch exactly,
-    valid or not; an adversary can make us pay the old per-signature
-    price, never accept a bad vote (up to the 2^-128 RLC bound).
+    valid or not.  An adversary can never make us accept a bad vote (up
+    to the 2^-128 RLC bound), but can make us pay MORE than the
+    per-signature price: the bisection runs one device program after
+    another, two a level, so ONE forged vote among 67 costs ten
+    programs where the per-signature ladder would be one launch
+    (PERF.md §5, the cell ``qc100f33.byz``).
 
     Batches beyond MAX_SUBBATCH fall back to the per-signature chunked
     path (the MSM's conv group count scales with batch, and quorums that
@@ -358,7 +362,7 @@ def verify_batch_rlc(msgs, pks, sigs, *, pad: bool = True) -> np.ndarray:
 
 
 def verify_batch_rlc_submit(msgs, pks, sigs, *, pad: bool = True,
-                            on_bisect=None):
+                            on_bisect=None, on_resolved=None):
     """Dispatch the combined RLC check WITHOUT fetching its verdict.
 
     Returns a zero-argument ``fetch`` producing the (N,) bool mask
@@ -367,28 +371,35 @@ def verify_batch_rlc_submit(msgs, pks, sigs, *, pad: bool = True,
     :func:`verify_batch_submit`.  The all-valid steady state stays fully
     asynchronous (one dispatched MSM, verdict read at fetch); only a
     failed combined check falls back to synchronous bisection inside
-    ``fetch`` — the adversarial slow path, which already pays
-    per-signature prices.  ``on_bisect`` (if given) is invoked once when
-    that happens — how the scheduler's telemetry counts ``rlc_bisect``
-    launches without the crypto layer importing it.
+    ``fetch`` — the adversarial slow path: one device program after
+    another on the fetching thread (:func:`_rlc_resolve`).
+    ``on_bisect`` (if given) is invoked once when that happens, and
+    ``on_resolved(programs, rows_per_sig, bad_rows)`` once when the mask
+    is complete — how the scheduler's telemetry counts ``rlc_bisect``
+    launches and the ``bisect`` totals without the crypto layer
+    importing it.
 
     Host-canonicality failures and degenerate sizes (fewer than
     RLC_MIN_MSM canonical rows, or more than MAX_SUBBATCH) dispatch the
     per-signature program instead — same contract, same mask.
     """
     return verify_batch_rlc_pack(msgs, pks, sigs, pad=pad,
-                                 on_bisect=on_bisect)()
+                                 on_bisect=on_bisect,
+                                 on_resolved=on_resolved)()
 
 
 def verify_batch_rlc_pack(msgs, pks, sigs, *, pad: bool = True,
-                          on_bisect=None, trace=NO_LAUNCH):
+                          on_bisect=None, on_resolved=None,
+                          trace=NO_LAUNCH):
     """Pack stage of the combined RLC check: host preparation, the
     coefficient PRF, bucket padding and the h2d transfers happen here;
     the returned ``dispatch()`` fires the donated one-MSM program and
     returns the ``fetch`` described on :func:`verify_batch_rlc_submit`
     (which is this function's two-stage wrapper).  ``trace`` as on
     :func:`verify_batch_pack`; a failed combined check adds one
-    ``bisect`` span around the whole resolution."""
+    ``bisect`` span around the whole resolution (tags ``launches``,
+    ``bad``, ``n``) and, under it, one ``bisect_step`` span a device
+    program the resolution ran."""
     n = len(msgs)
     if n == 0:
         return lambda: (lambda: np.zeros((0,), bool))
@@ -436,11 +447,18 @@ def verify_batch_rlc_pack(msgs, pks, sigs, *, pad: bool = True,
             if on_bisect is not None:
                 on_bisect()
             mid = m // 2
-            with trace.stage("bisect") as tags:
-                launches = _rlc_resolve(packed, idx[:mid], mask, b"L", pad) \
-                    + _rlc_resolve(packed, idx[mid:], mask, b"R", pad)
+            bisect = trace.stage("bisect")
+            with bisect as tags:
+                left = _rlc_resolve(packed, idx[:mid], mask, b"L", pad,
+                                    trace, bisect.id)
+                right = _rlc_resolve(packed, idx[mid:], mask, b"R", pad,
+                                     trace, bisect.id)
+                programs = left[0] + right[0]
+                bad = m - int(np.count_nonzero(mask[idx]))
                 if tags is not None:
-                    tags["launches"] = launches
+                    tags.update(launches=programs, bad=bad, n=m)
+            if on_resolved is not None:
+                on_resolved(programs, left[1] + right[1], bad)
             return mask
 
         return fetch
@@ -449,30 +467,48 @@ def verify_batch_rlc_pack(msgs, pks, sigs, *, pad: bool = True,
 
 
 def _rlc_resolve(packed: np.ndarray, indices: np.ndarray,
-                 out: np.ndarray, salt: bytes, pad: bool) -> int:
+                 out: np.ndarray, salt: bytes, pad: bool,
+                 trace=NO_LAUNCH, parent: int | None = None) -> tuple:
     """Resolve ``out[indices]`` for host-canonical rows: combined RLC
-    check first, bisection on failure, per-signature floor.  Returns the
-    number of device programs it ran."""
+    check first, bisection on failure, per-signature floor.  Every
+    device program runs to its verdict before the next is staged, on
+    the calling (fetching) thread; one forged vote costs two programs a
+    level — ten for a 67-vote certificate: 33/34, 16/17, 8/9, 4/5 and
+    two per-signature leaves (PERF.md §5).  Returns (device programs it
+    ran, rows a per-signature leaf resolved).  On a traced launch each
+    program is one ``bisect_step`` span (``parent``: the ``bisect``
+    span), from before the rows are staged to the verdict on the
+    host."""
     n = len(indices)
     if n == 0:
-        return 0
-    if n < RLC_MIN_MSM or n > MAX_SUBBATCH:
-        rows = np.ascontiguousarray(packed[indices])
-        out[indices] = verify_prepared_rows(rows, n, pad=pad)
-        return 1
+        return 0, 0
     rows = np.ascontiguousarray(packed[indices])
     m = _bucket(n) if pad else n
+    if n < RLC_MIN_MSM or n > MAX_SUBBATCH:
+        with trace.stage("bisect_step", parent) as tags:
+            verdicts = verify_prepared_rows(rows, n, pad=pad)
+            if tags is not None:
+                tags.update(n=n, route="per_sig", depth=len(salt), bucket=m,
+                            ok=bool(verdicts.all()))
+        out[indices] = verdicts
+        return 1, n
     z = np.zeros((m, 32), np.uint8)
     z[:n] = _rlc_coeffs(rows, salt)
     if m != n:
         rows = np.pad(rows, [(0, m - n), (0, 0)])
     # Fresh host arrays -> fresh device buffers; the launch donates arg 0
     # (same discipline as _dispatch_rows).
-    ok = bool(np.asarray(E.verify_rlc_packed_donated(
-        jnp.asarray(rows), jnp.asarray(z))))
+    with trace.stage("bisect_step", parent) as tags:
+        ok = bool(np.asarray(E.verify_rlc_packed_donated(
+            jnp.asarray(rows), jnp.asarray(z))))
+        if tags is not None:
+            tags.update(n=n, route="rlc", depth=len(salt), bucket=m, ok=ok)
     if ok:
         out[indices] = True
-        return 1
+        return 1, 0
     mid = n // 2
-    return 1 + _rlc_resolve(packed, indices[:mid], out, salt + b"L", pad) \
-        + _rlc_resolve(packed, indices[mid:], out, salt + b"R", pad)
+    left = _rlc_resolve(packed, indices[:mid], out, salt + b"L", pad,
+                        trace, parent)
+    right = _rlc_resolve(packed, indices[mid:], out, salt + b"R", pad,
+                         trace, parent)
+    return 1 + left[0] + right[0], left[1] + right[1]

@@ -8,8 +8,8 @@ package makes every claim attributable to a *place in the pipeline*:
     The span record schema and the buffered :class:`Tracer` the
     sidecar threads its hot path through: one span tree a request
     (request -> decode, queue, reply) and one a launch (pack, dispatch,
-    device -> h2d, fetch_wait, d2h, bisect), on one clock, ``t`` the
-    END and ``t0`` the start.  Timestamps always come from the injected
+    device -> h2d, fetch_wait, d2h, bisect -> bisect_step), on one
+    clock, ``t`` the END and ``t0`` the start.  Timestamps always come from the injected
     clock — graftlint's ``unclosed-span`` checker enforces both that
     and the begin/end pairing discipline.
 

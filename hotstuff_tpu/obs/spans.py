@@ -29,10 +29,12 @@ later).  Per-request spans (``request``, ``decode``, ``queue``,
 counter — ``pack``, ``dispatch`` and ``device`` with ``parent`` null,
 their children (``h2d``, ``fetch_wait``, ``d2h``, ``bisect``) with
 ``parent`` = the id of the launch's ``device`` span
-(:class:`LaunchScope`).  A request's ``queue`` span names the ``lid`` it
-left for and a ``pack`` span the ``rids`` it coalesced, so a request can
-be followed through a coalesced launch.  Everything else is free-form
-tags.
+(:class:`LaunchScope`), and a ``bisect``'s own children
+(``bisect_step``, one a device program the bisection ran) with
+``parent`` = the id of that ``bisect`` span.  A request's ``queue``
+span names the ``lid`` it left for and a ``pack`` span the ``rids`` it
+coalesced, so a request can be followed through a coalesced launch.
+Everything else is free-form tags.
 
 The sink keeps spans in memory: a record is appended to a list under the
 lock — no ``json.dumps``, no I/O under it — and ``close()`` writes them.
@@ -92,6 +94,7 @@ class _NullStage:
     reads no clock, allocates nothing."""
 
     __slots__ = ()
+    id = None
 
     def __enter__(self):
         return None
@@ -277,16 +280,18 @@ class LaunchScope:
             self._tracer.record(stage, t0, t, id=id, lid=self.lid,
                                 parent=None, **tags)
 
-    def stage(self, stage: str):
+    def stage(self, stage: str, parent: int | None = None):
         """``with scope.stage("h2d") as tags:`` — one child span of the
         launch's ``device`` span around the block, with the profiler
         annotation ``sidecar:<stage>`` beside it where the tracer has
         one.  ``tags`` is a dict the block may add to (``tags["bytes"]
         = ...``), or None when tracing is off: guard tag building on
-        it."""
+        it.  The object handed out has the span's ``id`` (None when
+        tracing is off), which a grandchild names as its ``parent``: a
+        ``bisect_step`` is the child of its ``bisect`` span."""
         if not self.enabled:
             return _NULL_STAGE
-        return _StageCtx(self, stage)
+        return _StageCtx(self, stage, parent)
 
     def annotate(self, stage: str):
         """The profiler annotation alone (for a span the caller records
@@ -301,11 +306,15 @@ NO_LAUNCH = LaunchScope(None, None)
 
 
 class _StageCtx:
-    __slots__ = ("_scope", "_stage", "_tags", "_t0", "_annot")
+    __slots__ = ("_scope", "_stage", "_tags", "_t0", "_annot", "_parent",
+                 "id")
 
-    def __init__(self, scope: LaunchScope, stage: str):
+    def __init__(self, scope: LaunchScope, stage: str,
+                 parent: int | None = None):
         self._scope = scope
         self._stage = stage
+        self._parent = parent if parent is not None else scope.device_id
+        self.id = scope._tracer.next_id()
         self._tags: dict = {}
         self._t0 = 0.0
         self._annot = None
@@ -322,8 +331,9 @@ class _StageCtx:
         self._annot.__exit__(exc_type, exc, tb)
         if exc_type:
             self._tags["error"] = True
-        scope._tracer.record(self._stage, self._t0, t, lid=scope.lid,
-                             parent=scope.device_id, **self._tags)
+        scope._tracer.record(self._stage, self._t0, t, id=self.id,
+                             lid=scope.lid, parent=self._parent,
+                             **self._tags)
         return False
 
 

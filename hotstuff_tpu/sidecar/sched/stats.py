@@ -75,6 +75,12 @@ class SchedStats:
         self.pad_waste_sigs = 0          # padded slots left empty
         self.bulk_fill_sigs = 0          # padded slots used by bulk fill
         self.paths: dict[str, int] = {}  # per_sig / rlc / rlc_bisect / ...
+        # What failed combined checks cost (crypto/eddsa.py
+        # verify_batch_rlc_pack's fetch): batches bisected (what
+        # paths.rlc_bisect counts), device programs the resolutions ran,
+        # rows a per-signature leaf resolved, rows found false.
+        self.bisect = {"batches": 0, "programs": 0, "rows_per_sig": 0,
+                       "bad_rows": 0}
         self.admitted: dict[str, int] = {}
         self.queue_full: dict[str, int] = {}
         self.carries: dict[str, int] = {}
@@ -212,6 +218,22 @@ class SchedStats:
         with self._lock:
             self.paths[path] = self.paths.get(path, 0) + 1
 
+    def note_bisect(self):
+        """One batch whose combined check failed: ``paths.rlc_bisect``
+        and ``bisect.batches`` together."""
+        with self._lock:
+            self.paths["rlc_bisect"] = self.paths.get("rlc_bisect", 0) + 1
+            self.bisect["batches"] += 1
+
+    def note_bisect_resolved(self, programs: int, rows_per_sig: int,
+                             bad_rows: int):
+        """The bisection of one batch is complete (single-chip route;
+        the mesh's resolution reports no totals)."""
+        with self._lock:
+            self.bisect["programs"] += programs
+            self.bisect["rows_per_sig"] += rows_per_sig
+            self.bisect["bad_rows"] += bad_rows
+
     def note_mesh_launch(self, buckets):
         """One scheduler launch dispatched onto the mesh: counted ONCE,
         with every per-slice shard bucket recorded in the histogram.
@@ -300,6 +322,7 @@ class SchedStats:
                 "pad_waste_sigs": self.pad_waste_sigs,
                 "bulk_fill_sigs": self.bulk_fill_sigs,
                 "paths": dict(self.paths),
+                "bisect": dict(self.bisect),
                 "admitted": dict(self.admitted),
                 "queue_full": dict(self.queue_full),
                 "carries": dict(self.carries),

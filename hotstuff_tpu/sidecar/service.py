@@ -1010,7 +1010,8 @@ class VerifyEngine:
         here instead.  ``scope`` is the tracer bound to this launch
         (``_begin_launch``): the ``pack`` span is written here, and the
         single-chip pack functions write the launch's ``h2d``,
-        ``fetch_wait``, ``d2h`` and ``bisect`` spans through it.
+        ``fetch_wait``, ``d2h``, ``bisect`` and ``bisect_step`` spans
+        through it.
 
         Verdict cache: signature validity is a pure function of the
         (msg, pk, sig) bytes, so records already verified are answered
@@ -1085,20 +1086,18 @@ class VerifyEngine:
         if device_records:
             stats.note_path(path)
 
-        def on_bisect():
-            stats.note_path("rlc_bisect")
-
         if not device_records:
             dispatchers = []
         elif path == vsched.PATH_RLC:
             from ..crypto import eddsa
 
             dispatchers = [eddsa.verify_batch_rlc_pack(
-                m_msgs, m_pks, m_sigs, on_bisect=on_bisect, trace=scope)]
+                m_msgs, m_pks, m_sigs, on_bisect=stats.note_bisect,
+                on_resolved=stats.note_bisect_resolved, trace=scope)]
         elif path in (vsched.PATH_RLC_SHARDED, vsched.PATH_LADDER_SHARDED,
                       vsched.PATH_SCAN_SHARDED, vsched.PATH_MESH):
             dispatchers = self._pack_sharded(path, m_msgs, m_pks, m_sigs,
-                                             on_bisect)
+                                             stats.note_bisect)
         elif path == vsched.PATH_HOST:
             # Host verification is pure host work — it runs right here on
             # the pack worker (per sub-batch, the pre-scheduler slicing

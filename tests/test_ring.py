@@ -454,7 +454,11 @@ def test_tick_key_rides_the_staged_bucket(fake_ring):
 # ---------------------------------------------------------------------------
 
 def _cadence_engine(**kw):
-    g = LaunchGuard(deadlines=LaunchDeadlines(**FAST))
+    # No test that boots through here injects a wedge: deadlines a busy
+    # host cannot overrun (FAST's 0.15 s grace tripped under six xdist
+    # workers and the reboot disengaged the ring: ROADMAP D4).
+    g = LaunchGuard(deadlines=LaunchDeadlines(
+        **dict(FAST, warm_grace_s=10.0, min_deadline_s=10.0)))
     engine = VerifyEngine(
         use_host=True, guard=g,
         ring_factory=lambda e: CadenceRing(e, depth=RingDepth(pinned=2)),

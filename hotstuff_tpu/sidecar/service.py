@@ -32,6 +32,7 @@ import socket
 import socketserver
 import sys
 import threading
+from collections import OrderedDict
 from time import monotonic
 
 import numpy as np
@@ -291,8 +292,8 @@ class VerifyEngine:
         # tell a CPU sidecar from a TPU one.  Host-mode engines hold no
         # device and keep None.
         self.device_info = None
-        # (msg, pk, sig) -> bool verdict; see _cache_verdict.
-        self._verdicts: dict = {}
+        # (msg, pk, sig) -> bool verdict, oldest first; see _cache_verdicts.
+        self._verdicts: OrderedDict = OrderedDict()
         self._verdicts_lock = threading.Lock()
         # graftfleet dedup accounting: the verdict cache is keyed on
         # record BYTES, so under a shared fleet a QC gossiped to N
@@ -302,10 +303,14 @@ class VerifyEngine:
         # lookups), inbatch_hits records deduped within one coalesced
         # batch, misses records that actually rode a verify path.  The
         # hit-rate rides OP_STATS (``dedup``) and the strict parser
-        # asserts it is non-zero under the greedy-flood drill.
+        # asserts it is non-zero under the greedy-flood drill.  inserts
+        # counts verdicts written to the cache and evictions the entries
+        # the cap pushed out for them (_cache_verdicts).
         self._dedup_cache_hits = 0
         self._dedup_inbatch_hits = 0
         self._dedup_misses = 0
+        self._dedup_inserts = 0
+        self._dedup_evictions = 0
         # graftguard: the launch supervisor (sidecar/guard.py).  When
         # attached (serve() always attaches one; direct embedders and
         # legacy tests may run bare), every staged dispatch/fetch wait
@@ -421,6 +426,8 @@ class VerifyEngine:
                 "inbatch_hits": self._dedup_inbatch_hits,
                 "misses": self._dedup_misses,
                 "hit_rate": round(hits / seen, 4) if seen else 0.0,
+                "inserts": self._dedup_inserts,
+                "evictions": self._dedup_evictions,
             }
         snap["tenant_caps"] = self._sched.tenant_caps()
         occupancy = self._sched.tenant_occupancy()
@@ -445,9 +452,9 @@ class VerifyEngine:
     def cached_verdicts(self, request):
         """[bool] if EVERY (msg, pk, sig) record of this Ed25519 verify
         request already has a cached verdict, else None.  Called from
-        connection threads (see _Handler.handle's fast path); the engine
-        thread is the only writer, so a concurrent eviction can at worst
-        turn a hit into a miss."""
+        connection threads (see _Handler.handle's fast path) without the
+        lock the writers hold (_cache_verdicts): a concurrent eviction can
+        at worst turn a hit into a miss."""
         verdicts = self._verdicts
         out = []
         for rec in zip(request.msgs, request.pks, request.sigs):
@@ -1011,7 +1018,7 @@ class VerifyEngine:
         (``_begin_launch``): the ``pack`` span is written here, and the
         single-chip pack functions write the launch's ``h2d``,
         ``fetch_wait``, ``d2h``, ``bisect`` and ``bisect_step`` spans
-        through it.
+        through it; the returned fetch writes ``cache_insert``.
 
         Verdict cache: signature validity is a pure function of the
         (msg, pk, sig) bytes, so records already verified are answered
@@ -1150,10 +1157,14 @@ class VerifyEngine:
                 fresh = []
                 for f in fetchers:
                     fresh.extend(f())
+                verdicts = list(zip(uniq_records, map(bool, fresh)))
+                with scope.stage("cache_insert") as tags:
+                    evicted = self._cache_verdicts(verdicts)
+                    if tags is not None:
+                        tags["n"] = len(verdicts)
+                        tags["evicted"] = evicted
                 mask = list(cached)
-                for record, ok in zip(uniq_records, fresh):
-                    ok = bool(ok)
-                    self._cache_verdict(record, ok)
+                for record, ok in verdicts:
                     for i in uniq[record]:
                         mask[i] = ok
                 return mask
@@ -1234,25 +1245,41 @@ class VerifyEngine:
     # Verdict-cache capacity: ~224 B/record key; 64k entries ~ 15 MB.
     VERDICT_CACHE_CAP = 64 * 1024
 
-    def _cache_verdict(self, record, ok: bool):
-        # Bounded FIFO (dicts preserve insertion order); False verdicts
-        # are cached too — validity is deterministic in the record bytes,
-        # so a poisoned entry can only ever answer for the same forged
-        # bytes, and the cap bounds an attacker to evicting, not growing.
+    def _cache_verdicts(self, verdicts) -> int:
+        """Write one launch's ``(record, ok)`` pairs into the verdict
+        cache under ONE hold of the lock; returns how many entries the
+        cap evicted for them."""
+        # Bounded FIFO by FIRST insertion: an OrderedDict gives up its
+        # oldest entry in constant time (a plain dict keeps its deleted
+        # entries at the front until a resize, so ``next(iter(d))``
+        # walked every earlier eviction: PERF.md sec. 6, PR 32).  A
+        # record written again keeps its place and evicts nothing, and
+        # a hit never moves an entry.  False verdicts are cached too —
+        # validity is deterministic in the record bytes, so a poisoned
+        # entry can only ever answer for the same forged bytes, and the
+        # cap bounds an attacker to evicting, not growing.
         #
         # graftguard changed the threading story that used to make this
         # lock-free: dispatch/fetch closures now execute on the guard's
         # DISPOSABLE launch threads, and an abandoned (wedged) launch
         # may complete late, concurrent with a fresh launch's fetch —
-        # two writers.  The explicit lock makes the insert+evict pair
-        # atomic; readers (connection threads' fast path, _pack's
-        # cached-lookup) stay lockless — a dict read under the GIL can
-        # at worst turn a hit into a miss, exactly as before.
+        # two writers.  The explicit lock makes each insert+evict pair
+        # atomic (and the two counters, written nowhere else); readers
+        # (connection threads' fast path, _pack's cached-lookup) stay
+        # lockless — ``get`` is the dict's own, and a read under the GIL
+        # can at worst turn a hit into a miss, exactly as before.
+        cache, cap = self._verdicts, self.VERDICT_CACHE_CAP
+        evicted = 0
         with self._verdicts_lock:
-            if record not in self._verdicts:
-                while len(self._verdicts) >= self.VERDICT_CACHE_CAP:
-                    self._verdicts.pop(next(iter(self._verdicts)))
-            self._verdicts[record] = ok
+            for record, ok in verdicts:
+                if record not in cache:
+                    while len(cache) >= cap:
+                        cache.popitem(last=False)
+                        evicted += 1
+                cache[record] = ok
+            self._dedup_inserts += len(verdicts)
+            self._dedup_evictions += evicted
+        return evicted
 
     def _bls_guard_key(self, req) -> str:
         """Launch-shape key for BLS work under the guard's per-shape
@@ -1313,7 +1340,7 @@ class VerifyEngine:
                 return
             replied[0] = True
             if cacheable and cache_key is not None and payload:
-                self._cache_verdict(cache_key, bool(payload[0]))
+                self._cache_verdicts([(cache_key, bool(payload[0]))])
             item.reply_fn(payload)
 
         key = self._bls_guard_key(req)

@@ -1303,7 +1303,9 @@ def test_thread_loop_inline_clock_fires_only_in_clock_injected_classes():
 
 
 def test_threads_rules_quiet_on_real_tree():
-    # the one worked suppression lives in sidecar/service._cache_verdict
+    # the worked suppression is the reboot thread's threading.local
+    # marker (service.VerifyEngine._reboot); _cache_verdicts writes the
+    # verdict cache and its counters under _verdicts_lock and needs none
     assert threads_checker.check(REPO) == []
 
 

@@ -669,7 +669,8 @@ def _device_route_engine(tracer):
 
 
 SPAN_STAGES = ("request", "decode", "queue", "pack", "h2d", "dispatch",
-               "device", "fetch_wait", "d2h", "bisect", "reply")
+               "device", "fetch_wait", "d2h", "bisect", "cache_insert",
+               "reply")
 
 
 @pytest.fixture(scope="module")
@@ -740,7 +741,7 @@ def test_span_tree_has_every_stage_on_one_clock(span_tree, stage):
             assert root["t0"] <= s["t0"] and s["t"] <= root["t"]
         elif stage in ("pack", "dispatch", "device"):
             assert s["parent"] is None and isinstance(s["lid"], int)
-        elif stage in ("h2d", "fetch_wait", "d2h", "bisect"):
+        elif stage in ("h2d", "fetch_wait", "d2h", "bisect", "cache_insert"):
             parent = by_id[s["parent"]]
             assert parent["stage"] == "device" and parent["lid"] == s["lid"]
             assert (f"sidecar:{stage}", {"lid": s["lid"]}) in annotations
@@ -760,6 +761,15 @@ def test_span_tree_has_every_stage_on_one_clock(span_tree, stage):
                    for s in mine)
     if stage == "bisect":
         assert len(mine) == 1 and mine[0]["launches"] >= 2
+    if stage == "cache_insert":
+        # ONE a launch, after its d2h, inside its device span; the
+        # request answered from the cache writes none.
+        assert sorted(s["lid"] for s in mine) == [1, 2]
+        assert all(s["n"] == 16 and s["evicted"] == 0 for s in mine)
+        for s in mine:
+            d2h = max(x["t"] for x in spans if x["stage"] == "d2h"
+                      and x["lid"] == s["lid"])
+            assert d2h <= s["t0"] and s["t"] <= by_id[s["parent"]]["t"]
     if stage in ("h2d", "d2h", "decode", "reply"):
         assert all(s["bytes"] > 0 for s in mine)
     if stage == "reply":

@@ -122,7 +122,7 @@ def check_coverage(root: str, must_cover) -> list:
                 "required module is missing from the tree"))
             continue
         # Targets are files, directories, or globs (timing's
-        # "scripts/exp_*.py"); a pin matches any of the three shapes.
+        # "hotstuff_tpu/obs/*.py"); a pin matches any of the three shapes.
         covered = any(
             norm == t or norm.startswith(t.rstrip("/") + "/")
             or fnmatch.fnmatch(norm, t)

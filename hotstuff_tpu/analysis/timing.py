@@ -1,5 +1,5 @@
 """graftlint timing checker: ``block_until_ready`` must not be the
-synchronization inside a timed region of the profiling scripts.
+synchronization inside a timed region of the measurement code.
 
 The repo convention for a stage timed as ``t0 = perf_counter(); out =
 fn(); <fence>; dt = perf_counter() - t0`` is to fence with a forced
@@ -8,7 +8,8 @@ when the host HOLDS the result, which is what the engine's fetch stage
 pays, and a data dependency cannot return early on any backend.
 Whether ``block_until_ready()`` alone is a sound fence here: not
 measured on the chip.  This rule keeps the convention mechanically in
-the profiling/experiment scripts, so their numbers stay comparable.
+the code that times device work (``DEFAULT_TARGETS``), so its numbers
+stay comparable.
 
 Rule:
   block-until-ready-in-timing   a ``.block_until_ready()`` call lexically
@@ -21,9 +22,9 @@ Rule:
                                 never time anything stay legal)
 
 Scope model is deliberately lexical, not dataflow: a timer read before
-and after a statement is what makes it "timed", and the profiling
-scripts are straight-line enough that this has no false positives on
-the repaired tree (fixtures in tests/test_analysis.py pin both
+and after a statement is what makes it "timed", and the scanned
+files are straight-line enough that this has no false positives on
+the tree (fixtures in tests/test_analysis.py pin both
 directions).
 """
 
@@ -36,15 +37,9 @@ import os
 from .common import Finding, apply_suppressions, parse_source, \
     read_source
 
-# Profiling / experiment scripts, relative to the repo root (globs
-# allowed): the scripts whose printed numbers feed optimization
-# decisions.  bench.py's timed loops synchronize via np.asarray already
-# and its block_until_ready uses are warmup fences; it rides along so a
-# regression there fires too.
+# Files whose clock reads feed optimization decisions, relative to the
+# repo root (globs allowed).
 DEFAULT_TARGETS = (
-    "scripts/profile_verify.py",
-    "scripts/exp_*.py",
-    "bench.py",
     # grafttrace: the obs package computes the numbers every future perf
     # claim cites — a bogus fence there poisons ALL attribution.
     "hotstuff_tpu/obs/*.py",

@@ -13,15 +13,15 @@ package is the declarative half of that testing story:
   runner.py    executes a plan against a running bench on its own
                thread, recording wall-clock timestamps per event
   recovery.py  per-fault recovery latency from the executed events and
-               the committee's commit timeline (shared by the harness
-               LogParser and bench.py's ``chaos`` headline field)
+               the committee's commit timeline (read by the harness
+               LogParser)
   netem.py     graftwan link shaping: per-host-pair WAN specs compiled
                to ``tc netem`` for fleets, with a root-free userspace
                TCP proxy (``WanProxy``) so local/CI runs exercise the
                identical plan schema
   slo.py       per-fault-class recovery SLOs: pass/fail verdicts over
-               the recovery summary (shared by LogParser notes, the
-               strict testbed assertion, and the bench headline)
+               the recovery summary (shared by LogParser notes and the
+               strict testbed assertion)
 
 The harness side (process murder, SIGSTOP partitions, sidecar chaos
 RPCs, remote ssh injection) lives in ``hotstuff_tpu/harness/faults.py``;

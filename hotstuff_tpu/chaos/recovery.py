@@ -9,9 +9,8 @@ fault.  Every event is measured — including restarts/resumes — because
 re-integration has its own recovery cost (a rebooting replica can steal
 a leader slot and force another view change).
 
-Shared by the harness LogParser (run-summary notes + strict assertion)
-and bench.py's ``chaos`` headline field, so the two never disagree on
-what "recovered" means.
+Read by the harness LogParser (run-summary notes + strict assertion):
+one place says what "recovered" means.
 """
 
 from __future__ import annotations

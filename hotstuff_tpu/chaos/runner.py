@@ -7,9 +7,9 @@ over its own injection must still let the bench finish, tear down, and
 surface the failure through the parsed summary (the LogParser treats a
 failed injection as a hard error there).
 
-The clock/sleep/wall callables are injectable: tests and bench.py's
-headline probe drive a plan through a virtual clock in microseconds;
-the harness uses the real ones.
+The clock/sleep/wall callables are injectable: tests drive a plan
+through a virtual clock in microseconds; the harness uses the real
+ones.
 """
 
 from __future__ import annotations

@@ -6,9 +6,8 @@ A fault class is the target kind plus the action (``node-kill``,
 recovery latency — first commit after the event — the class is allowed
 to cost.  ``judge`` turns ``summarize_recovery`` output into per-event
 verdicts the LogParser surfaces as notes (and raises on, under the
-strict testbed assertion) and bench.py folds into the ``chaos``
-headline, so "recovered" always means "recovered fast enough", not
-merely "eventually".
+strict testbed assertion), so "recovered" always means "recovered fast
+enough", not merely "eventually".
 
 Defaults are deliberately generous multiples of the local testbed's
 view-change budget (timeout_delay defaults to 5 s and a kill can

@@ -3,8 +3,8 @@ multi-user open-loop generator (native/src/node/rate_pacer.hpp
 ``UserLoadModel``).
 
 The C++ model drives live benches; this one drives everything that
-cannot boot a committee — the bench ``surge`` headline probe, the
-scheduler overload tests, and any harness experiment that needs a
+cannot boot a committee — the scheduler overload tests
+(tests/test_surge.py) and any harness experiment that needs a
 seeded heavy-tailed arrival stream on a virtual clock.  The two share
 one model (not one implementation): N users, each with mean-1
 heavy-tailed inter-arrival multipliers (lognormal ``exp(sigma Z -

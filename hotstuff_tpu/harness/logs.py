@@ -101,22 +101,22 @@ class LogParser:
         # labelled fields only.
         self.notes = []
         # grafttrace: the critical-path summary (note_trace) and the
-        # sampled metrics time series (note_metrics) land here for
-        # bench.py's machine-readable round trip.  graftscope adds the
+        # sampled metrics time series (note_metrics) land here,
+        # machine-readable.  graftscope adds the
         # per-replica node series accounting (hosts + divergence).
         self.trace = None
         self.metrics = None
         self.node_metrics = None
         # graftcadence: the OP_STATS ``cadence`` section (ring tick
         # rate, occupancy, pad-fill, generation drops, queue waits)
-        # lands here machine-readable for bench.py's round trip.
+        # lands here machine-readable.
         self.cadence = None
         # graftingress: the OP_STATS ``ingress`` bulk-lane feed mix
-        # (ingress-fed vs offchain-fed), machine-readable for bench.py.
+        # (ingress-fed vs offchain-fed), machine-readable.
         self.sidecar_ingress = None
         # graftfleet: cross-tenant verdict-cache dedup, the per-tenant
         # scheduler section, the node-side failover evidence, and the
-        # greedy-flood verdict — all machine-readable for bench.py.
+        # greedy-flood verdict — all machine-readable.
         self.sidecar_dedup = None
         self.sidecar_tenants = None
         self.failover = None
@@ -222,7 +222,7 @@ class LogParser:
         # and the protocol-v6 HELLO accepts per endpoint.  Surfaced so a
         # run that survived a fleet-member kill reads as exactly that;
         # machine-readable on self.failover for the strict drill check
-        # in note_chaos_events and bench.py's round trip.
+        # in note_chaos_events.
         rehomes = sum(len(findall(
             r"sidecar failover: endpoint \d+ unhealthy, "
             r"re-homed to endpoint \d+", log)) for log in nodes)
@@ -882,7 +882,7 @@ class LogParser:
             # graftcadence: a run served by the resident ring says so —
             # tick rate, pad-fill and generation accounting in the
             # CONFIG notes, the full section machine-readable on
-            # self.cadence for bench.py's round trip.
+            # self.cadence.
             cad = stats.get("cadence")
             if isinstance(cad, dict) and cad.get("ticks"):
                 self.cadence = cad
@@ -999,7 +999,7 @@ class LogParser:
     def note_trace(self, summary: dict):
         """Fold the grafttrace critical-path summary (obs/trace.py
         critical_path + sidecar_breakdown shape) into the CONFIG notes
-        and onto ``self.trace`` for bench.py's headline round trip.
+        and onto ``self.trace``.
         Best-effort like every telemetry note: a hostile summary adds
         nothing rather than raising."""
         if not isinstance(summary, dict):
@@ -1193,8 +1193,7 @@ class LogParser:
         recovery latency (first merged commit strictly after each event's
         wall stamp — hotstuff_tpu/chaos/recovery.py) as CONFIG notes,
         per-fault-class SLO verdicts (chaos/slo.py) as notes plus the
-        machine-readable summary on ``self.chaos`` for bench.py's
-        headline round trip.
+        machine-readable summary on ``self.chaos``.
 
         ``strict`` is the testbed's recovery assertion, now an SLO: a
         failed injection, ANY event with no commit after it, or a

@@ -23,8 +23,8 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-# Persistent XLA compilation cache (the same dir the sidecar and bench
-# use): the suite's wall-clock is dominated by lax.scan ladder compiles
+# Persistent XLA compilation cache (the same dir the sidecar uses): the
+# suite's wall-clock is dominated by lax.scan ladder compiles
 # that are identical run to run — cache them across sessions.  The
 # min-compile-time floor keeps trivial programs out of the cache dir.
 from hotstuff_tpu.utils.xla_cache import configure_xla_cache  # noqa: E402
@@ -137,6 +137,38 @@ def make_committee(tmp_path, nodes, timeout_delay_ms, batch_size=1000,
     params.json["mempool"]["batch_size"] = batch_size
     params.print(str(tmp_path / ".parameters.json"))
     return keys, committee, params
+
+
+def boot_without_serving(monkeypatch, tmp_path, **serve_args):
+    """Run ``service.serve(**serve_args)`` through its whole boot — the
+    engine, the guard, every warm-up leg the arguments ask for — up to
+    the point where it would listen, and return the engine it built.
+    The socket server is a stand-in whose ``serve_forever`` returns at
+    once, so serve() goes on through its own ``finally``.  The warm-up
+    manifest goes to ``tmp_path``: a test's stubbed shapes must never
+    make a later real boot look warm.  The caller stubs what it does not
+    want compiled BEFORE calling this."""
+    from hotstuff_tpu.sidecar import service
+
+    engines = []
+
+    class NeverListens:
+        def __init__(self, address, engine, chaos=None):
+            self.server_address = address
+            engines.append(engine)
+
+        def serve_forever(self, poll_interval=None):
+            pass
+
+        def server_close(self):
+            pass
+
+    monkeypatch.setenv("HOTSTUFF_TPU_COMPILE_MANIFEST",
+                       str(tmp_path / "manifest.json"))
+    monkeypatch.setattr(service, "SidecarServer", NeverListens)
+    service.serve(port=0, **serve_args)
+    (engine,) = engines
+    return engine
 
 
 @_pytest.fixture

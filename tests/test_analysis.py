@@ -609,9 +609,8 @@ def test_must_cover_gate():
         # bare pins accept any checker — including timing (exact file
         # and glob targets) and padshape
         "hotstuff_tpu/sidecar/protocol.py",
-        "bench.py",
-        "scripts/exp_xfer_streams.py",
-        "timing:bench.py",
+        "hotstuff_tpu/obs/spans.py",
+        "timing:hotstuff_tpu/obs/trace.py",
     ]) == []
     # Checker qualification is load-bearing: the sockets checker scans
     # sidecar/ too, but a hotpath-qualified pin on a file only sockets
@@ -673,9 +672,9 @@ def test_timing_rule_quiet_on_asarray_fence_and_warmup():
 
 def test_timing_rule_scopes_exclude_nested_functions():
     # The nested put() blocks, but only the OUTER scope times — and the
-    # block sits outside the outer scope's timed region (the
-    # exp_xfer_streams.py shape: per-stream put workers are fenced
-    # individually, the outer loop times the whole fan-out).
+    # block sits outside the outer scope's timed region (per-stream put
+    # workers are fenced individually, the outer loop times the whole
+    # fan-out).
     findings = tlint("""
         import time
 

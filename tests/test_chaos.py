@@ -1,8 +1,8 @@
 """graftchaos tests: plan parsing/validation, the runner's scheduling and
 error capture (virtual clock — tier-1 fast), recovery-latency math, the
 LogParser integration (notes, strict liveness assertion, chaos-events.json
-round trip, client-failure tolerance), and bench.py's chaos headline
-probe."""
+round trip, client-failure tolerance), and the TC-shaped batch a view
+change sends to the verifier."""
 
 import json
 import threading
@@ -401,130 +401,6 @@ def test_local_bench_boot_flags_carry_mesh():
     assert "--mesh" not in cmd and "--warm-rlc-sharded" not in cmd
 
 
-def test_bench_chaos_headline_probe_round_trips():
-    import bench
-
-    out = bench.chaos_headline_probe()
-    assert out["recovered"] and out["injected_ok"]
-    assert out["executed"] == out["plan_events"]
-    json.dumps(out)  # headline-safe
-    out = bench.chaos_headline_probe("1 node:0 kill; 2 node:0 restart")
-    assert out["plan_events"] == 2 and out["recovered"]
-    assert [e["action"] for e in out["events"]] == ["kill", "restart"]
-
-
-# ---------------------------------------------------------------------------
-# bench device probe: the retry loop must respect the OUTER budget (the
-# BENCH_r05.json regression — rc=124, nine retries, no JSON at all)
-# ---------------------------------------------------------------------------
-
-
-class _VirtualClock:
-    """Deterministic clock for the probe loop: a fake always-failing
-    probe advances it by its timeout (a wedge eats the full wait);
-    sleeps advance it too.  No real time passes."""
-
-    def __init__(self):
-        self.t = 0.0
-
-    def now(self):
-        return self.t
-
-    def sleep(self, dt):
-        self.t += dt
-
-    def wedged_run(self, cmd, timeout=None, **kwargs):
-        import subprocess
-
-        self.t += timeout
-        raise subprocess.TimeoutExpired(cmd, timeout)
-
-
-def test_probe_device_caps_window_against_bench_deadline(monkeypatch):
-    import bench
-
-    clock = _VirtualClock()
-    monkeypatch.setattr(bench, "_BENCH_T0", 0.0)
-    monkeypatch.setenv("HOTSTUFF_TPU_BENCH_DEADLINE", "200")
-    # The probe's own window (600 s) exceeds the outer budget: without
-    # the cap, retries would outlive the driver's timeout and the
-    # degraded JSON line would never print.
-    ok, reason = bench.probe_device(
-        window=600.0, max_attempts=99, run=clock.wedged_run,
-        sleep=clock.sleep, now=clock.now)
-    assert not ok
-    # The loop gave up with at least the emit slack left in the budget.
-    assert clock.t <= 200.0 - bench._DEADLINE_SLACK
-    assert "outer budget 200s" in reason
-
-
-def test_probe_device_exhausted_budget_probes_once_briefly(monkeypatch):
-    import bench
-
-    clock = _VirtualClock()
-    clock.t = 500.0  # already past the whole budget
-    monkeypatch.setattr(bench, "_BENCH_T0", 0.0)
-    monkeypatch.setenv("HOTSTUFF_TPU_BENCH_DEADLINE", "200")
-    calls = []
-
-    def run(cmd, timeout=None, **kwargs):
-        import subprocess
-
-        calls.append(timeout)
-        clock.t += timeout
-        raise subprocess.TimeoutExpired(cmd, timeout)
-
-    ok, _ = bench.probe_device(window=600.0, max_attempts=99, run=run,
-                               sleep=clock.sleep, now=clock.now)
-    assert not ok
-    assert calls == [5.0]  # one floor-timeout attempt, nothing more
-
-
-def test_probe_device_attempt_cap_and_success(monkeypatch):
-    import bench
-
-    monkeypatch.setattr(bench, "_BENCH_T0", 0.0)
-    monkeypatch.delenv("HOTSTUFF_TPU_BENCH_DEADLINE", raising=False)
-    clock = _VirtualClock()
-    ok, reason = bench.probe_device(
-        window=600.0, max_attempts=3, run=clock.wedged_run,
-        sleep=clock.sleep, now=clock.now)
-    assert not ok and "3x (cap 3" in reason
-
-    healthy = _VirtualClock()
-    ok, reason = bench.probe_device(
-        window=600.0, max_attempts=3,
-        run=lambda *a, **k: None, sleep=healthy.sleep, now=healthy.now)
-    assert ok and reason == ""
-
-
-def test_probe_device_deterministic_errors_bail_fast(monkeypatch):
-    import bench
-
-    monkeypatch.setattr(bench, "_BENCH_T0", 0.0)
-    monkeypatch.delenv("HOTSTUFF_TPU_BENCH_DEADLINE", raising=False)
-    clock = _VirtualClock()
-
-    def broken_run(cmd, timeout=None, **kwargs):
-        import subprocess
-
-        clock.t += 1.0
-        raise subprocess.CalledProcessError(1, cmd,
-                                            stderr=b"ImportError: nope")
-
-    ok, reason = bench.probe_device(
-        window=600.0, max_attempts=99, run=broken_run,
-        sleep=clock.sleep, now=clock.now)
-    assert not ok and "not a wedge" in reason and "ImportError" in reason
-    assert clock.t < 60.0  # quick retries, no 30 s wedge waits
-
-
-def test_mesh_rlc_headline_skips_on_zero_budget():
-    import bench
-
-    assert bench.mesh_rlc_headline(budget_s=0.0) == {"skipped": True}
-
-
 def test_local_fault_injector_signals_real_process_groups(tmp_path):
     """The signal plumbing against live (dummy) process groups: kill
     really SIGKILLs the group, pause really SIGSTOPs it (resume undoes),
@@ -839,22 +715,35 @@ def test_leader_cascade_e2e_local(tmp_path, monkeypatch):
     assert events[0]["target"] == "leader-cascade" and events[0]["ok"]
 
 
-def test_bench_viewchange_headline_probe_schema():
-    """Schema + acceptance bar of the viewchange headline field on tiny
-    committees (budget-bounded shapes compile fast), plus the zero-budget
-    skip contract."""
-    import bench
+def test_tc_shaped_batch_ejects_exactly_the_tampered_signer():
+    """The batch a view change sends to the verifier (graftview): the
+    quorum's timeout votes all sign ONE shared (round, high_qc_round)
+    digest under distinct keys.  One launch accepts the honest quorum;
+    with one tampered vote the batch rejects and its mask names exactly
+    the signer that per-signature verification rejects (the eject
+    contract of the native batched TC assembly, through the python
+    engine)."""
+    import numpy as np
 
-    out = bench.viewchange_headline(committees=(6,), repeats=1)
-    assert out["n6"]["quorum"] == 5
-    assert out["n6"]["batched_ms"] > 0 and out["n6"]["per_sig_ms"] > 0
-    assert out["n6"]["speedup"] > 0
-    eject = out["eject"]
-    assert eject["batch_rejected"] and eject["match_per_sig"]
-    assert eject["ejected"] == [eject["tampered_index"]]
-    assert out["ok"] is True
-    json.dumps(out)  # headline-safe
-    assert bench.viewchange_headline(budget_s=0.0)["skipped"] is True
+    from hotstuff_tpu.crypto import eddsa, ref_ed25519 as ref
+    from hotstuff_tpu.sidecar.sched.shapes import quorum_sigs
+
+    rng = np.random.default_rng(37)
+    shared = rng.bytes(32)
+    q = quorum_sigs(6)
+    assert q == 5
+    pks, sigs = [], []
+    for _ in range(q):
+        sk = rng.bytes(32)
+        pks.append(ref.generate_keypair(sk)[1])
+        sigs.append(ref.sign(sk, shared))
+    assert eddsa.verify_batch([shared] * q, pks, sigs).all()
+    bad_i = q // 2
+    sigs[bad_i] = sigs[bad_i][:1] + bytes([sigs[bad_i][1] ^ 0xFF]) \
+        + sigs[bad_i][2:]
+    mask = [bool(b) for b in eddsa.verify_batch([shared] * q, pks, sigs)]
+    assert mask == [ref.verify(pk, shared, sg) for pk, sg in zip(pks, sigs)]
+    assert [i for i, ok in enumerate(mask) if not ok] == [bad_i]
 
 
 def test_finish_fault_plan_fails_on_skipped_events(tmp_path, monkeypatch):

@@ -217,7 +217,7 @@ def test_chunked_batch_over_subbatch_cap():
 
 @pytest.mark.slow  # ~44 s: recompiles the ladder per flag combination
 def test_ab_flag_variants_match_reference():
-    """Every import-time A/B switch (scripts/eval_device.py knobs) must
+    """Every import-time A/B switch of ops/ed25519.py (ROADMAP D2) must
     produce reference-identical verdicts: a correctness bug in a flagged
     code path would otherwise surface only mid-A/B on a live device."""
     import importlib

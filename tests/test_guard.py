@@ -530,6 +530,44 @@ def test_rewarm_runs_on_the_device_path():
         g.close()
 
 
+_LEGS = ("_warmup", "_warmup_bls", "_warmup_bls_multi", "_warmup_bulk",
+         "_warmup_rlc", "_warmup_rlc_sharded")
+
+
+@pytest.mark.parametrize("boot,own_leg", [
+    ({}, None),
+    ({"warm_bulk": True}, "_warmup_bulk"),
+    ({"warm_rlc": True}, "_warmup_rlc"),
+    ({"mesh_devices": 4, "warm_rlc": True, "warm_rlc_sharded": True},
+     "_warmup_rlc_sharded"),
+], ids=["plain", "warm_bulk", "warm_rlc", "mesh4_warm_rlc_sharded"])
+def test_rewarm_runs_the_boots_legs_in_the_boots_order(
+        boot, own_leg, monkeypatch, tmp_path):
+    """serve() writes the order of its warm-up legs twice, once for the
+    boot and once in the ``_rewarm`` it hands the engine for a crash-only
+    reboot: the reboot must re-trace what the boot warmed, in the boot's
+    order (a bisection's smaller shapes before the larger ones that fall
+    back on them), less the BLS legs (minutes of compile; an un-warmed
+    BLS shape falls back to the host pairing under the guard)."""
+    from conftest import boot_without_serving
+    from hotstuff_tpu.sidecar import service
+
+    calls = []
+    for leg in _LEGS:
+        monkeypatch.setattr(
+            service, leg,
+            lambda *args, _leg=leg: calls.append(
+                (_leg, [a for a in args if not isinstance(a, VerifyEngine)])))
+    engine = boot_without_serving(monkeypatch, tmp_path, warm_max=32,
+                                  warm_bls=True, warm_bls_multi=3, **boot)
+    booted, calls[:] = list(calls), []
+    engine._rewarm_fn()
+    names = [leg for leg, _ in booted]
+    assert names[:3] == ["_warmup", "_warmup_bls", "_warmup_bls_multi"]
+    assert names[3:] == ([own_leg] if own_leg else [])
+    assert calls == [c for c in booted if not c[0].startswith("_warmup_bls")]
+
+
 def test_engine_without_guard_is_unchanged():
     """Legacy embedders (no guard): no guard section, no supervision
     hop, identical verdicts."""
@@ -723,142 +761,6 @@ def test_parser_quiet_without_guard_activity():
                   "device_ok": True},
     })
     assert not any(n.startswith("Sidecar guard:") for n in parser.notes)
-
-
-# ---------------------------------------------------------------------------
-# bench: kill-proof emit + guard headline
-# ---------------------------------------------------------------------------
-
-def test_bench_guard_headline_probe_passes_its_bar():
-    import bench
-
-    out = bench.guard_headline_probe()
-    assert out["ok"], out
-    assert out["masks_bit_identical"]
-    assert out["busy_during_reboot"] is True
-    assert out["wedges"] >= 1 and out["reboots"] >= 1
-    assert out["recovered"]
-    json.dumps(out)
-
-
-def test_bench_emit_writes_line_cache_first(tmp_path, monkeypatch,
-                                            capsys):
-    import bench
-
-    cache = tmp_path / "last_line.json"
-    monkeypatch.setattr(bench, "_LINE_CACHE_PATH", str(cache))
-    monkeypatch.setattr(bench, "_LAST_LINE", None)
-    bench.emit(123.0, 4.5, rlc={"n4": {"skipped": True}})
-    # the disk artifact exists and matches stdout
-    on_disk = json.loads(cache.read_text())
-    printed = json.loads(capsys.readouterr().out.strip())
-    assert on_disk == printed
-    assert on_disk["value"] == 123.0
-    assert bench._LAST_LINE == on_disk
-
-
-def test_bench_kill_handler_reemits_wedged_stage_partial(
-        tmp_path, monkeypatch, capfd):
-    """The kill-proof emit regression (round-5 review, top-next): a stage
-    wedges forever on a virtual clock, the driver's window closes
-    (SIGTERM), and the handler re-emits the partial line already
-    measured — an rc=124 round still yields a parseable artifact."""
-    import signal
-
-    import bench
-
-    monkeypatch.setattr(bench, "_LINE_CACHE_PATH",
-                        str(tmp_path / "last_line.json"))
-    monkeypatch.setattr(bench, "_LAST_LINE", None)
-    exits = []
-    handler = bench.install_kill_handlers(exit=exits.append)
-    # restore default handlers after the test
-    try:
-        # A fake wedged stage on a virtual clock: the stage never
-        # finishes, the virtual clock races past the driver's budget,
-        # and the only thing that ever ran is the partial emit below.
-        now = [0.0]
-
-        def clock():
-            return now[0]
-
-        def wedged_stage():
-            now[0] += 10_000.0  # the stage "hangs" past any budget
-            return None
-
-        bench.emit(77.0, 2.0, rlc={"n4": {"skipped": True}},
-                   note="partial: rlc stage only")
-        wedged_stage()
-        assert clock() > bench.bench_budget_s()  # the window is gone
-        handler(signal.SIGTERM, None)  # what the driver's timeout sends
-        assert exits == [0]
-        # fd-level capture: the handler writes fd 1 directly (one
-        # os.write — a torn interrupted print can never weld onto it)
-        lines = [json.loads(ln) for ln in
-                 capfd.readouterr().out.strip().splitlines() if ln]
-        final = lines[-1]
-        assert final["killed"] == "SIGTERM"
-        assert final["value"] == 77.0  # the partial measurement survived
-        assert final["rlc"] == {"n4": {"skipped": True}}
-    finally:
-        signal.signal(signal.SIGTERM, signal.SIG_DFL)
-        signal.signal(signal.SIGALRM, signal.SIG_DFL)
-
-
-def test_bench_kill_handler_without_any_line_emits_error(
-        tmp_path, monkeypatch, capfd):
-    import signal
-
-    import bench
-
-    monkeypatch.setattr(bench, "_LINE_CACHE_PATH",
-                        str(tmp_path / "last_line.json"))
-    monkeypatch.setattr(bench, "_LAST_LINE", None)
-    monkeypatch.setattr(bench, "load_cache", lambda: None)
-    exits = []
-    handler = bench.install_kill_handlers(exit=exits.append)
-    try:
-        handler(signal.SIGALRM, None)
-        assert exits == [0]
-        line = json.loads(capfd.readouterr().out.strip())
-        assert line["killed"] == "SIGALRM"
-        assert line["value"] == 0
-        assert "error" in line
-    finally:
-        signal.signal(signal.SIGTERM, signal.SIG_DFL)
-        signal.signal(signal.SIGALRM, signal.SIG_DFL)
-
-
-def test_bench_kill_handler_line_survives_a_torn_print(
-        tmp_path, monkeypatch, capfd):
-    """A SIGTERM mid-print must never weld the re-emitted line onto the
-    torn prefix: the handler's leading newline closes the partial line,
-    so the LAST line always parses."""
-    import signal
-    import sys
-
-    import bench
-
-    monkeypatch.setattr(bench, "_LINE_CACHE_PATH",
-                        str(tmp_path / "last_line.json"))
-    monkeypatch.setattr(bench, "_LAST_LINE",
-                        {"metric": "ed25519-batch-verify",
-                         "value": 9.0, "unit": "sigs/sec",
-                         "vs_baseline": 1.0})
-    exits = []
-    handler = bench.install_kill_handlers(exit=exits.append)
-    try:
-        # the interrupted print: a torn prefix with no newline
-        sys.stdout.write('{"metric": "ed25')
-        sys.stdout.flush()
-        handler(signal.SIGTERM, None)
-        out = capfd.readouterr().out
-        last = [ln for ln in out.splitlines() if ln][-1]
-        line = json.loads(last)  # must parse despite the torn prefix
-        assert line["killed"] == "SIGTERM" and line["value"] == 9.0
-    finally:
-        signal.signal(signal.SIGTERM, signal.SIG_DFL)
-        signal.signal(signal.SIGALRM, signal.SIG_DFL)
 
 
 # ---------------------------------------------------------------------------

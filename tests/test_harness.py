@@ -434,70 +434,6 @@ def test_sidecar_wait_stops_when_the_process_exited(no_sidecar_listening):
 
 
 # ---------------------------------------------------------------------------
-# bench.py headline emit: the live measurement is always the headline and
-# the cache is namespaced by the kernel-source hash (round-5 ADVICE.md
-# high: the old final emit was a monotonic ratchet a regression could
-# never lower).
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture()
-def bench_mod(tmp_path, monkeypatch):
-    import bench
-
-    monkeypatch.setattr(bench, "CACHE_PATH",
-                        str(tmp_path / "headline_cache.json"))
-    monkeypatch.setattr(bench, "_LINE_CACHE_PATH",
-                        str(tmp_path / "last_line.json"))
-    return bench
-
-
-def _emitted_lines(capsys):
-    return [json.loads(line)
-            for line in capsys.readouterr().out.strip().splitlines()]
-
-
-def test_final_emit_headline_is_live_measurement(bench_mod, capsys):
-    bench_mod.save_cache(100_000.0, 10.0, 10_000.0)  # best on record
-    bench_mod.emit_final(60_000.0, 10_000.0)         # live run regressed
-    (line,) = _emitted_lines(capsys)
-    assert line["value"] == 60_000.0, "headline must be the live reading"
-    assert line["vs_baseline"] == 6.0
-    assert line["best_on_record"] == 100_000.0
-    assert "source" not in line  # not a cached-measurement line
-
-
-def test_final_emit_no_secondary_when_live_is_best(bench_mod, capsys):
-    bench_mod.save_cache(50_000.0, 5.0, 10_000.0)
-    bench_mod.emit_final(60_000.0, 10_000.0)
-    (line,) = _emitted_lines(capsys)
-    assert line["value"] == 60_000.0
-    assert "best_on_record" not in line
-
-
-def test_cache_namespaced_by_kernel_hash(bench_mod):
-    bench_mod.save_cache(100_000.0, 10.0, 10_000.0)
-    assert bench_mod.load_cache()["value"] == 100_000.0
-    # A best recorded by different kernel sources must not answer for
-    # this tree: stamp a foreign kernel hash and reload.
-    with open(bench_mod.CACHE_PATH) as f:
-        cached = json.load(f)
-    cached["kernel"] = "0" * 16
-    with open(bench_mod.CACHE_PATH, "w") as f:
-        json.dump(cached, f)
-    assert bench_mod.load_cache() is None
-    # ... and save_cache starts fresh rather than comparing against it.
-    bench_mod.save_cache(10_000.0, 1.0, 10_000.0)
-    assert bench_mod.load_cache()["value"] == 10_000.0
-
-
-def test_save_cache_keeps_best_for_same_kernel(bench_mod):
-    bench_mod.save_cache(100_000.0, 10.0, 10_000.0)
-    bench_mod.save_cache(60_000.0, 6.0, 10_000.0)  # lower: not stored
-    assert bench_mod.load_cache()["value"] == 100_000.0
-
-
-# ---------------------------------------------------------------------------
 # grafttrace: torn-line tolerance, critical-path notes, metrics series,
 # sampled-stats fallback (PR 7)
 # ---------------------------------------------------------------------------
@@ -662,61 +598,51 @@ def test_fetch_sidecar_stats_falls_back_to_last_sample(tmp_path,
     assert not (tmp_path / "logs" / "sidecar-stats.json").exists()
 
 
-def test_trace_headline_probe_schema(bench_mod):
-    """The headline `trace` field: known skew recovered, partial trace
-    tolerated, the graftscope ctx join accounted (one joined block, one
-    verify-traced block with no chain -> join_rate 0.5), Chrome round
-    trip intact (the field rides the degraded line too, so this schema
-    is what a no-device run publishes)."""
-    out = bench_mod.trace_headline_probe()
-    assert out["roundtrip_ok"] is True
-    assert out["blocks"] == 3 and out["complete"] == 2
-    assert out["offset_applied_ms"] == pytest.approx(125.0)
-    segs = out["segments"]
-    # replica 1's skewed observations aligned BEHIND replica 0's, so
-    # the earliest-wins totals are replica 0's own
-    assert segs["proposal->commit"]["n"] == 3
-    assert segs["proposal->commit"]["p50_ms"] == pytest.approx(60.0)
-    assert segs["verify_submit->verify_reply"]["p50_ms"] == \
-        pytest.approx(20.0)
-    # graftscope: device time nested as the verify:device sub-segment,
-    # join accounting on the line
-    assert segs["verify:device"]["p50_ms"] == pytest.approx(18.0)
-    assert out["join"] == {"committed": 3, "with_verify": 2,
-                           "joined": 1, "rate": 0.5}
-    assert out["join_rate"] == 0.5
-    assert out["chrome_events"] > 0
+def test_op_stats_wire_carries_scan_section():
+    """What a client reads with OP_STATS after one latency-class and one
+    bulk-class request went through the real scheduler of a host-mode
+    engine: the snapshot survives protocol.encode_stats_reply ->
+    decode_reply_raw -> decode_stats_body, and the graftscale ``scan``
+    section and the shape registry's mesh fields ride it (zeros off a
+    mesh, but the keys are what LogParser.note_sidecar_stats reads)."""
+    import threading
 
+    import numpy as np
 
-def test_committee_scale_probe_schema(bench_mod):
-    """The headline `committee_scale` field (graftscale): QC-shaped
-    batches of 2f+1 votes per committee size through all three
-    engine-path mesh entries, keyed N<committee>, sigs/sec/chip per
-    route — the schema both the live and degraded lines publish.
-    Fixture-scale committees keep the CPU compiles tiny; the real
-    sweep (100/300/1000) runs in the bench's forced-host subprocess."""
-    out = bench_mod.committee_scale_probe(committees=(10, 22),
-                                          repeats=1, budget_s=600.0)
-    assert set(out) == {"N10", "N22"}
-    for key, committee in (("N10", 10), ("N22", 22)):
-        stats = out[key]
-        assert stats["quorum"] == 2 * committee // 3 + 1
-        for route in ("per_sig_sharded", "rlc_sharded", "scan"):
-            assert stats[f"{route}_sigs_per_s_chip"] > 0, (key, route)
-        assert stats["rlc_speedup"] > 0
-    # An exhausted budget marks remaining committees skipped instead of
-    # stalling the stage (the degraded-line discipline).
-    out = bench_mod.committee_scale_probe(committees=(10,), repeats=1,
-                                          budget_s=0.0)
-    assert out["N10"] == {"quorum": 7, "skipped": True}
+    from hotstuff_tpu.crypto import ref_ed25519 as ref
+    from hotstuff_tpu.sidecar import protocol as proto
+    from hotstuff_tpu.sidecar import sched as vsched
+    from hotstuff_tpu.sidecar.service import VerifyEngine
 
+    rng = np.random.default_rng(23)
+    msgs, pks, sigs = [], [], []
+    for _ in range(6):
+        sk, msg = rng.bytes(32), rng.bytes(32)
+        msgs.append(msg)
+        pks.append(ref.generate_keypair(sk)[1])
+        sigs.append(ref.sign(sk, msg))
+    engine = VerifyEngine(use_host=True)
+    try:
+        done = []
+        cond = threading.Condition()
 
-def test_sched_probe_carries_scan_section(bench_mod):
-    """The bench `sched` field round-trips the OP_STATS snapshot over
-    the real wire encoding — the graftscale ``scan`` section rides it
-    (zeros on the host-mode probe engine, but the schema is what a
-    mesh run's headline publishes)."""
-    out = bench_mod.sched_headline_probe()
+        def reply(mask):
+            with cond:
+                done.append(mask)
+                cond.notify()
+
+        engine.submit(proto.VerifyRequest(1, msgs[:4], pks[:4], sigs[:4]),
+                      reply, cls=vsched.LATENCY)
+        engine.submit(proto.VerifyRequest(2, msgs[4:], pks[4:], sigs[4:]),
+                      reply, cls=vsched.BULK)
+        with cond:
+            assert cond.wait_for(lambda: len(done) == 2, timeout=60.0)
+        frame = proto.encode_stats_reply(7, engine.stats_snapshot())
+    finally:
+        engine.stop()
+    opcode, rid, body = proto.decode_reply_raw(frame[4:])
+    assert (opcode, rid) == (proto.OP_STATS, 7)
+    out = proto.decode_stats_body(body)
     assert out["scan"] == {"launches": 0, "sigs": 0, "chunk_hist": {},
                            "slices_avoided": 0}
     assert out["shapes"]["mesh_chunks"] == []

@@ -4,11 +4,10 @@ frame round trip against the documented preimage construction, the
 wirecheck ``txframe-mismatch`` constant extractors and the repo-clean
 gate, the LogParser's signed-ingress accounting (verified goodput,
 strict zero-forged-committed and shard-fairness assertions), the node
-METRICS admission-verify suffix, and the bench ``users`` headline
-probe's schema + budget-skip contract."""
+METRICS admission-verify suffix, and signed frames through the
+verifier's ingress-tagged bulk lane."""
 
 import hashlib
-import importlib.util
 import os
 
 import pytest
@@ -206,42 +205,81 @@ def test_sampler_metrics_verify_suffix_is_optional():
 
 
 # ---------------------------------------------------------------------------
-# bench: the ``users`` headline probe + trend flattening
+# signed frames through the verifier's ingress-tagged bulk lane
 # ---------------------------------------------------------------------------
 
 
-def test_users_probe_schema_and_acceptance_at_small_populations():
-    import bench
+@pytest.mark.parametrize("users", [50, 120])
+def test_signed_frames_through_the_ingress_tagged_bulk_lane(users):
+    """The admission path end to end in process, at a small population:
+    the seeded generator names each arrival's user, the keyring derives
+    that user's key on FIRST arrival (one derivation a distinct user),
+    version-2 signed frames become (digest, pk, sig) admission records,
+    and INGRESS_CTX-tagged bulk requests to a host-mode engine come back
+    with exactly the forged frames rejected.  OP_STATS ``ingress`` must
+    count the lane wholly ingress-fed — the tag the mempool's
+    admission-verify stage uses is what tells it from off-chain bulk."""
+    import threading
 
-    out = bench.users_headline_probe(populations=(50, 120),
-                                     txs_per_point=24)
-    assert out["ok"], out
-    assert out["mix_forge_pct"] == 1.0
-    assert out["txs_per_point"] == 24
-    for pop in (50, 120):
-        pt = out[f"u{pop}"]
-        assert pt["point_ok"], pt
-        assert pt["users"] == pop
-        assert pt["txs"] == 24 and pt["answered"] == 24
-        assert 1 <= pt["distinct_users"] <= pop
-        # derive-on-first-arrival: exactly one derivation per user seen
-        assert pt["key_derivations"] == pt["distinct_users"]
-        assert pt["forged_sent"] >= 1          # floored at one forgery
-        assert pt["forgery_rejection_rate"] == 1.0
-        assert pt["verified"] == 24 - pt["forged_sent"]
-        assert pt["verified_goodput_sigs_per_s"] > 0
-        assert pt["bulk_ingress_share"] == 1.0  # lane fully ingress-fed
-        assert pt["bulk_ingress_sigs"] == 24
+    from hotstuff_tpu.harness.loadgen import UserLoad
+    from hotstuff_tpu.sidecar import protocol as proto
+    from hotstuff_tpu.sidecar import sched as vsched
+    from hotstuff_tpu.sidecar.service import VerifyEngine
 
+    n, batch = 24, 8
+    load = UserLoad(rate=64.0, users=users, seed=13)
+    arrivals: list = []
+    tick = 0
+    while len(arrivals) < n and tick < 4096:
+        tick += 1
+        load.arrivals(tick * 0.025, arrivals)
+    arrivals = arrivals[:n]
+    keyring = txsign.UserKeyring(seed=7, capacity=4096)
+    forged = [i in (5, n - 1) for i in range(n)]
+    frames = [
+        txsign.build_signed_tx(
+            keyring.get(user), nonce=i,
+            payload=txsign.build_payload(
+                txsign.TX_MARKER_FORGED if forged[i]
+                else txsign.TX_MARKER_FILLER, i),
+            flip_sig_bit=forged[i])
+        for i, user in enumerate(arrivals)]
+    assert 1 <= len(set(arrivals)) <= users
+    assert keyring.derivations == len(set(arrivals))
+    records = [txsign.admission_record(f) for f in frames]
 
-def test_users_probe_skips_points_past_budget():
-    import bench
+    masks: dict = {}
+    cond = threading.Condition()
 
-    out = bench.users_headline_probe(populations=(50, 120),
-                                     budget_s=-1.0)
-    assert out["u50"] == {"skipped": True}
-    assert out["u120"] == {"skipped": True}
-    assert out["ok"] is False
+    def reply_to(rid):
+        def _reply(mask):
+            with cond:
+                masks[rid] = mask
+                cond.notify_all()
+        return _reply
+
+    eng = VerifyEngine(use_host=True)
+    try:
+        rids = []
+        for b in range(0, n, batch):
+            chunk = records[b:b + batch]
+            rid = 1 + b // batch
+            assert eng.submit(
+                proto.VerifyRequest(
+                    rid, [r[0] for r in chunk], [r[1] for r in chunk],
+                    [r[2] for r in chunk], ctx=txsign.INGRESS_CTX),
+                reply_to(rid), cls=vsched.BULK)
+            rids.append(rid)
+        with cond:
+            assert cond.wait_for(lambda: all(r in masks for r in rids),
+                                 timeout=120.0)
+        snap = eng.stats_snapshot()["ingress"]
+    finally:
+        eng.stop()
+    flat = [bool(ok) for rid in rids for ok in masks[rid]]
+    assert flat == [not f for f in forged]
+    assert snap["bulk_sigs"] == n and snap["bulk_requests"] == len(rids)
+    assert snap["offchain_sigs"] == 0
 
 
 @pytest.mark.slow
@@ -279,25 +317,3 @@ def test_signed_ingress_e2e_local(tmp_path, monkeypatch):
     assert any(n.startswith("Signed ingress:") for n in parser.notes)
     # The run still commits real throughput under the signed stream.
     assert "TPS:" in parser.result()
-
-
-def test_bench_trend_flattens_users_leaves():
-    spec = importlib.util.spec_from_file_location(
-        "bench_trend", os.path.join(REPO, "scripts", "bench_trend.py"))
-    bt = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bt)
-    flat = bt.flatten_numeric({"users": {
-        "mix_forge_pct": 1.0,
-        "u100000": {"verified_goodput_sigs_per_s": 171.5,
-                    "forgery_rejection_rate": 1.0,
-                    "point_ok": True},
-        "u1000000": {"skipped": True},
-        "ok": True,
-    }})
-    assert flat["users.mix_forge_pct"] == 1.0
-    assert flat["users.u100000.verified_goodput_sigs_per_s"] == 171.5
-    assert flat["users.u100000.forgery_rejection_rate"] == 1.0
-    # booleans are flags, not measurements
-    assert "users.ok" not in flat
-    assert "users.u100000.point_ok" not in flat
-    assert "users.u1000000.skipped" not in flat

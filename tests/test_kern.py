@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -497,8 +496,8 @@ class TestCompileManifest:
     def test_fingerprint_covers_kern_sources(self):
         base = kernel_fingerprint()
         assert len(base) == 16
-        # bench's variant (extra sources) must differ from the base.
-        assert kernel_fingerprint(extra=("bench.py",)) != base
+        # A caller's own sources (extra) must change the hash.
+        assert kernel_fingerprint(extra=("chip_smoke.py",)) != base
 
 
 class TestWarmupWiring:
@@ -609,59 +608,3 @@ class TestWarmupReport:
     def test_cli_missing_manifest(self, tmp_path):
         wr = self._load()
         assert wr.main(["--manifest", str(tmp_path / "none.json")]) == 1
-
-
-class TestRooflineHeadline:
-    def test_estimate_shape(self):
-        sys.path.insert(0, REPO)
-        import bench
-
-        est = bench.roofline_estimate()
-        for key in ("int_ops_per_sig", "chip", "chip_int_ops_per_s",
-                    "roofline_sigs_per_s_chip", "field_muls_per_sig"):
-            assert key in est
-        assert est["int_ops_per_sig"] > 1e6
-        assert est["roofline_sigs_per_s_chip"] > 0
-        json.dumps(est)
-
-    def test_headline_budget_zero_skips(self):
-        sys.path.insert(0, REPO)
-        import bench
-
-        out = bench.roofline_headline(budget_s=0)
-        assert out["skipped"] is True
-        assert out["est"]["roofline_sigs_per_s_chip"] > 0
-        assert out["kern_default"] in ("lax", "pallas")
-        json.dumps(out)
-
-    @pytest.mark.slow
-    def test_headline_measures_both_routes(self):
-        # kern_gate lane: one small size through BOTH routes (the
-        # pallas entry is interpreter-flagged on this backend).
-        sys.path.insert(0, REPO)
-        import bench
-
-        out = bench.roofline_headline(sizes=(8,), repeats=1,
-                                      budget_s=600.0)
-        stats = out["n8"]
-        assert stats["lax"]["sigs_per_s_chip"] > 0
-        assert stats["pallas"]["sigs_per_s_chip"] > 0
-        assert stats["pallas"].get("interpreted") is True
-        assert "pallas_speedup" in stats
-        json.dumps(out)
-
-
-class TestMsmChunkSweep:
-    @pytest.mark.slow
-    def test_sweep_in_process(self):
-        sys.path.insert(0, REPO)
-        import bench
-        from hotstuff_tpu.ops import ed25519 as E2
-
-        default = E2.msm_window_chunk()
-        out = bench.msm_chunk_sweep(chunks=(4, 8), n=8, budget_s=300.0)
-        assert E2.msm_window_chunk() == default  # restored
-        for key in ("chunk4", "chunk8"):
-            assert out[key].get("rlc_sigs_per_s", 0) > 0 or \
-                "error" in out[key]
-        json.dumps(out)

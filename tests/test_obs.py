@@ -6,9 +6,8 @@ trace build the harness + LogParser drive.
 
 graftscope additions: the protocol-v5 context-tag round trip (legacy
 zero-tag frames included), the per-block node<->sidecar span join
-(partial chains degrade join_rate, never the trace), the C++ node's
-METRICS line reader + per-replica divergence, and the bench-trajectory
-regression ledger.
+(partial chains degrade join_rate, never the trace), and the C++
+node's METRICS line reader + per-replica divergence.
 
 All CPU-only and fast (no jax, no device, no sleeps beyond thread
 joins) — the suite runs in tier-1.
@@ -1324,292 +1323,6 @@ def test_note_trace_includes_join_rate():
     note = next(n for n in parser.notes if "Commit critical path" in n)
     assert "sidecar join 75% of 4 verify-traced" in note
     assert "verify:device p50 12 ms / p99 18 ms" in note
-
-
-# ---------------------------------------------------------------------------
-# graftscope: bench-trajectory regression ledger
-# ---------------------------------------------------------------------------
-
-
-def _bench_trend():
-    import importlib.util
-    import os
-
-    from conftest import REPO
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_trend", os.path.join(REPO, "scripts", "bench_trend.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _write_artifacts(tmp_path, *runs):
-    for name, doc in runs:
-        (tmp_path / name).write_text(json.dumps(doc))
-
-
-def test_bench_trend_best_latest_and_degraded_flags(tmp_path):
-    bt = _bench_trend()
-    _write_artifacts(
-        tmp_path,
-        ("BENCH_r01.json", {"n": 1, "rc": 0,
-                            "parsed": {"metric": "m", "value": 100.0,
-                                       "rlc": {"n64": {"speedup": 2.0}}}}),
-        ("BENCH_r02.json", {"n": 2, "rc": 0,
-                            "parsed": {"metric": "m", "value": 95.0,
-                                       "rlc": {"n64": {"speedup": 2.5}}}}),
-        # wedged round: no line at all
-        ("BENCH_r03.json", {"n": 3, "rc": 124, "parsed": None}),
-        # bare-headline degraded artifact (the surge_degraded shape)
-        ("BENCH_zz_degraded.json", {"metric": "m", "value": 5.0,
-                                    "degraded": True}),
-    )
-    trend = bt.build_trend(sorted(str(p) for p in
-                                  tmp_path.glob("BENCH_*.json")))
-    runs = {r["file"]: r for r in trend["runs"]}
-    assert not runs["BENCH_r01.json"]["degraded"]
-    assert not runs["BENCH_r02.json"]["degraded"]
-    assert runs["BENCH_r03.json"]["degraded"]
-    assert runs["BENCH_zz_degraded.json"]["degraded"]
-    v = trend["fields"]["value"]
-    assert v["best"] == 100.0 and v["best_run"] == "BENCH_r01.json"
-    assert v["latest_live"] == 95.0
-    # Degraded values stay visible as "latest" but never become best.
-    assert v["latest"] == 5.0 and v["latest_degraded"] is True
-    assert trend["fields"]["rlc.n64.speedup"]["best"] == 2.5
-    # 5% drop inside the default 20% threshold: ok.
-    assert bt.judge(trend, 0.2)["ok"] is True
-    # A 1% threshold turns the same history into a regression.
-    verdict = bt.judge(trend, 0.01)
-    assert verdict["ok"] is False and "below best" in verdict["reason"]
-
-
-def test_bench_trend_flattens_committee_scale(tmp_path):
-    """graftscale: the committee_scale headline's numeric leaves land in
-    the ledger like every other field — per-committee per-route
-    sigs/sec/chip tracked best/latest, degraded runs still excluded
-    from best."""
-    bt = _bench_trend()
-    cs = {"N100": {"quorum": 67, "per_sig_sharded_sigs_per_s_chip": 50.0,
-                   "rlc_sharded_sigs_per_s_chip": 120.0,
-                   "scan_sigs_per_s_chip": 60.0, "rlc_speedup": 2.4},
-          "N1000": {"quorum": 667, "skipped": True}}
-    _write_artifacts(
-        tmp_path,
-        ("BENCH_r01.json", {"n": 1, "rc": 0,
-                            "parsed": {"metric": "m", "value": 100.0,
-                                       "committee_scale": cs}}),
-        # A degraded line carrying larger CPU-backend numbers must not
-        # claim "best".
-        ("BENCH_zz_degraded.json", {
-            "metric": "m", "value": 5.0, "degraded": True,
-            "committee_scale": {
-                "N100": {"quorum": 67,
-                         "rlc_sharded_sigs_per_s_chip": 999.0}}}),
-    )
-    trend = bt.build_trend(sorted(str(p) for p in
-                                  tmp_path.glob("BENCH_*.json")))
-    f = trend["fields"]
-    assert f["committee_scale.N100.rlc_sharded_sigs_per_s_chip"]["best"] \
-        == 120.0
-    assert f["committee_scale.N100.rlc_sharded_sigs_per_s_chip"][
-        "latest"] == 999.0
-    assert f["committee_scale.N100.rlc_speedup"]["best"] == 2.4
-    assert f["committee_scale.N100.quorum"]["best"] == 67
-    # The skipped committee contributes only its quorum (bools and the
-    # skipped flag are not measurements).
-    assert "committee_scale.N1000.skipped" not in f
-    assert f["committee_scale.N1000.quorum"]["latest"] == 667
-
-
-def test_bench_trend_flattens_cadence(tmp_path):
-    """graftcadence: the cadence headline's numeric leaves (ring-vs-
-    staged sigs/sec per depth, queue-wait p99, pad-fill ratio) land in
-    the ledger like every other field, and a degraded line's larger
-    CPU-backend cadence numbers never claim best."""
-    bt = _bench_trend()
-    cad = {"staged_sigs_per_s": 2000.0,
-           "ring_k2": {"sigs_per_s": 2100.0, "queue_wait_p99_ms": 40.0,
-                       "pad_fill_ratio": 0.25},
-           "ring_k8": {"skipped": True},
-           "surge_wait": {"queue_wait_p99_ms": 150.0},
-           "ok": True}
-    _write_artifacts(
-        tmp_path,
-        ("BENCH_r01.json", {"n": 1, "rc": 0,
-                            "parsed": {"metric": "m", "value": 100.0,
-                                       "cadence": cad}}),
-        ("BENCH_zz_degraded.json", {
-            "metric": "m", "value": 5.0, "degraded": True,
-            "cadence": {"staged_sigs_per_s": 9999.0,
-                        "ring_k2": {"sigs_per_s": 9999.0}}}),
-    )
-    trend = bt.build_trend(sorted(str(p) for p in
-                                  tmp_path.glob("BENCH_*.json")))
-    f = trend["fields"]
-    assert f["cadence.ring_k2.sigs_per_s"]["best"] == 2100.0
-    assert f["cadence.staged_sigs_per_s"]["best"] == 2000.0
-    # Degraded cadence values stay visible as latest, never best.
-    assert f["cadence.ring_k2.sigs_per_s"]["latest"] == 9999.0
-    assert f["cadence.ring_k2.sigs_per_s"]["latest_degraded"] is True
-    assert f["cadence.surge_wait.queue_wait_p99_ms"]["latest"] == 150.0
-    # Flags are not measurements: ok/skipped never become fields.
-    assert "cadence.ok" not in f
-    assert "cadence.ring_k8.skipped" not in f
-
-
-def test_bench_trend_flattens_fleet(tmp_path):
-    """graftfleet: the fleet headline's numeric leaves (goodput on both
-    sides of the kill, re-home wall, dedup hit rate, flood p99s) land
-    in the ledger, and a degraded line's fleet numbers never claim
-    best."""
-    bt = _bench_trend()
-    fleet = {"endpoints": 2,
-             "live_goodput_sigs_per_s": 60000.0,
-             "failover_goodput_sigs_per_s": 80000.0,
-             "rehome_ms": 120.0,
-             "rehomes": 1, "host_fallbacks": 0,
-             "masks_bit_identical": True,
-             "dedup": {"cache_hits": 500, "hit_rate": 0.9},
-             "flood": {"starvation": 0, "pre_p99_ms": 100.0,
-                       "post_p99_ms": 130.0, "judged": True,
-                       "ok": True},
-             "ok": True}
-    _write_artifacts(
-        tmp_path,
-        ("BENCH_r01.json", {"n": 1, "rc": 0,
-                            "parsed": {"metric": "m", "value": 100.0,
-                                       "fleet": fleet}}),
-        ("BENCH_zz_degraded.json", {
-            "metric": "m", "value": 5.0, "degraded": True,
-            "fleet": {"failover_goodput_sigs_per_s": 99999.0,
-                      "rehome_ms": 999.0}}),
-    )
-    trend = bt.build_trend(sorted(str(p) for p in
-                                  tmp_path.glob("BENCH_*.json")))
-    f = trend["fields"]
-    assert f["fleet.failover_goodput_sigs_per_s"]["best"] == 80000.0
-    assert f["fleet.live_goodput_sigs_per_s"]["best"] == 60000.0
-    assert f["fleet.dedup.hit_rate"]["best"] == 0.9
-    assert f["fleet.flood.post_p99_ms"]["latest"] == 130.0
-    # Degraded fleet values stay visible as latest, never best.
-    assert f["fleet.failover_goodput_sigs_per_s"]["latest"] == 99999.0
-    assert f["fleet.failover_goodput_sigs_per_s"]["latest_degraded"] \
-        is True
-    # Flags are not measurements: ok/masks booleans never become fields.
-    assert "fleet.ok" not in f
-    assert "fleet.masks_bit_identical" not in f
-    assert "fleet.flood.ok" not in f
-
-
-def test_bench_trend_flattens_dag_and_namespaces_foreign_metric(tmp_path):
-    """graftdag: the dag headline declares its OWN metric (consensus
-    tx/s, not verify sigs/s), so its numeric leaves land in the ledger
-    under a ``<metric>:``-prefixed lane — tracked best/latest with
-    degraded-excluded-from-best like every field — while the primary
-    sigs/s headline lane (and the --check judgement) never sees the
-    foreign value."""
-    bt = _bench_trend()
-    dag = {"n4": {"payload_tps": 900.0, "cert_tps": 1600.0},
-           "n10": {"payload_tps": 700.0, "cert_tps": 2500.0,
-                   "eventloop_ceiling_tps": 1000.0},
-           "chain_depth": 4, "ok": True}
-    _write_artifacts(
-        tmp_path,
-        ("BENCH_r01.json", {"n": 1, "rc": 0,
-                            "parsed": {"metric": "m", "value": 100.0}}),
-        ("BENCH_r02.json", {"n": 2, "rc": 0,
-                            "parsed": {"metric": "m", "value": 95.0}}),
-        # a LIVE dag headline with its own metric
-        ("BENCH_dag.json", {"metric": "dag-commit-tps", "value": 2500.0,
-                            "dag": dag}),
-        # a degraded dag line with larger numbers must not claim best
-        # in the dag lane either
-        ("BENCH_dag_degraded.json", {
-            "metric": "dag-commit-tps", "value": 9999.0, "degraded": True,
-            "dag": {"n10": {"cert_tps": 9999.0}}}),
-    )
-    trend = bt.build_trend(sorted(str(p) for p in
-                                  tmp_path.glob("BENCH_*.json")))
-    f = trend["fields"]
-    assert trend["headline_metric"] == "m"
-    # The dag leaves trend in their own namespaced lane.
-    assert f["dag-commit-tps:dag.n10.cert_tps"]["best"] == 2500.0
-    assert f["dag-commit-tps:dag.n4.payload_tps"]["best"] == 900.0
-    assert f["dag-commit-tps:value"]["best"] == 2500.0
-    # Degraded dag values stay visible as latest, never best.
-    assert f["dag-commit-tps:dag.n10.cert_tps"]["latest"] == 9999.0
-    assert f["dag-commit-tps:dag.n10.cert_tps"]["latest_degraded"] is True
-    assert f["dag-commit-tps:value"]["best_run"] == "BENCH_dag.json"
-    # Flags are not measurements.
-    assert "dag-commit-tps:dag.ok" not in f
-    # The PRIMARY headline lane is untouched by the foreign metric: the
-    # 2500 tx/s dag number must neither become the latest live value nor
-    # trip the regression judge against the 100-sigs/s-scale history.
-    v = f["value"]
-    assert v["best"] == 100.0 and v["latest_live"] == 95.0
-    assert v["latest_live_run"] == "BENCH_r02.json"
-    verdict = bt.judge(trend, 0.2)
-    assert verdict["ok"] is True
-    assert verdict["latest"] == 95.0 and verdict["best"] == 100.0
-
-
-def test_bench_trend_committed_history_keeps_sigs_headline():
-    """The committed repo history itself: the graftdag artifacts ride
-    the real BENCH_*.json glob, so pin — against the actual files —
-    that the primary headline lane still belongs to the verify metric
-    and still judges clean."""
-    import os
-
-    from conftest import REPO
-
-    bt = _bench_trend()
-    paths = sorted(
-        os.path.join(REPO, p) for p in os.listdir(REPO)
-        if p.startswith("BENCH_") and p.endswith(".json"))
-    assert paths, "committed BENCH_*.json artifacts missing"
-    trend = bt.build_trend(paths)
-    assert trend["headline_metric"] == "ed25519-batch-verify"
-    assert bt.judge(trend, 0.2)["ok"] is True
-
-
-def test_bench_trend_unjudgeable_histories_pass(tmp_path):
-    bt = _bench_trend()
-    # Only degraded runs: nothing to judge, never a failure.
-    _write_artifacts(
-        tmp_path,
-        ("BENCH_r01.json", {"n": 1, "rc": 3,
-                            "parsed": {"value": 0, "error": "wedged"}}))
-    trend = bt.build_trend([str(tmp_path / "BENCH_r01.json")])
-    verdict = bt.judge(trend, 0.2)
-    assert verdict["ok"] is True and verdict["judged"] is False
-    # One live run that IS the best: also unjudged, ok.
-    _write_artifacts(
-        tmp_path,
-        ("BENCH_r02.json", {"n": 2, "rc": 0, "parsed": {"value": 50.0}}))
-    trend = bt.build_trend(sorted(str(p) for p in
-                                  tmp_path.glob("BENCH_*.json")))
-    verdict = bt.judge(trend, 0.2)
-    assert verdict["ok"] is True and verdict["judged"] is False
-
-
-def test_bench_trend_cli_writes_ledger_and_exits_on_regression(tmp_path):
-    bt = _bench_trend()
-    _write_artifacts(
-        tmp_path,
-        ("BENCH_r01.json", {"n": 1, "rc": 0, "parsed": {"value": 100.0}}),
-        ("BENCH_r02.json", {"n": 2, "rc": 0, "parsed": {"value": 10.0}}))
-    out = tmp_path / "results" / "trend.json"
-    assert bt.main(["--root", str(tmp_path), "--out", str(out)]) == 0
-    ledger = json.loads(out.read_text())
-    assert ledger["schema"] == "bench-trend-v1"
-    assert ledger["check"]["ok"] is False  # recorded even without --check
-    # --check makes the 90% drop fatal.
-    assert bt.main(["--root", str(tmp_path), "--out", str(out),
-                    "--check"]) == 1
-    # No artifacts at all: usage error, not a crash.
-    assert bt.main(["--root", str(tmp_path / "empty")]) == 2
 
 
 # ---------------------------------------------------------------------------

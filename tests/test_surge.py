@@ -3,8 +3,8 @@ clock), the overlap-driven admission controller, the scheduler's
 bulk-before-latency + derated-cap policy, the OP_BUSY/retry-after wire
 round trip, the metrics-driven recovery-to-baseline SLO judge, surge
 fault-plan events, the LogParser's overload notes + strict fairness
-assertion, the bounded-ingress lint rule, and the bench ``surge``
-headline probe."""
+assertion, the bounded-ingress lint rule, and the whole backpressure
+loop under sustained overload."""
 
 import threading
 
@@ -618,20 +618,60 @@ def test_real_tree_is_ingress_clean():
 
 
 # ---------------------------------------------------------------------------
-# bench surge headline probe
+# the whole backpressure loop under sustained overload
 # ---------------------------------------------------------------------------
 
 
-def test_bench_surge_headline_probe_meets_acceptance_bar():
-    import bench
+def test_overload_loop_keeps_the_latency_class_bounded():
+    """The generator, the real scheduler and its admission controller in
+    one closed loop on a virtual clock: 4x a modeled drain capacity of
+    BULK work from 200 heavy-tailed users beside a steady consensus-class
+    stream, shed bulk requests feeding BUSY backoff into the generator.
+    No queue collapse: the consensus class is never shed and its wait
+    p99 stays within three ticks, sheds are bulk-before-latency, and the
+    generator really deferred users on the hints."""
+    tick_s, seconds, offered_x = 0.01, 1.5, 4.0
+    cap_sigs_per_tick, qc_sigs, lat_per_tick, bulk_req_sigs = 128, 16, 2, 32
+    bulk_req_rate = offered_x * (cap_sigs_per_tick / tick_s) / bulk_req_sigs
 
-    out = bench.surge_headline_probe(seconds=1.5)
-    assert out["ok"]
-    assert out["offered_x"] >= 3.0
-    assert out["latency"]["shed"] == 0
-    assert out["latency"]["wait_p99_ms"] <= 30.0
-    assert out["bulk"]["shed"] > 0
-    assert out["bulk"]["deferred_by_busy"] > 0  # BUSY loop closed
-    assert out["fairness_violations"] == 0
-    assert out["busy_roundtrip"]["ok"]
-    assert out["baseline_slo"]["ok"]
+    sched = vsched.Scheduler(latency_cap_sigs=4 * 1024,
+                             bulk_cap_sigs=8 * 1024)
+    # Coalesce at the modeled per-tick drain: the "device" is the tick
+    # budget, so launch granularity and drain speak the same units.
+    sched.shapes.launch_cap = cap_sigs_per_tick
+    adm = sched.admission
+    load = UserLoad(rate=bulk_req_rate, users=200, seed=11)
+
+    rid = 0
+    offered_at, lat_waits = {}, []
+    for k in range(1, int(round(seconds / tick_s)) + 1):
+        t = k * tick_s
+        for _ in range(lat_per_tick):
+            rid += 1
+            offered_at[rid] = t
+            sched.offer(_request(rid, qc_sigs), lambda m: None,
+                        cls=vsched.LATENCY)
+        for _ in range(load.arrivals(t)):
+            rid += 1
+            if not sched.offer(_request(rid, bulk_req_sigs),
+                               lambda m: None, cls=vsched.BULK):
+                load.busy(t, sched.retry_after_ms(vsched.BULK) / 1e3)
+        budget = cap_sigs_per_tick
+        while budget > 0:
+            launch = sched.next_launch(block=False)
+            if launch is None:
+                break
+            lat_waits += [
+                (t - offered_at.pop(p.request.request_id, t)) * 1e3
+                for p in launch.items if p.cls == vsched.LATENCY]
+            budget -= launch.total_sigs
+            # Offered load over drain capacity packs in the open
+            # (overlap collapsed): the surge regime's evidence.
+            adm.note_pack(0.001, hidden=False)
+    snap = adm.snapshot()
+    lat_waits.sort()
+    assert lat_waits[int(0.99 * (len(lat_waits) - 1))] <= 3 * tick_s * 1e3
+    assert snap["shed"].get(vsched.LATENCY, 0) == 0
+    assert snap["shed"].get(vsched.BULK, 0) > 0
+    assert snap["fairness_violations"] == 0
+    assert load.deferred > 0  # the BUSY loop closed

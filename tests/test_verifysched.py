@@ -701,7 +701,7 @@ def test_pipeline_overlap_is_a_rolling_window():
     assert pipe["overlap_ratio"] == 1.0
     assert pipe["pack_ms"] == pytest.approx(40.0)
     assert pipe["window_s"] == PIPE_WINDOW_S
-    # Lifetime keeps the whole story for bench_trend.
+    # Lifetime keeps the whole story.
     assert pipe["lifetime_pack_ms"] == pytest.approx(120.0)
     assert pipe["lifetime_overlap_ratio"] == pytest.approx(0.333,
                                                            abs=1e-3)

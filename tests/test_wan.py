@@ -351,21 +351,6 @@ def test_wanproxy_partition_heal_and_loss():
         stop_srv()
 
 
-def test_wan_headline_probe_tolerates_lossy_spec():
-    """A user --wan with loss_pct drops connections BY DESIGN; the bench
-    probe must report roundtrip_ok/healed False on a link lossy enough
-    to defeat its retries — never collapse the whole wan sub-field to an
-    error on exactly the shapes it claims to prove."""
-    import bench
-
-    out = bench.wan_headline_probe(
-        "node:0>sidecar latency_ms=1 loss_pct=100 name=lossy")
-    assert out["roundtrip_ok"] is False
-    assert out["partition_enforced"] is True
-    assert out["healed"] is False
-    assert out["links"] == ["lossy"]
-
-
 # ---------------------------------------------------------------------------
 # SLO table + verdicts
 # ---------------------------------------------------------------------------

@@ -141,7 +141,7 @@ def prepare_batch(msgs, pks, sigs):
 
 def split_packed_rows(packed: np.ndarray, host_ok=None) -> dict:
     """(n, 128) already-prepared rows -> the prepare_batch dict shape,
-    without re-deriving anything.  The RLC bisection paths slice prepared
+    without re-deriving anything.  The mesh's RLC bisection slices prepared
     rows by index and re-enter the batch verifiers with them; rows
     selected through a host_ok mask are canonical by construction, so the
     default mask is all-True."""
@@ -298,8 +298,9 @@ def verify(pk: bytes, msg: bytes, sig: bytes) -> bool:
 # ---------------------------------------------------------------------------
 
 # Below this the per-signature program is cheaper than the MSM's fixed
-# Horner/comb tail; it is also the bisection floor — sub-batches this
-# small resolve per signature, which is what pinpoints a bad vote.
+# Horner/comb tail: a batch with fewer canonical rows goes per signature
+# from the start.  The mesh (parallel/sharded_verify) also stops its
+# bisection here.
 RLC_MIN_MSM = 4
 
 _RLC_DOMAIN = b"hotstuff-tpu/rlc-batch-v1"
@@ -310,10 +311,11 @@ def _rlc_coeffs(rows: np.ndarray, salt: bytes) -> np.ndarray:
     nonzero z_i in canonical little-endian bytes (high 16 bytes zero).
 
     Deterministic per call: a SHA-512 counter-mode PRF seeded by the
-    batch CONTENT (all rows), the bisection path (``salt``) and a domain
-    tag.  Soundness needs the z_i to be unpredictable to whoever chose
-    the signatures *before* the batch was formed — hashing every row into
-    the seed gives the standard derandomized batch-verification argument:
+    batch CONTENT (all rows), ``salt`` (empty here; the mesh's bisection
+    path, parallel/sharded_verify) and a domain tag.  Soundness needs
+    the z_i to be unpredictable to whoever chose the signatures *before*
+    the batch was formed — hashing every row into the seed gives the
+    standard derandomized batch-verification argument:
     changing any bit of any signature re-randomizes every coefficient.
     128-bit coefficients put an adversarial cancellation at ~2^-128, the
     scheme's security level; anything shorter would make the combined
@@ -342,16 +344,14 @@ def verify_batch_rlc(msgs, pks, sigs, *, pad: bool = True) -> np.ndarray:
     [sum z_i S_i]B == sum [z_i]R_i + sum [z_i k_i]A_i over the whole
     batch (ops/ed25519.verify_rlc_packed).  All-valid batches — the
     steady state of quorum-certificate verification — pay one MSM
-    instead of 2n scalar ladders.  When the combined check fails, the
-    batch bisects (fresh coefficients per sub-batch) down to
-    RLC_MIN_MSM, below which the per-signature path pinpoints each bad
-    vote — so the returned mask always matches verify_batch exactly,
+    instead of 2n scalar ladders.  When the combined check fails, ONE
+    per-signature program over the batch's canonical rows pinpoints each
+    bad vote — so the returned mask always matches verify_batch exactly,
     valid or not.  An adversary can never make us accept a bad vote (up
     to the 2^-128 RLC bound), but can make us pay MORE than the
-    per-signature price: the bisection runs one device program after
-    another, two a level, so ONE forged vote among 67 costs ten
-    programs where the per-signature ladder would be one launch
-    (PERF.md §5, the cell ``qc100f33.byz``).
+    per-signature price: a forged batch costs the failed combined check
+    AND the per-signature launch, two round trips however many votes
+    are forged (PERF.md §5, the cell ``qc100f33.byz``).
 
     Batches beyond MAX_SUBBATCH fall back to the per-signature chunked
     path (the MSM's conv group count scales with batch, and quorums that
@@ -370,14 +370,14 @@ def verify_batch_rlc_submit(msgs, pks, sigs, *, pad: bool = True,
     pipeline the next launch behind this one exactly like
     :func:`verify_batch_submit`.  The all-valid steady state stays fully
     asynchronous (one dispatched MSM, verdict read at fetch); only a
-    failed combined check falls back to synchronous bisection inside
-    ``fetch`` — the adversarial slow path: one device program after
-    another on the fetching thread (:func:`_rlc_resolve`).
+    failed combined check resolves synchronously inside ``fetch`` — the
+    adversarial slow path: one per-signature program over the canonical
+    rows, run to its verdicts on the fetching thread.
     ``on_bisect`` (if given) is invoked once when that happens, and
     ``on_resolved(programs, rows_per_sig, bad_rows)`` once when the mask
-    is complete — how the scheduler's telemetry counts ``rlc_bisect``
-    launches and the ``bisect`` totals without the crypto layer
-    importing it.
+    is complete (``programs`` 1, ``rows_per_sig`` the canonical rows) —
+    how the scheduler's telemetry counts ``rlc_bisect`` launches and the
+    ``bisect`` totals without the crypto layer importing it.
 
     Host-canonicality failures and degenerate sizes (fewer than
     RLC_MIN_MSM canonical rows, or more than MAX_SUBBATCH) dispatch the
@@ -398,8 +398,9 @@ def verify_batch_rlc_pack(msgs, pks, sigs, *, pad: bool = True,
     (which is this function's two-stage wrapper).  ``trace`` as on
     :func:`verify_batch_pack`; a failed combined check adds one
     ``bisect`` span around the whole resolution (tags ``launches``,
-    ``bad``, ``n``) and, under it, one ``bisect_step`` span a device
-    program the resolution ran."""
+    ``bad``, ``n``) and, under it, ONE ``bisect_step`` span, from before
+    the canonical rows are staged to their verdicts on the host (tags
+    ``n``, ``route`` ``per_sig``, ``bucket``, ``ok``, ``depth`` 0)."""
     n = len(msgs)
     if n == 0:
         return lambda: (lambda: np.zeros((0,), bool))
@@ -446,69 +447,23 @@ def verify_batch_rlc_pack(msgs, pks, sigs, *, pad: bool = True,
                 return mask
             if on_bisect is not None:
                 on_bisect()
-            mid = m // 2
             bisect = trace.stage("bisect")
             with bisect as tags:
-                left = _rlc_resolve(packed, idx[:mid], mask, b"L", pad,
-                                    trace, bisect.id)
-                right = _rlc_resolve(packed, idx[mid:], mask, b"R", pad,
-                                     trace, bisect.id)
-                programs = left[0] + right[0]
-                bad = m - int(np.count_nonzero(mask[idx]))
+                # ONE per-signature program over the canonical rows names
+                # every bad vote in one round trip, whatever their number.
+                with trace.stage("bisect_step", bisect.id) as step:
+                    verdicts = verify_prepared_rows(rows[:m], m, pad=pad)
+                    if step is not None:
+                        step.update(n=m, route="per_sig", depth=0,
+                                    bucket=bucket, ok=bool(verdicts.all()))
+                mask[idx] = verdicts
+                bad = m - int(np.count_nonzero(verdicts))
                 if tags is not None:
-                    tags.update(launches=programs, bad=bad, n=m)
+                    tags.update(launches=1, bad=bad, n=m)
             if on_resolved is not None:
-                on_resolved(programs, left[1] + right[1], bad)
+                on_resolved(1, m, bad)
             return mask
 
         return fetch
 
     return dispatch
-
-
-def _rlc_resolve(packed: np.ndarray, indices: np.ndarray,
-                 out: np.ndarray, salt: bytes, pad: bool,
-                 trace=NO_LAUNCH, parent: int | None = None) -> tuple:
-    """Resolve ``out[indices]`` for host-canonical rows: combined RLC
-    check first, bisection on failure, per-signature floor.  Every
-    device program runs to its verdict before the next is staged, on
-    the calling (fetching) thread; one forged vote costs two programs a
-    level — ten for a 67-vote certificate: 33/34, 16/17, 8/9, 4/5 and
-    two per-signature leaves (PERF.md §5).  Returns (device programs it
-    ran, rows a per-signature leaf resolved).  On a traced launch each
-    program is one ``bisect_step`` span (``parent``: the ``bisect``
-    span), from before the rows are staged to the verdict on the
-    host."""
-    n = len(indices)
-    if n == 0:
-        return 0, 0
-    rows = np.ascontiguousarray(packed[indices])
-    m = _bucket(n) if pad else n
-    if n < RLC_MIN_MSM or n > MAX_SUBBATCH:
-        with trace.stage("bisect_step", parent) as tags:
-            verdicts = verify_prepared_rows(rows, n, pad=pad)
-            if tags is not None:
-                tags.update(n=n, route="per_sig", depth=len(salt), bucket=m,
-                            ok=bool(verdicts.all()))
-        out[indices] = verdicts
-        return 1, n
-    z = np.zeros((m, 32), np.uint8)
-    z[:n] = _rlc_coeffs(rows, salt)
-    if m != n:
-        rows = np.pad(rows, [(0, m - n), (0, 0)])
-    # Fresh host arrays -> fresh device buffers; the launch donates arg 0
-    # (same discipline as _dispatch_rows).
-    with trace.stage("bisect_step", parent) as tags:
-        ok = bool(np.asarray(E.verify_rlc_packed_donated(
-            jnp.asarray(rows), jnp.asarray(z))))
-        if tags is not None:
-            tags.update(n=n, route="rlc", depth=len(salt), bucket=m, ok=ok)
-    if ok:
-        out[indices] = True
-        return 1, 0
-    mid = n // 2
-    left = _rlc_resolve(packed, indices[:mid], out, salt + b"L", pad,
-                        trace, parent)
-    right = _rlc_resolve(packed, indices[mid:], out, salt + b"R", pad,
-                         trace, parent)
-    return 1 + left[0] + right[0], left[1] + right[1]

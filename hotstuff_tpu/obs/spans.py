@@ -30,7 +30,7 @@ counter — ``pack``, ``dispatch`` and ``device`` with ``parent`` null,
 their children (``h2d``, ``fetch_wait``, ``d2h``, ``bisect``) with
 ``parent`` = the id of the launch's ``device`` span
 (:class:`LaunchScope`), and a ``bisect``'s own children
-(``bisect_step``, one a device program the bisection ran) with
+(``bisect_step``, one a device program the resolution ran) with
 ``parent`` = the id of that ``bisect`` span.  A request's ``queue``
 span names the ``lid`` it left for and a ``pack`` span the ``rids`` it
 coalesced, so a request can be followed through a coalesced launch.

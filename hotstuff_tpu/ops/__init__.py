@@ -20,7 +20,7 @@ whose variable half is a single 2n-point multi-scalar multiplication
 (Straus shared 4-bit windows + a masked binary-tree batch reduction —
 see ops/ed25519.msm_window_sums).  All-valid batches — the steady state
 of quorum-certificate verification — pay one MSM instead of 2n ladders;
-a failed combined check bisects down to the per-signature path, so a bad
+a failed combined check is resolved on the per-signature path, so a bad
 vote is still pinpointed and the returned mask is bit-identical to
 verify_batch's.  Coefficients must be >= 128 bits: an adversary who can
 cancel a defect against the z-weighted sum forges a batch verdict, and

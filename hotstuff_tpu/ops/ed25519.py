@@ -593,8 +593,8 @@ verify_prepared_jit = jax.jit(verify_prepared)
 # batch of all-valid votes always satisfies it (the defects sum to exactly
 # zero); an invalid vote escapes only if its defect cancels against the
 # z-weighted sum, probability ~2^-128 for >=128-bit coefficients (see
-# crypto/eddsa.verify_batch_rlc for the PRF and the bisection fallback
-# that pinpoints culprits when the combined check fails).
+# crypto/eddsa.verify_batch_rlc for the PRF and the one per-signature
+# launch that pinpoints culprits when the combined check fails).
 #
 # MSM shape (Straus with shared 4-bit windows): per-point 16-entry tables
 # (14 batched adds — the same table build the per-signature ladder does),

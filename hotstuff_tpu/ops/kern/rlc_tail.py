@@ -161,8 +161,8 @@ def _tile(points: jnp.ndarray) -> jnp.ndarray:
                    [(0, 0), (0, _SUBLANES - 4), (0, FK.NLANES - FK.NLIMBS)])
 
 
-# jit-wrapped: every rlc program (each bucket, each program of a
-# bisection, the mesh's replicated finish) runs this one shape, so the
+# jit-wrapped: every rlc program (each bucket, each program of the
+# mesh's bisection, its replicated finish) runs this one shape, so the
 # kernel is traced once a process (kern package docstring).
 @jax.jit
 def _tail(w_cached: jnp.ndarray, comb_sel: jnp.ndarray):

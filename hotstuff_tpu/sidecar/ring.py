@@ -54,7 +54,7 @@ headline shows it winning.
 
 Bit-identity is non-negotiable: the ring feeds batches through the very
 same ``VerifyEngine._pack`` the staged path uses (same dedup, same
-verdict cache, same RLC bisection per generation), so ring verdicts
+verdict cache, same RLC resolution per generation), so ring verdicts
 equal ``verify_batch`` masks by construction — and tests assert it
 through the engine.
 """

@@ -28,9 +28,10 @@ registry is the single record of what was warmed:
 policy that wires the one-MSM verifiers into the engine's coalesced
 launch path: batches of ``RLC_MIN_LAUNCH`` or more signatures whose
 bucket (per-shard bucket, on a mesh) is RLC-warmed pay one Straus MSM
-instead of 2n scalar ladders, and the bisection fallback inside the RLC
-paths keeps the verdict mask bit-identical to the per-signature program
-whenever the combined check fails.  Mesh deployments route between
+instead of 2n scalar ladders, and the resolution inside the RLC paths
+(one per-signature launch on a single chip, a bisection on a mesh) keeps
+the verdict mask bit-identical to the per-signature program whenever
+the combined check fails.  Mesh deployments route between
 ``rlc_sharded`` and ``ladder_sharded`` the same way single-chip ones
 route between ``rlc`` and ``per_sig``.
 
@@ -50,8 +51,9 @@ from ...parallel.shard_shapes import (mesh_chunk_count, shard_aligned_rows,
 
 # Engine-path RLC floor: below this the combined check's fixed
 # Horner/comb tail outweighs the saved ladders (crypto/eddsa.RLC_MIN_MSM
-# is the *bisection* floor, a different constant: bisection wants to go
-# as low as profitable, the engine wants to start where the MSM wins).
+# is a different constant: the fewest canonical rows an admitted batch
+# may be left with and still run the MSM, and the mesh's bisection
+# floor; the engine wants to start where the MSM wins).
 RLC_MIN_LAUNCH = 16
 
 # Largest chunk count the whole-backlog mesh scan warms (graftscale):

@@ -76,9 +76,10 @@ class SchedStats:
         self.bulk_fill_sigs = 0          # padded slots used by bulk fill
         self.paths: dict[str, int] = {}  # per_sig / rlc / rlc_bisect / ...
         # What failed combined checks cost (crypto/eddsa.py
-        # verify_batch_rlc_pack's fetch): batches bisected (what
-        # paths.rlc_bisect counts), device programs the resolutions ran,
-        # rows a per-signature leaf resolved, rows found false.
+        # verify_batch_rlc_pack's fetch): batches resolved (what
+        # paths.rlc_bisect counts), device programs the resolutions ran
+        # (one a batch), rows they verified per signature (a batch's
+        # canonical rows), rows found false.
         self.bisect = {"batches": 0, "programs": 0, "rows_per_sig": 0,
                        "bad_rows": 0}
         self.admitted: dict[str, int] = {}

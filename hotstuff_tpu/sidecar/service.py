@@ -1082,8 +1082,9 @@ class VerifyEngine:
         # RLC warmup compiled pay ONE Straus MSM — single-chip via
         # crypto/eddsa.verify_batch_rlc_pack, mesh via
         # parallel/sharded_verify.verify_rlc_sharded_pack — instead of
-        # per-signature ladders; the bisection fallbacks keep the verdict
-        # mask bit-identical when the combined check fails.  While a
+        # per-signature ladders; when the combined check fails, one
+        # per-signature launch over the same rows (on a mesh, a
+        # bisection) keeps the verdict mask bit-identical.  While a
         # crash-only reboot is re-warming the device leg (graftguard),
         # everything routes host — the path the ladder already answers
         # wedged batches from.
@@ -2161,14 +2162,14 @@ def _warmup_rlc(engine, warm_max: int = MAX_SUBBATCH):
     so the scheduler's router starts choosing the RLC path.
 
     Runs all-valid batches in INCREASING size through the real
-    verify_batch_rlc entry, so the bisection fallback's smaller-bucket
-    programs are always already compiled when a larger bucket first
-    bisects mid-traffic (the per-signature floor shapes come from
-    _warmup, which serve() always runs first).  Starts at the bucket
-    floor (8), BELOW the routing threshold: bisection halves sub-batches
-    down to RLC_MIN_MSM regardless of what the router admits, so the
-    small RLC shapes must exist even though no whole batch routes to
-    them."""
+    verify_batch_rlc entry.  A failed combined check is resolved by ONE
+    per-signature program at the batch's own bucket, a shape of _warmup,
+    which serve() always runs first.  Starts at the bucket floor (8),
+    BELOW the routing threshold: since the bisection went, no half of a
+    batch launches the small RLC shapes, and bucket 8 is reached only by
+    an admitted batch (RLC_MIN_LAUNCH+ records) that the host's
+    canonicality checks leave with RLC_MIN_MSM .. 8 rows.  Taking the
+    shapes no traffic reaches out of the plan is ROADMAP S2(a)'s."""
     from ..crypto import eddsa, ref_ed25519 as ref
 
     sk = bytes(range(32))

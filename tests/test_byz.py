@@ -6,69 +6,69 @@ of ``benchmark/rehearsal/configs/qc24f7.json`` (the chip's is
 
 Every reply is held, bit for bit, to one plain reference verify per
 signature; the ``bisect.*`` counters of OP_STATS and the ``bisect`` /
-``bisect_step`` spans are held to what the bisection has to run."""
+``bisect_step`` spans are held to what resolving a failed combined
+check runs: ONE per-signature program over the certificate's canonical
+rows, however many votes are forged and wherever they sit.  The chip's
+width — 67 rows at bucket 128, a third and a half of them forged —
+goes through ``eddsa.verify_batch_rlc_pack`` itself (the last test)."""
 
 import hashlib
 import random
 import threading
 
+import numpy as np
 import pytest
 
-from hotstuff_tpu.crypto import ref_ed25519 as ref
-from hotstuff_tpu.obs.spans import parse_spans
+from hotstuff_tpu.crypto import eddsa, ref_ed25519 as ref
+from hotstuff_tpu.obs.spans import Tracer, parse_spans
 from hotstuff_tpu.sidecar.client import SidecarClient
+from hotstuff_tpu.sidecar.sched.stats import SchedStats
 
 COMMITTEE = 24
 QUORUM = 2 * COMMITTEE // 3 + 1        # 17, the node's own formula
-RLC_MIN_MSM = 4                        # crypto/eddsa.py: the bisection floor
 
-CASES = [(kind, k) for kind in ("qc", "tc") for k in (1, 2, 6)]
+# (kind, forged votes, where): seeded rows, or the first / the last row.
+# 6 of 17 is the third that 22 of 67 is, 8 of 17 the half that 33 is.
+CASES = [(kind, k, "seeded") for kind in ("qc", "tc") for k in (1, 2, 6)] \
+    + [("qc", 1, "first"), ("qc", 1, "last"), ("tc", 8, "seeded")]
+WIDE_CASES = [0, 22, 33]               # forged votes of 67, bucket 128
 
 
-def _certificate(kind: str, k: int):
-    """A 17-vote certificate of a seeded 17 of the 24 validators — a QC
-    (one common digest) or a TC (a message a vote) — with ``k`` votes
-    forged (one bit of S flipped) at seeded rows.  Returns (msgs, pks,
-    sigs, forged rows)."""
-    rng = random.Random(f"byz-{kind}-{k}")
+def _case_id(case) -> str:
+    kind, k, where = case
+    return f"{kind}-{k}forged" + ("" if where == "seeded" else f"-{where}")
+
+
+def _certificate(kind: str, k: int, where: str = "seeded",
+                 committee: int = COMMITTEE):
+    """A 2N/3+1-vote certificate of a seeded quorum of the ``committee``
+    validators — a QC (one common digest) or a TC (a message a vote) —
+    with ``k`` votes forged (one bit of S flipped) at seeded rows, or at
+    the first / the last row.  Returns (msgs, pks, sigs, forged rows)."""
+    quorum = 2 * committee // 3 + 1
+    rng = random.Random(f"byz-{kind}-{k}-{where}-{committee}")
     secrets = [hashlib.sha512(b"validator-%d" % i).digest()[:32]
-               for i in rng.sample(range(COMMITTEE), QUORUM)]
-    tag = f"{kind}-{k}".encode()
+               for i in rng.sample(range(committee), quorum)]
+    tag = f"{kind}-{k}-{where}".encode()
     if kind == "qc":
-        msgs = [hashlib.sha512(b"digest-" + tag).digest()[:32]] * QUORUM
+        msgs = [hashlib.sha512(b"digest-" + tag).digest()[:32]] * quorum
     else:
         msgs = [hashlib.sha512(b"timeout-%d-" % i + tag).digest()[:32]
-                for i in range(QUORUM)]
+                for i in range(quorum)]
     pks = [ref.generate_keypair(sk)[1] for sk in secrets]
     sigs = [ref.sign(sk, m) for sk, m in zip(secrets, msgs)]
-    forged = sorted(rng.sample(range(QUORUM), k))
+    forged = {"seeded": sorted(rng.sample(range(quorum), k)),
+              "first": [0], "last": [quorum - 1]}[where]
+    assert len(forged) == k
     for row in forged:
         sigs[row] = sigs[row][:32] + bytes([sigs[row][32] ^ 1]) \
             + sigs[row][33:]
     return msgs, pks, sigs, forged
 
 
-def _programs(rows: list, bad: set) -> tuple:
-    """What resolving ``rows`` has to run, from the rule alone: (device
-    programs, rows a per-signature leaf resolves).  Under RLC_MIN_MSM
-    rows, one per-signature program; else one combined check and, if it
-    holds a forged row, both halves."""
-    if len(rows) < RLC_MIN_MSM:
-        return 1, len(rows)
-    if not bad & set(rows):
-        return 1, 0
-    mid = len(rows) // 2
-    left, right = _programs(rows[:mid], bad), _programs(rows[mid:], bad)
-    return 1 + left[0] + right[0], left[1] + right[1]
-
-
-def _expected(forged: list) -> tuple:
-    """The certificate's own (failed) launch is not a bisection program:
-    the resolution starts at its two halves."""
-    rows, bad = list(range(QUORUM)), set(forged)
-    left = _programs(rows[:QUORUM // 2], bad)
-    right = _programs(rows[QUORUM // 2:], bad)
-    return left[0] + right[0], left[1] + right[1]
+def _reference_mask(msgs, pks, sigs) -> list:
+    """One plain reference verify per signature."""
+    return [bool(ref.verify(pk, m, s)) for m, pk, s in zip(msgs, pks, sigs)]
 
 
 @pytest.fixture(scope="module")
@@ -124,7 +124,7 @@ def byz(tmp_path_factory):
         assert not thread.is_alive()
     spans, malformed = parse_spans(spans_path.read_text())
     assert malformed == 0
-    # One connection, one request in flight: the i-th bisection is the
+    # One connection, one request in flight: the i-th resolution is the
     # i-th case's.
     bisects = sorted((s for s in spans if s["stage"] == "bisect"),
                      key=lambda s: s["t0"])
@@ -134,53 +134,91 @@ def byz(tmp_path_factory):
         records[case]["steps"] = [
             s for s in spans if s["stage"] == "bisect_step"
             and s["parent"] == bisect["id"]]
+        # No span of its own inside a step: the benchmark's readers take
+        # a step's time whole.
+        records[case]["inside_steps"] = [
+            s for s in spans if s.get("parent") in
+            {step["id"] for step in records[case]["steps"]}]
     assert sum(len(r["steps"]) for r in records.values()) == \
         sum(s["stage"] == "bisect_step" for s in spans)
     return records
 
 
-@pytest.mark.parametrize("kind,k", CASES,
-                         ids=[f"{kind}-{k}forged" for kind, k in CASES])
-def test_forged_votes_are_named_and_counted(byz, kind, k):
-    r = byz[(kind, k)]
+@pytest.mark.parametrize("case", CASES, ids=_case_id)
+def test_forged_votes_are_named_and_counted(byz, case):
+    _, k, _ = case
+    r = byz[case]
     # The reply, bit for bit: one plain reference verify per signature.
-    want = [bool(ref.verify(pk, m, s))
-            for m, pk, s in zip(r["msgs"], r["pks"], r["sigs"])]
+    want = _reference_mask(r["msgs"], r["pks"], r["sigs"])
     assert want == [i not in r["forged"] for i in range(QUORUM)]
     assert r["reply"] == want
-
-    programs, rows_per_sig = _expected(r["forged"])
-    if k == 1:
-        # One forged vote at quorum 17, whichever row: 17 -> 8/9, the
-        # failing half -> 4/4 or 4/5, the failing quarter -> 2/2 or 2/3,
-        # both under RLC_MIN_MSM: two per-signature leaves.  Two
-        # programs a level, three levels.
-        assert programs == 6 and rows_per_sig in (4, 5)
 
     def moved(section, key):
         return r["after"][section].get(key, 0) - \
             r["before"][section].get(key, 0)
 
+    # One program over every canonical row, whatever k is.
     assert moved("bisect", "batches") == 1
     assert moved("paths", "rlc_bisect") == 1 and moved("paths", "rlc") == 1
-    assert moved("bisect", "programs") == programs
-    assert moved("bisect", "rows_per_sig") == rows_per_sig
+    assert moved("bisect", "programs") == 1
+    assert moved("bisect", "rows_per_sig") == QUORUM
     assert moved("bisect", "bad_rows") == k
     assert r["after"]["paths"].get("host", 0) == 0
     assert r["after"]["guard"]["wedges"] == 0
+    assert r["after"]["compile"]["in_service"]["count"] == 0
 
-    bisect, steps = r["bisect"], r["steps"]
-    assert bisect["launches"] == programs == len(steps)
-    assert bisect["bad"] == k and bisect["n"] == QUORUM
-    for s in steps:
-        assert s["lid"] == bisect["lid"]
-        assert bisect["t0"] <= s["t0"] and s["t"] <= bisect["t"]
-        assert s["route"] == ("per_sig" if s["n"] < RLC_MIN_MSM else "rlc")
-        assert s["bucket"] == (8 if s["n"] <= 8 else 16)
-        assert 1 <= s["depth"] <= 3
-    assert sum(s["n"] for s in steps if s["route"] == "per_sig") == \
-        rows_per_sig
-    # A step is `ok` exactly when it holds no forged row; the two steps
-    # of depth 1 are the certificate's halves.
-    assert sum(not s["ok"] for s in steps) >= 1
-    assert sorted(s["n"] for s in steps if s["depth"] == 1) == [8, 9]
+    bisect, (step,) = r["bisect"], r["steps"]
+    assert (bisect["launches"], bisect["bad"], bisect["n"]) == (1, k, QUORUM)
+    assert step["lid"] == bisect["lid"]
+    assert bisect["t0"] <= step["t0"] and step["t"] <= bisect["t"]
+    assert (step["n"], step["route"], step["bucket"], step["depth"],
+            step["ok"]) == (QUORUM, "per_sig", 32, 0, False)
+    assert r["inside_steps"] == []
+
+
+class _FailedCheck:
+    """What ``verify_rlc_packed_donated`` returns, as far as the fetch
+    uses it, with a verdict of false."""
+
+    def block_until_ready(self):
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(False)
+
+
+@pytest.mark.parametrize("k", WIDE_CASES, ids=lambda k: f"67-{k}forged")
+def test_one_program_at_the_chips_width(tmp_path, monkeypatch, k):
+    """``qc100f33``'s width through the call ``service._pack`` makes: 67
+    canonical rows with a third and a half of them forged and (k = 0) a
+    combined check that fails though every signature verifies alone,
+    which still answers all-true.  The combined check is stood in by a
+    verdict of false (its program at bucket 128 is ``test_rlc``'s and
+    the chip's to run); the resolution runs the real per-signature
+    program at bucket 128."""
+    monkeypatch.setattr(eddsa.E, "verify_rlc_packed_donated",
+                        lambda rows, z: _FailedCheck())
+    msgs, pks, sigs, forged = _certificate("qc", k, committee=100)
+    stats = SchedStats()
+    tracer = Tracer(str(tmp_path / "spans.jsonl"))
+    mask = eddsa.verify_batch_rlc_pack(
+        msgs, pks, sigs, on_bisect=stats.note_bisect,
+        on_resolved=stats.note_bisect_resolved, trace=tracer.launch(1))()()
+    tracer.close()
+
+    want = _reference_mask(msgs, pks, sigs)
+    assert len(want) == 67 and want == [i not in forged for i in range(67)]
+    assert mask.tolist() == want
+    snap = stats.snapshot()
+    assert snap["paths"] == {"rlc_bisect": 1}
+    assert snap["bisect"] == {"batches": 1, "programs": 1,
+                              "rows_per_sig": 67, "bad_rows": k}
+    spans, malformed = parse_spans((tmp_path / "spans.jsonl").read_text())
+    assert malformed == 0
+    (bisect,) = [s for s in spans if s["stage"] == "bisect"]
+    (step,) = [s for s in spans if s["stage"] == "bisect_step"]
+    assert (bisect["launches"], bisect["bad"], bisect["n"]) == (1, k, 67)
+    assert step["parent"] == bisect["id"]
+    assert (step["n"], step["route"], step["bucket"], step["depth"],
+            step["ok"]) == (67, "per_sig", 128, 0, k == 0)
+    assert not [s for s in spans if s.get("parent") == step["id"]]

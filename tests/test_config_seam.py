@@ -116,33 +116,31 @@ def test_traffic_width_takes_the_configured_route(booted):
         assert engine._shapes.route(n) == CONFIGS[name]["route"], n
 
 
-def _bisection_reach(n, out):
-    """(route, bucket) of every device program ``eddsa._rlc_resolve`` may
-    run for n rows: the combined check, then both halves, down to the
-    per-signature floor under RLC_MIN_MSM."""
-    if n == 0:
-        return out
-    if n < eddsa.RLC_MIN_MSM:
-        out.add(("per_sig", eddsa._bucket(n)))
-        return out
-    out.add(("rlc", eddsa._bucket(n)))
-    _bisection_reach(n // 2, out)
-    return _bisection_reach(n - n // 2, out)
+def _resolution_reach(m):
+    """(route, bucket) of every device program a certificate with m
+    canonical rows may run (``eddsa.verify_batch_rlc_pack``): under
+    RLC_MIN_MSM the per-signature program from the start; else the
+    combined check and, when it fails, ONE per-signature program over
+    the same rows, at the same bucket."""
+    if m < eddsa.RLC_MIN_MSM:
+        return {("per_sig", eddsa._bucket(m))} if m else set()
+    return {("rlc", eddsa._bucket(m)), ("per_sig", eddsa._bucket(m))}
 
 
 @pytest.mark.parametrize("booted", RLC_CONFIGS, indirect=True)
 def test_every_shape_a_bisection_can_reach_is_warmed(booted):
     """A failed combined check must never build a program while serving
-    (``compile.in_service`` stays 0): each half it may launch is a shape
-    the boot warmed."""
+    (``compile.in_service`` stays 0): the per-signature bucket of the
+    traffic's width is a shape the boot warmed — and so are both
+    programs at every narrower bucket, which a certificate reaches when
+    the host refuses some of its rows as non-canonical."""
     name, keys, engine = booted
     for width in _widths(name):
-        reach = set()
-        _bisection_reach(width // 2, reach)
-        _bisection_reach(width - width // 2, reach)
         if width == 67:
-            assert reach == {("rlc", 64), ("rlc", 32), ("rlc", 16),
-                             ("rlc", 8), ("per_sig", 8)}
+            assert _resolution_reach(width) == {("rlc", 128),
+                                                ("per_sig", 128)}
+        reach = set().union(*map(_resolution_reach, range(width + 1)))
+        assert {b for _, b in reach} == set(engine._shapes.buckets)
         for route, bucket in sorted(reach):
             if route == "rlc":
                 assert f"rlc:{bucket}" in keys

@@ -676,8 +676,8 @@ SPAN_STAGES = ("request", "decode", "queue", "pack", "h2d", "dispatch",
 def span_tree(tmp_path_factory):
     """One traced round trip over the socket on a virtual clock: a valid
     16-vote certificate, the same one again (verdict cache), one with a
-    forged vote (bisection).  Yields (spans, annotations opened,
-    replies)."""
+    forged vote (resolved per signature).  Yields (spans, annotations
+    opened, replies)."""
     import itertools
     from contextlib import contextmanager
 
@@ -759,7 +759,16 @@ def test_span_tree_has_every_stage_on_one_clock(span_tree, stage):
                    and ("sidecar:dispatch", {"lid": s["lid"]}) in annotations
                    for s in mine)
     if stage == "bisect":
-        assert len(mine) == 1 and mine[0]["launches"] >= 2
+        # ONE per-signature program over the 16 rows resolves it: one
+        # ``bisect_step`` child, with its own profiler annotation.
+        (bisect,) = mine
+        assert (bisect["launches"], bisect["bad"], bisect["n"]) == (1, 1, 16)
+        (step,) = [s for s in spans if s["stage"] == "bisect_step"]
+        assert step["parent"] == bisect["id"] and step["lid"] == bisect["lid"]
+        assert (step["n"], step["route"], step["bucket"], step["depth"],
+                step["ok"]) == (16, "per_sig", 16, 0, False)
+        assert bisect["t0"] <= step["t0"] and step["t"] <= bisect["t"]
+        assert ("sidecar:bisect_step", {"lid": step["lid"]}) in annotations
     if stage == "cache_insert":
         # ONE a launch, after its d2h, inside its device span; the
         # request answered from the cache writes none.

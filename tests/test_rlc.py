@@ -2,8 +2,8 @@
 
 Contract under test (crypto/eddsa.verify_batch_rlc): the mask it returns
 is bit-identical to the per-signature verify_batch on EVERY input —
-all-valid batches ride the one-MSM fast path, any failure bisects down
-to the per-signature floor, so a bad vote is always pinpointed.  Parity
+all-valid batches ride the one-MSM fast path, any failure is resolved
+by one per-signature program, so a bad vote is always pinpointed.  Parity
 model: the reference's verify_valid_batch / verify_invalid_batch
 (crypto/src/tests/crypto_tests.rs) plus the batch-forgery cases a
 combined check uniquely has to survive.
@@ -44,7 +44,7 @@ def test_all_valid_batch_passes_fast_path():
 
 def test_each_single_corrupted_index_is_pinpointed():
     """For every index of a 6-vote batch: corrupt exactly that vote; the
-    combined check must fail and bisection must blame exactly it."""
+    combined check must fail and the resolution must blame exactly it."""
     for bad in range(6):
         msgs, pks, sigs = map(list, zip(*POOL[:6]))
         sigs[bad] = corrupt_sig(sigs[bad])
@@ -109,7 +109,7 @@ def test_coefficients_are_deterministic_nonzero_128bit():
     rows2 = rows.copy()
     rows2[3, 60] ^= 1
     assert (eddsa._rlc_coeffs(rows2, b"") != z1).any()
-    # path-keyed: bisection halves draw fresh coefficients
+    # path-keyed: the mesh's bisection halves draw fresh coefficients
     assert (eddsa._rlc_coeffs(rows, b"L") != z1).any()
 
 

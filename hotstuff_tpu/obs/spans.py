@@ -258,9 +258,12 @@ class LaunchScope:
     ``crypto/`` sees nothing else of the sidecar.  ``pack_end`` is the
     one stamp that crosses threads through it: the pack worker leaves the
     end of the ``pack`` span there, and the dispatch site reads from it
-    how long it waited for the pack."""
+    how long it waited for the pack.  ``bucket`` crosses the same way:
+    the padded rows of the program the pack staged, for the ``device``
+    span."""
 
-    __slots__ = ("_tracer", "lid", "device_id", "enabled", "pack_end")
+    __slots__ = ("_tracer", "lid", "device_id", "enabled", "pack_end",
+                 "bucket")
 
     def __init__(self, tracer: Tracer | None, lid: int | None):
         self._tracer = tracer
@@ -268,6 +271,7 @@ class LaunchScope:
         self.enabled = tracer is not None
         self.device_id = tracer.next_id() if tracer is not None else None
         self.pack_end = None
+        self.bucket = None
 
     def now(self) -> float:
         return self._tracer.now()

@@ -1,7 +1,8 @@
 """Per-launch telemetry for the verify scheduler.
 
 Counters answer the questions the drain-loop engine could not: how big
-are launches actually (coalesce histogram), how much padded capacity is
+are launches actually (coalesce histogram), how many tenants' requests
+one launch holds (tenants histogram), how much padded capacity is
 wasted (pad_waste vs bulk fill), which verify path ran (per_sig / rlc /
 rlc_bisect / host / rlc_sharded / ladder_sharded), how long requests sat
 queued per class (p50/p99), how often backpressure fired, how mesh
@@ -71,6 +72,9 @@ class SchedStats:
         self.launches_by_class: dict[str, int] = {}
         # coalesce-size histogram: padded-bucket capacity -> launches
         self.coalesce_hist: dict[int, int] = {}
+        # distinct tenants a launch holds -> launches: how far the lanes
+        # of a shared sidecar coalesce into one device program
+        self.tenants_hist: dict[int, int] = {}
         self.sigs_launched = 0
         self.pad_waste_sigs = 0          # padded slots left empty
         self.bulk_fill_sigs = 0          # padded slots used by bulk fill
@@ -193,14 +197,18 @@ class SchedStats:
             self.pad_waste_sigs += max(0, capacity - total)
             fill = launch.items[len(launch.items) - launch.fill_count:]
             self.bulk_fill_sigs += sum(len(p) for p in fill)
+            tenants = set()
             for p in launch.items:
                 waits = self._waits.get(p.cls)
                 if waits is not None:
                     waits.append(now - p.enqueued_at)
-                tw = self._tenant_locked(
-                    getattr(p, "tenant", None) or "default")["waits"]
+                tenant = getattr(p, "tenant", None) or "default"
+                tenants.add(tenant)
+                tw = self._tenant_locked(tenant)["waits"]
                 if p.cls in tw:
                     tw[p.cls].append(now - p.enqueued_at)
+            self.tenants_hist[len(tenants)] = \
+                self.tenants_hist.get(len(tenants), 0) + 1
 
     def note_bulk_source(self, ingress: bool, sigs: int):
         """One offered bulk-lane request, split by feed: ingress-fed
@@ -319,6 +327,8 @@ class SchedStats:
                 "launches_by_class": dict(self.launches_by_class),
                 "coalesce_hist": {str(k): v for k, v in
                                   sorted(self.coalesce_hist.items())},
+                "tenants_hist": {str(k): v for k, v in
+                                 sorted(self.tenants_hist.items())},
                 "sigs_launched": self.sigs_launched,
                 "pad_waste_sigs": self.pad_waste_sigs,
                 "bulk_fill_sigs": self.bulk_fill_sigs,

@@ -685,13 +685,17 @@ class VerifyEngine:
         """The ONE ``device`` span of a traced launch (dispatch returned
         at ``t0`` -> fetch returned, now): it includes the d2h copy,
         exactly what the engine pays.  ``hop_ms`` is the guard hop of
-        the dispatch (``hop_s``) and of the fetch together."""
+        the dispatch (``hop_s``) and of the fetch together; ``tenants``
+        the distinct HELLO names among its ``reqs`` requests; ``bucket``
+        the padded rows its program ran (``_pack`` left them on the
+        scope)."""
         ctxs = _ctx_tags(batch)
         if ctxs:
             tags["ctxs"] = ctxs
         scope.record(
             "device", t0, id=scope.device_id, reqs=len(batch),
             sigs=sum(len(p.request.msgs) for p in batch),
+            tenants=len({p.tenant for p in batch}), bucket=scope.bucket,
             hop_ms=round((hop_s + self._last_hop_s()) * 1e3, 3), **tags)
 
     def _guard_key(self, batch) -> str:
@@ -1144,6 +1148,11 @@ class VerifyEngine:
             pack_ctxs = _ctx_tags(batch)
             if pack_ctxs:
                 pack_tags["ctxs"] = pack_ctxs
+            # Rows the staged program runs: none where none was staged
+            # (every record cached, or the host path).
+            on_device = device_records and path != vsched.PATH_HOST
+            scope.bucket = self._shapes.bucket_capacity(
+                len(device_records)) if on_device else 0
             scope.pack_end = scope.now()
             scope.record("pack", span_t0, scope.pack_end,
                          reqs=len(batch), uniq=len(uniq_records),

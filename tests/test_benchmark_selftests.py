@@ -27,12 +27,13 @@ _DESELECT = {
 }
 
 # Whole commands at rehearsal size: minutes each on the CPU.
-_SLOW = ("test_byz.py", "test_correct.py")
+_SLOW = ("test_byz.py", "test_correct.py", "test_gate_rehearsal.py")
 
 
 @pytest.mark.parametrize("name", [
-    "test_arith.py", "test_manifest.py", "test_readers.py", "test_run.py",
-    "test_span_tree.py", "test_streams.py", "test_trace_reduce.py",
+    "test_arith.py", "test_gate.py", "test_manifest.py", "test_readers.py",
+    "test_run.py", "test_span_tree.py", "test_streams.py",
+    "test_trace_reduce.py",
     *(pytest.param(name, marks=pytest.mark.slow) for name in _SLOW)])
 def test_benchmark_test_file_passes(name):
     cmd = [sys.executable, "-m", "pytest", f"benchmark/tests/{name}", "-q",

@@ -36,8 +36,10 @@ CONFIGS = {c["name"]: c for c in map(
 RLC_CONFIGS = sorted(n for n, c in CONFIGS.items() if c["route"] == "rlc")
 
 # PERF.md §4: "ten shapes" (qc100, and qc100f33 whose programs are
-# qc100's), "eight ladder shapes" (eddsa1024).
-STATED_PLAN_SIZE = {"qc100": 10, "qc100f33": 10, "eddsa1024": 8}
+# qc100's), "eight ladder shapes" (eddsa1024, and ingress20 whose
+# programs are eddsa1024's).
+STATED_PLAN_SIZE = {"qc100": 10, "qc100f33": 10, "eddsa1024": 8,
+                    "ingress20": 8}
 
 
 def _widths(name):

@@ -15,8 +15,8 @@ from hotstuff_tpu.sidecar.client import SidecarClient
 from hotstuff_tpu.sidecar.service import SidecarServer, VerifyEngine
 
 
-def _sigs(n, tamper=()):
-    rng = np.random.default_rng(7)
+def _sigs(n, tamper=(), seed=7):
+    rng = np.random.default_rng(seed)
     msgs, pks, sigs = [], [], []
     for i in range(n):
         sk = rng.bytes(32)
@@ -167,6 +167,94 @@ def test_false_warmup_verdict_aborts_serve_before_bind(served, monkeypatch):
     assert len(errors) == 1 and isinstance(errors[0], RuntimeError)
     assert "returned false for a valid signature" in str(errors[0])
     assert servers == []
+
+
+@pytest.mark.parametrize("tenants,records", [(6, 8), (3, 4)])
+def test_coalesced_tenants_get_the_reference_mask(served, tmp_path, tenants,
+                                                  records):
+    """The path a launch with MANY requests takes (``ingress20.gate`` at
+    a size the CPU compiles): ``tenants`` connections, a HELLO name
+    each, one bulk request of ``records`` signatures in flight each, two
+    rounds, seeded forgeries.  Through the real scheduler and device
+    program every mask equals the plain reference per record, some
+    launch holds requests of several tenants, no request is split over
+    launches and every tenant is answered in every round."""
+    import time
+
+    from hotstuff_tpu.crypto.eddsa import _bucket
+    from hotstuff_tpu.obs.spans import parse_spans
+
+    rounds = 2
+    trace = tmp_path / "spans.jsonl"
+    errors, servers = served(warm_max=_bucket(tenants * records),
+                             committee=tenants, trace_path=str(trace))
+    assert not errors
+    port = servers[0].server_address[1]
+    rng = np.random.default_rng(records)
+    batches = {(t, r): _sigs(
+        records, seed=[records, t, r], tamper=set(rng.choice(
+            records, rng.integers(0, 3), replace=False).tolist()))
+        for t in range(tenants) for r in range(rounds)}
+    want = {k: [bool(ref.verify(pk, m, s)) for m, pk, s in zip(*b)]
+            for k, b in batches.items()}
+    assert sum(m.count(False) for m in want.values()) >= 1
+    got = {}
+    barrier = threading.Barrier(tenants)
+
+    def gate(t):
+        with SidecarClient(port=port, timeout=300.0) as client:
+            client.hello(f"gate-{t}")
+            for r in range(rounds):
+                barrier.wait(timeout=300)
+                got[t, r] = client.verify_batch(*batches[t, r], bulk=True)
+
+    threads = [threading.Thread(target=gate, args=(t,), daemon=True)
+               for t in range(tenants)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=600)
+        assert not th.is_alive()
+    assert got == want
+    with SidecarClient(port=port) as client:
+        stats = client.stats()
+    # OP_STATS: every launch is in the tenants histogram, one of them
+    # held several tenants, nothing was refused, every gate was served
+    # in every round.
+    hist = {int(k): v for k, v in stats["tenants_hist"].items()}
+    assert sum(hist.values()) == stats["launches"]
+    assert max(hist) >= 2
+    assert stats["queue_full"] == {} and "host" not in stats["paths"]
+    assert stats["sigs_launched"] == tenants * rounds * records
+    for t in range(tenants):
+        mine = stats["tenants"][f"gate-{t}"]
+        assert mine["admitted"] == {"bulk": rounds} and mine["shed"] == {}
+        assert mine["queue_wait"]["bulk"]["n"] == rounds
+    # The spans reach the file when serve() returns, in one write.
+    servers[0].shutdown()
+    deadline = time.monotonic() + 60
+    devices = []
+    while len(devices) < stats["launches"] and time.monotonic() < deadline:
+        time.sleep(0.05)
+        if trace.exists():
+            spans, _ = parse_spans(trace.read_text())
+            devices = [s for s in spans if s["stage"] == "device"]
+    assert len(devices) == stats["launches"]
+    assert sum(d["reqs"] for d in devices) == tenants * rounds
+    assert max(d["tenants"] for d in devices) >= 2
+    for d in devices:
+        assert 1 <= d["tenants"] <= d["reqs"] and d["sigs"] == \
+            d["reqs"] * records <= d["bucket"] == _bucket(d["sigs"])
+    # No request is split: one ``queue`` span a request, naming the one
+    # launch it left for.
+    roots = [s for s in spans if s["stage"] == "request"]
+    queues = [s for s in spans if s["stage"] == "queue"]
+    assert len(roots) == len(queues) == tenants * rounds
+    assert sorted(q["parent"] for q in queues) == sorted(
+        r["id"] for r in roots)
+    by_lid = {d["lid"]: d for d in devices}
+    for lid in {q["lid"] for q in queues}:
+        assert sum(q["lid"] == lid for q in queues) == by_lid[lid]["reqs"]
 
 
 @pytest.fixture(scope="module")

@@ -10,7 +10,8 @@ launches distribute over per-shard buckets, how many bulk backlogs
 drained as ONE whole-backlog chunked scan instead of per-launch_cap
 slices (the ``scan`` section), and how much of the host pack work the
 double-buffered dispatch pipeline actually hid behind device execution
-(the ``pipeline`` overlap ratio).
+(the ``pipeline`` overlap ratio) and how many launches it dispatched
+while the launch before was still in flight (``dispatch_ahead_share``).
 
 Exposed over the wire as the ``OP_STATS`` reply (one JSON object — the
 snapshot() dict verbatim), which the harness fetches at teardown into
@@ -122,6 +123,10 @@ class SchedStats:
         self.pack_s = 0.0
         self.pack_hidden_s = 0.0
         self._pack_window = deque(maxlen=PIPE_WINDOW)  # (t, dur, hidden)
+        # Launches the staged engine put on the device, and whether each
+        # went while another launch was still in flight (dispatched
+        # ahead of that launch's drain): the same rolling window.
+        self._dispatch_window = deque(maxlen=PIPE_WINDOW)  # (t, ahead)
         self._waits = {c: deque(maxlen=self.WAIT_SAMPLES_CAP)
                        for c in ("latency", "bulk")}
         # graftfleet per-tenant section: admissions/sheds per class and
@@ -286,6 +291,14 @@ class SchedStats:
                 self.pack_hidden_s += duration_s
             self._pack_window.append((now, duration_s, bool(hidden)))
 
+    def note_dispatch(self, ahead: int, now: float | None = None):
+        """One launch dispatched by the staged engine; ``ahead`` is the
+        launches still in flight at that moment (> 0: dispatched before
+        the launch before it was drained)."""
+        now = self._clock() if now is None else now
+        with self._lock:
+            self._dispatch_window.append((now, ahead > 0))
+
     # -- reporting ----------------------------------------------------------
 
     def _pipeline_locked(self) -> dict:
@@ -294,15 +307,21 @@ class SchedStats:
         read, now answering for RECENT pack-boundedness; lifetime
         accumulators ride along under ``lifetime_*``."""
         now = self._clock()
-        while self._pack_window and \
-                now - self._pack_window[0][0] > PIPE_WINDOW_S:
-            self._pack_window.popleft()
+        for window in (self._pack_window, self._dispatch_window):
+            while window and now - window[0][0] > PIPE_WINDOW_S:
+                window.popleft()
         win = sum(d for _, d, _ in self._pack_window)
         win_hidden = sum(d for _, d, h in self._pack_window if h)
+        dispatches = len(self._dispatch_window)
+        ahead = sum(1 for _, a in self._dispatch_window if a)
         return {
             "pack_ms": round(win * 1e3, 3),
             "pack_hidden_ms": round(win_hidden * 1e3, 3),
             "overlap_ratio": round(win_hidden / win, 3) if win else 0.0,
+            "dispatches": dispatches,
+            "dispatch_ahead": ahead,
+            "dispatch_ahead_share": round(ahead / dispatches, 3)
+            if dispatches else 0.0,
             "window_s": PIPE_WINDOW_S,
             "lifetime_pack_ms": round(self.pack_s * 1e3, 3),
             "lifetime_overlap_ratio": round(

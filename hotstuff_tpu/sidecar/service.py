@@ -522,15 +522,21 @@ class VerifyEngine:
     # -- consumer ----------------------------------------------------------
 
     # Ed25519 launches kept in flight before the oldest result is fetched
-    # (STAGED path only).  A dispatch's fixed cost OVERLAPS device
-    # execution of the previous launch — but only if the engine
-    # dispatches launch i+1 before fetching launch i's mask.  Depth 2
-    # covers dispatch ~= execute; deeper only adds reply latency
-    # (neither is measured on the chip).  On top of the dispatch depth sits ONE pack
-    # slot (the pack worker in __init__): while up to two launches
-    # execute, the host side of the next launch — byte decode,
-    # prepare_batch, h2d — is already staging, so in the steady state
-    # the device never waits for host packing.
+    # (STAGED path only).  With a pack pending and room here, the engine
+    # waits for that pack and dispatches launch n+1 BEFORE it drains
+    # launch n, so n's fetch, d2h, verdict-cache insert, fan-out and
+    # replies run beside n+1's program instead of in front of it: on
+    # the chip eddsa1024.flood went from 71,219 to 87,040 sigs/s with it
+    # (PERF.md §6, PR 38), where draining first had left no two
+    # `device` spans overlapping in 1,288 launches.  A launch whose
+    # result is in waits for a pack that outlasts it: draining it early
+    # read slower in both bulk cells, since its Python then runs beside
+    # the pack.  Depth 2 keeps one program queued behind the running one;
+    # deeper only adds reply latency.  On top of the dispatch depth sits
+    # ONE pack slot (the pack worker in __init__): while up to two
+    # launches execute, the host side of the next launch — byte decode,
+    # prepare_batch, h2d — is already staging; the device waits for it
+    # only where a pack outlasts the program before it.
     # Knob hygiene: a constant, not an env knob — the cadence ring
     # (sidecar/ring.py) generalizes it to a TRAINED depth k in {2,4,8}
     # (RingDepth, swept in the bench ``cadence`` headline), so anyone
@@ -562,12 +568,16 @@ class VerifyEngine:
                                         #  launch scope, dispatched_at,
                                         #  dispatch hop)
         while not self._stopped.is_set():
-            # 1) A FINISHED pack moves onto the device whenever there is
-            #    dispatch room.  Unfinished packs are waited out in step
-            #    3's bounded slices, never blocked on here — stop() must
-            #    stay observable even mid-pack.
-            if packing and len(inflight) < self.PIPELINE_DEPTH and \
-                    packing[0][1].done():
+            # 1) A pending pack with dispatch room goes onto the device
+            #    as soon as it is done — BEFORE the launch in flight is
+            #    drained, so that launch's fetch, insert and replies run
+            #    beside the next program.  The pack is waited on in
+            #    bounded slices: stop() stays observable mid-pack.
+            if packing and len(inflight) < self.PIPELINE_DEPTH:
+                try:
+                    packing[0][1].exception(timeout=0.25)
+                except cfut.TimeoutError:
+                    continue
                 self._dispatch_one(packing, inflight)
                 continue
             # 2) A free pack slot admits the next scheduler launch.
@@ -617,17 +627,10 @@ class VerifyEngine:
                     continue
                 if idle:
                     continue
-            # 3) Pipeline full or queue empty: make progress on the
-            #    oldest work — fetch the oldest launch (its execution
-            #    overlapped the pack that is still staging), or wait out
-            #    the pack in bounded slices so stop() stays observable.
+            # 3) Pipeline full, or no pack pending: drain the oldest
+            #    launch (the one after it, if any, is already running).
             if inflight:
                 self._drain_one(inflight)
-            elif packing:
-                try:
-                    packing[0][1].exception(timeout=0.25)
-                except cfut.TimeoutError:
-                    pass
         # Shutdown: every accepted request still gets its reply (clients
         # would otherwise block until their recv deadline and report a
         # spurious transport failure).
@@ -760,8 +763,10 @@ class VerifyEngine:
             for p in batch:
                 p.reply_fn([False] * len(p.request.msgs))
             return
-        dispatched_at, hop_s = self._trace_dispatch(scope, batch, t0) \
-            if scope.enabled else (0.0, 0.0)
+        ahead = len(inflight)  # launches this one is queued behind
+        self._sched.stats.note_dispatch(ahead)
+        dispatched_at, hop_s = self._trace_dispatch(
+            scope, batch, t0, ahead=ahead) if scope.enabled else (0.0, 0.0)
         inflight.append((batch, fetch, key, scope, dispatched_at, hop_s))
         self._inflight_n = len(inflight)
 

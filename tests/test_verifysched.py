@@ -5,6 +5,7 @@ equivalence asserted through the FULL engine path (not the crypto
 layer).
 """
 
+import collections
 import itertools
 import threading
 import time
@@ -14,6 +15,7 @@ import numpy as np
 import pytest
 
 from hotstuff_tpu.crypto import eddsa, ref_ed25519 as ref
+from hotstuff_tpu.obs.spans import NO_LAUNCH
 from hotstuff_tpu.sidecar import protocol as proto
 from hotstuff_tpu.sidecar import sched as vsched
 from hotstuff_tpu.sidecar import service
@@ -705,6 +707,64 @@ def test_pipeline_overlap_is_a_rolling_window():
     assert pipe["lifetime_pack_ms"] == pytest.approx(120.0)
     assert pipe["lifetime_overlap_ratio"] == pytest.approx(0.333,
                                                            abs=1e-3)
+
+
+def test_dispatch_ahead_share_is_a_rolling_window():
+    """``note_dispatch``: the ``pipeline`` section counts the launches
+    the staged engine dispatched and those it dispatched while another
+    was in flight, over the same window as ``overlap_ratio`` (age and
+    PIPE_WINDOW entries)."""
+    from hotstuff_tpu.sidecar.sched.stats import PIPE_WINDOW, PIPE_WINDOW_S
+
+    now = [1000.0]
+    stats = vsched.SchedStats(clock=lambda: now[0])
+    pipe = stats.snapshot()["pipeline"]
+    assert (pipe["dispatches"], pipe["dispatch_ahead"],
+            pipe["dispatch_ahead_share"]) == (0, 0, 0.0)
+    for _ in range(4):
+        stats.note_dispatch(0)
+    assert stats.snapshot()["pipeline"]["dispatch_ahead_share"] == 0.0
+    now[0] += PIPE_WINDOW_S + 1.0
+    for ahead in (0, 1, 2, 1):
+        stats.note_dispatch(ahead)
+    pipe = stats.snapshot()["pipeline"]
+    assert (pipe["dispatches"], pipe["dispatch_ahead"],
+            pipe["dispatch_ahead_share"]) == (4, 3, 0.75)
+    for _ in range(PIPE_WINDOW):
+        stats.note_dispatch(1)
+    pipe = stats.snapshot()["pipeline"]
+    assert (pipe["dispatches"], pipe["dispatch_ahead"],
+            pipe["dispatch_ahead_share"]) == (PIPE_WINDOW, PIPE_WINDOW, 1.0)
+
+
+def test_a_pack_is_hidden_while_another_launch_is_in_flight():
+    """``_inflight_n`` drops a launch as its drain BEGINS: a pack begun
+    while the older launch drains and a younger one is still in flight
+    runs beside the device (``hidden``); one begun while the only launch
+    drains does not (its program has finished: the device is idle)."""
+    engine = VerifyEngine(use_host=True)
+    try:
+        seen = []
+
+        def pack_beside_it():
+            seen.append(engine._inflight_n)
+            nxt = vsched.Pending(_req(4, 2), lambda m: None, vsched.BULK)
+            engine._pack([nxt])()()
+            return np.ones(4, bool)
+
+        inflight = collections.deque(
+            [([vsched.Pending(_req(4, rid), lambda m: None, vsched.BULK)],
+              pack_beside_it, "launch:8", NO_LAUNCH, 0.0, 0.0)
+             for rid in (1, 3)])
+        engine._inflight_n = len(inflight)
+        engine._drain_one(inflight)
+        engine._drain_one(inflight)
+        assert seen == [1, 0]
+        assert engine._inflight_n == 0
+        window = engine._sched.stats._pack_window
+        assert [hidden for _, _, hidden in window] == [True, False]
+    finally:
+        engine.stop()
 
 
 @pytest.mark.slow

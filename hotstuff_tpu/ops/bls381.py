@@ -2,10 +2,11 @@
 final exponentiation, with host-precomputed Miller line values.
 
 Work split (mirrors the Ed25519 engine's host/device boundary):
-* HOST (python bigints, ~2 ms/pairing): point decode/validation,
-  hash-to-G2, public-key aggregation, and the Miller loop's line values —
-  the curve bookkeeping is O(64) affine operations whose cost is
-  negligible next to the extension-field tower.
+* HOST (python bigints): point decode/validation, hash-to-G2, public-key
+  aggregation, and the Miller loop's line values — O(64) affine
+  operations over Fq12 a pairing, tens of ms of Python each (~44 ms a
+  pairing on a CPU core); PERF.md §5 has what they cost on the chip's
+  host beside the device program (``qc100bls.votes``).
 * DEVICE (the FLOPs): the Miller accumulation f <- f^2 * l_i over the 63
   BLS_X bits and the ~1,600-multiplication final exponentiation, all as
   batched Fq12 arithmetic on the Montgomery conv engine (field381.py).
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import field381 as F
+from ..obs.spans import NO_LAUNCH
 from ..offchain import bls12381 as host
 
 Q = host.Q
@@ -241,6 +243,40 @@ def selfcheck() -> None:
 # Aggregate verification (host orchestration + device check)
 # ---------------------------------------------------------------------------
 
+def aggregate_keys(pks):
+    """The sum of a certificate's keys (host G1 points), or None when a
+    key is malformed or the sum is the identity: nothing to pair."""
+    apk = None
+    for pk in pks:
+        if pk is None or not host.g1_on_curve(pk):
+            return None
+        apk = pk if apk is None else host.g1_add(apk, pk)
+    return apk
+
+
+def verify_common_apk(apk, msg: bytes, agg_sig, trace=NO_LAUNCH) -> bool:
+    """e(apk, H(m)) * e(-g1, agg_sig) == 1 for an aggregate key and an
+    on-curve aggregate signature: the digest hashed to G2 and the Miller
+    lines of both pairings on the host, the pairing program on the
+    device.  ``trace`` (an ``obs.spans.LaunchScope``) writes one span a
+    step: ``hash_to_g2``, ``miller_lines``, ``pairing`` (staging, dispatch
+    and the wait for the program) and ``d2h``."""
+    with trace.stage("hash_to_g2"):
+        h = host.hash_to_g2(msg)
+    with trace.stage("miller_lines") as tags:
+        lines = np.stack([miller_lines(apk, h),
+                          miller_lines(host.g1_neg(host.g1_generator()),
+                                       agg_sig)])
+        if tags is not None:
+            tags["pairings"] = len(lines)
+    with trace.stage("pairing") as tags:
+        out = jax.block_until_ready(pairings_check_jit(jnp.asarray(lines)))
+        if tags is not None:
+            tags["bytes"] = lines.nbytes
+    with trace.stage("d2h"):
+        return bool(np.asarray(out))
+
+
 def verify_aggregate_common(pks, msg: bytes, agg_sig) -> bool:
     """Same-message aggregate verify (the QC shape: 2f+1 votes on one
     digest): e(apk, H(m)) * e(-g1, agg_sig) == 1, pairing math on device.
@@ -250,18 +286,10 @@ def verify_aggregate_common(pks, msg: bytes, agg_sig) -> bool:
     # must reject, not crash the Miller-line precomputation.
     if agg_sig is None or not host.g2_on_curve(agg_sig):
         return False
-    apk = None
-    for pk in pks:
-        if pk is None or not host.g1_on_curve(pk):
-            return False
-        apk = pk if apk is None else host.g1_add(apk, pk)
+    apk = aggregate_keys(pks)
     if apk is None:
         return False
-    h = host.hash_to_g2(msg)
-    neg_g1 = host.g1_neg(host.g1_generator())
-    lines = np.stack([miller_lines(apk, h),
-                      miller_lines(neg_g1, agg_sig)])
-    return bool(np.asarray(pairings_check_jit(jnp.asarray(lines))))
+    return verify_common_apk(apk, msg, agg_sig)
 
 
 def multi_pairing_rows(pks, msgs, agg_sig):
@@ -293,4 +321,10 @@ def verify_aggregate_multi(pks, msgs, agg_sig) -> bool:
     rows = multi_pairing_rows(pks, msgs, agg_sig)
     if rows is None:
         return False
+    return verify_rows(rows)
+
+
+def verify_rows(rows) -> bool:
+    """The device check of ``multi_pairing_rows``' rows: every Miller
+    loop under one final exponentiation."""
     return bool(np.asarray(pairings_check_jit(jnp.asarray(np.stack(rows)))))

@@ -7,8 +7,9 @@ control flow), but q = 0x1a0111ea...aaab has no special form, so reduction
 is **Montgomery** with R = 2^384:
 
 * elements live in Montgomery form x~ = x*R mod q as (..., 48) int32 limb
-  arrays in "weak" form (limbs < 2^9, value < 2^385 — the REDC digit bound
-  keeps this stable across arbitrarily long chains);
+  arrays in "weak" form (limbs < 2^9, value < 2^385); a chain of bare
+  products stays bounded only from values under R (see pow_const), and
+  the Fq12 tower brings every product back with reduce_sum;
 * mont_mul does conv(48x48) -> wide carry -> m = T*q' mod R (conv + carry
   with truncation) -> T + m*q (conv) -> exact /R via a float32 carry-out
   dot (the low half's true value is divisible by 2^384, so its carry into
@@ -145,10 +146,16 @@ def _conv_product(a: jnp.ndarray, b: jnp.ndarray, nb: int) -> jnp.ndarray:
         n *= d
     lhs = a.reshape(1, n, na).astype(jnp.float32)
     rhs = jnp.flip(b.reshape(n, 1, nb), -1).astype(jnp.float32)
+    # A lone product (the final exponentiation's Fermat inverse runs at
+    # batch shape ()) is ONE plain convolution, which a TPU may run as
+    # three bf16 passes at HIGH: inexact once limbs pass 8 bits, as
+    # field25519's was on the v5e (PERF.md §6).  It takes HIGHEST;
+    # a grouped convolution keeps the measured-exact HIGH.
     out = jax.lax.conv_general_dilated(
         lhs, rhs, window_strides=(1,), padding=[(nb - 1, nb - 1)],
         dimension_numbers=("NCH", "OIH", "NCH"),
-        feature_group_count=n, precision=_PRECISION,
+        feature_group_count=n,
+        precision=jax.lax.Precision.HIGHEST if n == 1 else _PRECISION,
     ).reshape(*batch_shape, na + nb - 1)
     return out.astype(jnp.int32)
 
@@ -316,9 +323,17 @@ def pow_windowed(x, exponent: int, mul, one, window: int = 4):
 
 
 def pow_const(x: jnp.ndarray, exponent: int, window: int = 4) -> jnp.ndarray:
-    """x^exponent in Montgomery form, static exponent, windowed scan."""
+    """x^exponent in Montgomery form, static exponent, windowed scan.
+
+    The REDC output of a*b is at least a*b/R, so products of values
+    above R grow: from a weak input near 2^385 (reduce_sum's output
+    reaches it) the table's powers x^2 .. x^15 outgrew the conv
+    exactness bound, and on the chip 5 of 64 valid certificates' final
+    exponentiations came back wrong (PERF.md §6).  A product by
+    Montgomery one first leaves the residue and brings the value under
+    x*(R mod q)/R + q ~ 2^382.1, where every product stays below R."""
     one = jnp.broadcast_to(mont_constant(1), x.shape).astype(jnp.int32)
-    return pow_windowed(x, exponent, mont_mul, one, window)
+    return pow_windowed(mont_mul(x, one), exponent, mont_mul, one, window)
 
 
 def inv(x: jnp.ndarray) -> jnp.ndarray:
@@ -331,15 +346,22 @@ def inv(x: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 def mul_selfcheck(batch: int = 64, seed: int = 0) -> None:
+    """mont_mul against Python integers on a batch (grouped convolutions)
+    and on one pair at batch shape () (plain convolutions: the final
+    exponentiation's inverse runs there)."""
     rng = np.random.default_rng(seed)
     xs = [int(rng.integers(0, 2**62)) ** 7 % Q for _ in range(batch)]
     ys = [int(rng.integers(0, 2**62)) ** 7 % Q for _ in range(batch)]
     a = jnp.asarray(np.stack([to_limbs(x * R % Q) for x in xs]))
     b = jnp.asarray(np.stack([to_limbs(y * R % Q) for y in ys]))
     got = np.asarray(from_mont(mont_mul(a, b)))
+    # weak limbs (an add leaves them up to 2^9) into the lone product
+    lone = np.asarray(from_mont(mont_mul(add(a[0], b[0]), b[0])))
     for i, (x, y) in enumerate(zip(xs, ys)):
         want = x * y % Q
         have = from_limbs(got[i])
         if have != want:
             raise AssertionError(
                 f"field381 mont_mul mismatch at row {i}")
+    if from_limbs(lone) != (xs[0] + ys[0]) * ys[0] % Q:
+        raise AssertionError("field381 mont_mul mismatch at batch shape ()")

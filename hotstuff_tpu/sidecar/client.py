@@ -119,6 +119,18 @@ class SidecarClient:
             lambda r: proto.encode_bls_agg_request(r, msg, agg_sig, pks))
         return self._bls_verdict(self._await(rid))
 
+    def bls_verify_votes(self, msg: bytes, pks, sigs, *,
+                         ctx: bytes | None = None) -> bool:
+        """Common-message BLS verify of per-vote signatures (the frame a
+        ``scheme=bls`` replica ships for a QC, OP_BLS_VERIFY_VOTES): one
+        digest, a 96 B G1 key and a 192 B G2 vote a signer; the sidecar
+        aggregates the votes.  ``ctx`` as in :meth:`verify_batch`.
+        Raises SidecarOverloaded on a queue-full shed."""
+        rid = self._send(
+            lambda r: proto.encode_bls_votes_request(r, msg, pks, sigs,
+                                                     ctx=ctx))
+        return self._bls_verdict(self._await(rid))
+
     def bls_verify_multi(self, msgs, pks, sigs) -> bool:
         """Multi-digest BLS verify (the TC shape): n (digest, pk, sig)
         triples checked as one product of pairings in ONE round-trip.

@@ -87,6 +87,13 @@ class SchedStats:
         # canonical rows), rows found false.
         self.bisect = {"batches": 0, "programs": 0, "rows_per_sig": 0,
                        "bad_rows": 0}
+        # BLS verify requests the engine took, by kind (votes / agg /
+        # multi); Miller loops the device pairing program ran (2 a
+        # common-message certificate, n+1 a multi-digest one); requests
+        # rejected before any pairing (decode, subgroup, identity).
+        # Where each verdict came from is in ``paths`` (bls_pairing,
+        # host) and ``dedup.cache_hits``.
+        self.bls = {"requests": {}, "pairings": 0, "decode_rejects": 0}
         self.admitted: dict[str, int] = {}
         self.queue_full: dict[str, int] = {}
         self.carries: dict[str, int] = {}
@@ -232,6 +239,19 @@ class SchedStats:
         with self._lock:
             self.paths[path] = self.paths.get(path, 0) + 1
 
+    def note_bls_request(self, kind: str):
+        with self._lock:
+            reqs = self.bls["requests"]
+            reqs[kind] = reqs.get(kind, 0) + 1
+
+    def note_bls_pairings(self, pairings: int):
+        with self._lock:
+            self.bls["pairings"] += pairings
+
+    def note_bls_decode_reject(self):
+        with self._lock:
+            self.bls["decode_rejects"] += 1
+
     def note_bisect(self):
         """One batch whose combined check failed: ``paths.rlc_bisect``
         and ``bisect.batches`` together."""
@@ -353,6 +373,7 @@ class SchedStats:
                 "bulk_fill_sigs": self.bulk_fill_sigs,
                 "paths": dict(self.paths),
                 "bisect": dict(self.bisect),
+                "bls": dict(self.bls, requests=dict(self.bls["requests"])),
                 "admitted": dict(self.admitted),
                 "queue_full": dict(self.queue_full),
                 "carries": dict(self.carries),

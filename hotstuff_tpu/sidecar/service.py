@@ -67,6 +67,13 @@ _Pending = vsched.Pending
 from base64 import b64encode as _b64encode
 
 
+# OP_BLS verify requests by kind: the guard's shape keys and OP_STATS
+# ``bls.requests``.
+_BLS_VERIFY_KINDS = {proto.BlsAggRequest: "agg",
+                     proto.BlsVotesRequest: "votes",
+                     proto.BlsMultiRequest: "multi"}
+
+
 def _ctx_tag(request):
     """Protocol v5 block-digest context tag -> the base64 string the C++
     node logs in its TRACE lines (common/bytes.hpp base64_encode:
@@ -472,8 +479,9 @@ class VerifyEngine:
         """Verdict-cache key for a BLS verify request, or None if the op
         is uncacheable (signing).  Validity is a pure function of the
         request's own bytes, so the whole request keys the verdict — a
-        pairing costs seconds on host and ~100 ms on device, making the
-        N-replicas-one-certificate dedup worth far more here than for
+        pairing costs seconds on the host, and what a certificate costs
+        on the device path is in PERF.md §5 (``qc100bls.votes``), making
+        the N-replicas-one-certificate dedup worth far more here than for
         Ed25519."""
         import hashlib
 
@@ -508,7 +516,11 @@ class VerifyEngine:
         if key is None:
             return None
         v = self._verdicts.get(key)
-        return None if v is None else [v]
+        if v is None:
+            return None
+        with self._verdicts_lock:
+            self._dedup_cache_hits += 1
+        return [v]
 
     def enable_bulk(self):
         """Raise the per-launch cap to MAX_COALESCED; call only after the
@@ -600,7 +612,10 @@ class VerifyEngine:
                             self._drain_one(inflight)
                         tags = {}
                         if scope.enabled:
-                            tags = {"lid": scope.lid, "parent": None}
+                            # The launch's own id: the BLS stages
+                            # (bls_prep ... d2h) name it as parent.
+                            tags = {"lid": scope.lid, "parent": None,
+                                    "id": scope.device_id}
                             ctx = _ctx_tag(item.request)
                             if ctx:
                                 # v5 context tag: scheme=bls device spans
@@ -618,7 +633,7 @@ class VerifyEngine:
                             # escaped after a success path had already
                             # answered, e.g. a wedged-then-completing
                             # pairing).
-                            self._execute_bls(item)
+                            self._execute_bls(item, scope)
                         continue
                     batch = launch.items
                     packing.append(
@@ -1306,12 +1321,26 @@ class VerifyEngine:
 
         if isinstance(req, proto.BlsSignRequest):
             return "bls:sign"
-        kind = {proto.BlsAggRequest: "agg",
-                proto.BlsVotesRequest: "votes",
-                proto.BlsMultiRequest: "multi"}[type(req)]
+        kind = _BLS_VERIFY_KINDS[type(req)]
         return f"bls:{kind}:{next_pow2(max(1, len(req.pks)))}"
 
-    def _execute_bls(self, item):
+    def _note_bls_source(self, req, source):
+        """Count where a BLS verdict came from (``_execute_bls``)."""
+        stats = self._sched.stats
+        if source == "bls_pairing":
+            stats.note_path(source)
+            stats.note_bls_pairings(
+                len(req.pks) + 1 if isinstance(req, proto.BlsMultiRequest)
+                else 2)
+        elif source == "host":
+            stats.note_path(source)
+        elif source == "cache":
+            with self._verdicts_lock:
+                self._dedup_cache_hits += 1
+        elif source == "reject":
+            stats.note_bls_decode_reject()
+
+    def _execute_bls(self, item, scope=NO_LAUNCH):
         """Run one BLS request under the launch guard (engine thread).
 
         The request body executes on one of the guard's DISPOSABLE
@@ -1339,10 +1368,21 @@ class VerifyEngine:
         backend exception) must reply ``None`` and NEVER a cacheable
         ``[False]``: the verdict cache is shared by every replica, so one
         poisoned entry would reject a valid certificate fleet-wide.
+
+        Counting: ``bls.requests`` by kind as a verify request arrives;
+        on a clean return, where the verdict came from (the inner body
+        says): ``paths.bls_pairing`` and ``bls.pairings`` (Miller loops
+        the device program ran), ``paths.host``, ``dedup.cache_hits``, or
+        ``bls.decode_rejects`` (rejected before any pairing).  ``scope``
+        is the launch's tracer scope: the body's stages are children of
+        its ``device`` span.
         """
         req = item.request
-        cache_key = self.bls_cache_key(req) \
-            if not isinstance(req, proto.BlsSignRequest) else None
+        stats = self._sched.stats
+        cache_key = None
+        if not isinstance(req, proto.BlsSignRequest):
+            cache_key = self.bls_cache_key(req)
+            stats.note_bls_request(_BLS_VERIFY_KINDS[type(req)])
         replied = [False]
 
         def reply(payload, *, cacheable=False):
@@ -1360,8 +1400,9 @@ class VerifyEngine:
 
         key = self._bls_guard_key(req)
         try:
-            payload, cacheable = self._guarded(
-                key, lambda: self._execute_bls_inner(req, cache_key))
+            payload, cacheable, source = self._guarded(
+                key, lambda: self._execute_bls_inner(req, cache_key, scope))
+            self._note_bls_source(req, source)
             reply(payload, cacheable=cacheable)
         except WedgedLaunch:
             # BLS arm of the wedge ladder.  No host re-verify here: the
@@ -1385,24 +1426,29 @@ class VerifyEngine:
                       req.request_id)
             reply(None)
 
-    def _execute_bls_inner(self, req, cache_key):
+    def _execute_bls_inner(self, req, cache_key, scope=NO_LAUNCH):
         """The BLS request body; runs on a disposable guard launch
-        thread and RETURNS ``(payload, cacheable)`` — it must not touch
-        the connection (a wedged call's late completion is discarded by
-        the guard; only the engine thread replies)."""
+        thread and RETURNS ``(payload, cacheable, source)`` — it must not
+        touch the connection (a wedged call's late completion is
+        discarded by the guard; only the engine thread replies).
+        ``source`` says where a verdict came from: ``bls_pairing`` (the
+        device program), ``host``, ``cache`` or ``reject`` (no pairing
+        ran); None for a signature.  A traced common-message verify
+        writes ``bls_prep`` (decode, aggregate, subgroup test, keys and
+        their sum) and the device path's stages under ``scope``."""
         from ..offchain import bls12381 as bls
 
         if isinstance(req, proto.BlsSignRequest):
             # Signing is G2 scalar multiplication — host bigint work, no
             # pairing; mirrors the reference keeping signing on CPU.
             sk = int.from_bytes(req.sk, "big")
-            return bls.g2_encode(bls.sign(sk, req.msg)), False
+            return bls.g2_encode(bls.sign(sk, req.msg)), False, None
         # Verdict cache (same FIFO as Ed25519, keyed on the full request):
         # N replicas verifying one certificate cost one pairing.  Decode
         # failures cache as False — deterministic in the request bytes.
         cached = self._verdicts.get(cache_key) if cache_key else None
         if cached is not None:
-            return [cached], False
+            return [cached], False, "cache"
 
         if isinstance(req, proto.BlsMultiRequest):
             # TC shape: per-vote signatures over DISTINCT digests in one
@@ -1414,46 +1460,57 @@ class VerifyEngine:
                 agg = bls.aggregate(
                     [bls.g2_decode_lax(s) for s in req.sigs])
                 if not bls.g2_in_subgroup(agg):
-                    return [False], True
+                    return [False], True, "reject"
                 pks = [bls.g1_decode(p) for p in req.pks]
             except ValueError:
-                return [False], True
+                return [False], True, "reject"
             if self._use_host or len(pks) not in self._bls_multi_warmed:
                 if not self._use_host:
                     log.warning(
                         "BLS multi shape for %d votes not warmed "
                         "(--warm-bls-multi); verifying on host", len(pks))
                 ok = bls.verify_aggregate(pks, req.msgs, agg)
-            else:
-                from ..ops import bls381 as dbls
-
-                ok = dbls.verify_aggregate_multi(pks, req.msgs, agg)
-            return [bool(ok)], True
-        try:
-            if isinstance(req, proto.BlsVotesRequest):
-                # C++ nodes ship per-vote signatures; aggregate them here
-                # (host G2 adds), then run the same common-message check.
-                # Fresh per-vote sigs get on-curve checks only; the single
-                # aggregate gets the [R]P subgroup test — the pairing
-                # statement depends only on the aggregate, so this is the
-                # same soundness at 1/N the host cost (per-vote subgroup
-                # ladders can't be cached the way committee keys can).
-                agg = bls.aggregate(
-                    [bls.g2_decode_lax(s) for s in req.sigs])
-                if not bls.g2_in_subgroup(agg):
-                    return [False], True
-            else:
-                agg = bls.g2_decode(req.agg_sig)
-            pks = [bls.g1_decode(p) for p in req.pks]
-        except ValueError:
-            return [False], True
-        if self._use_host:
-            ok = bls.verify_aggregate_common(pks, req.msg, agg)
-        else:
+                return [bool(ok)], True, "host"
             from ..ops import bls381 as dbls
 
-            ok = dbls.verify_aggregate_common(pks, req.msg, agg)
-        return [bool(ok)], True
+            rows = dbls.multi_pairing_rows(pks, req.msgs, agg)
+            if rows is None:
+                return [False], True, "reject"
+            return [dbls.verify_rows(rows)], True, "bls_pairing"
+        with scope.stage("bls_prep") as tags:
+            if tags is not None:
+                tags["n"] = len(req.pks)
+            try:
+                if isinstance(req, proto.BlsVotesRequest):
+                    # C++ nodes ship per-vote signatures; aggregate them
+                    # here (host G2 adds), then run the same
+                    # common-message check.  Fresh per-vote sigs get
+                    # on-curve checks only; the single aggregate gets the
+                    # [R]P subgroup test — the pairing statement depends
+                    # only on the aggregate, so this is the same soundness
+                    # at 1/N the host cost (per-vote subgroup ladders
+                    # can't be cached the way committee keys can).
+                    agg = bls.aggregate(
+                        [bls.g2_decode_lax(s) for s in req.sigs])
+                    if not bls.g2_in_subgroup(agg):
+                        return [False], True, "reject"
+                else:
+                    agg = bls.g2_decode(req.agg_sig)
+                pks = [bls.g1_decode(p) for p in req.pks]
+            except ValueError:
+                return [False], True, "reject"
+            if not self._use_host:
+                from ..ops import bls381 as dbls
+
+                # None: a key is the identity, or the keys sum to it.
+                apk = dbls.aggregate_keys(pks)
+                if agg is None or apk is None:
+                    return [False], True, "reject"
+        if self._use_host:
+            ok = bls.verify_aggregate_common(pks, req.msg, agg)
+            return [bool(ok)], True, "host"
+        ok = dbls.verify_common_apk(apk, req.msg, agg, scope)
+        return [bool(ok)], True, "bls_pairing"
 
     # graftlint: sanitizes=device-verdict
     def _verify_submit(self, msgs, pks, sigs, force_device: bool = False):
@@ -1821,7 +1878,7 @@ def serve(host: str = "127.0.0.1", port: int = 7100,
         try:
             _warmup(engine, warm_max)
             if warm_bls:
-                tracker.warm("bls:pairing", _warmup_bls)
+                _warmed(engine, "bls:pairing", _warmup_bls)
             if warm_bls_multi:
                 tracker.warm(
                     f"bls_multi:{warm_bls_multi}",
@@ -1953,9 +2010,10 @@ def _warmup_bls(n_pks: int = 3):
     msg = b"warmup"
     keys = [bls.key_gen(bytes([i]) * 32) for i in range(1, n_pks + 1)]
     agg = bls.aggregate([bls.sign(sk, msg) for sk, _ in keys])
-    _require_valid(
-        dbls.verify_aggregate_common([pk for _, pk in keys], msg, agg),
-        "BLS warmup verify")
+    # The served entry (_execute_bls_inner): keys summed, then the check.
+    apk = dbls.aggregate_keys([pk for _, pk in keys])
+    _require_valid(dbls.verify_common_apk(apk, msg, agg),
+                   "BLS warmup verify")
     log.info("BLS pairing warmup done in %.1fs", monotonic() - t0)
 
 

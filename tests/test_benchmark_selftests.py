@@ -31,7 +31,8 @@ _SLOW = ("test_byz.py", "test_correct.py", "test_gate_rehearsal.py")
 
 
 @pytest.mark.parametrize("name", [
-    "test_arith.py", "test_gate.py", "test_manifest.py", "test_readers.py",
+    "test_arith.py", "test_bls_votes.py", "test_gate.py", "test_manifest.py",
+    "test_readers.py",
     "test_run.py", "test_span_tree.py", "test_streams.py",
     "test_trace_reduce.py",
     *(pytest.param(name, marks=pytest.mark.slow) for name in _SLOW)])

@@ -20,6 +20,7 @@ import pytest
 
 from conftest import REPO, boot_without_serving
 from hotstuff_tpu.crypto import eddsa
+from hotstuff_tpu.ops import bls381
 from hotstuff_tpu.sidecar import service
 from hotstuff_tpu.sidecar.sched.shapes import quorum_sigs
 
@@ -37,9 +38,10 @@ RLC_CONFIGS = sorted(n for n, c in CONFIGS.items() if c["route"] == "rlc")
 
 # PERF.md §4: "ten shapes" (qc100, and qc100f33 whose programs are
 # qc100's), "eight ladder shapes" (eddsa1024, and ingress20 whose
-# programs are eddsa1024's).
+# programs are eddsa1024's), "two shapes" (qc100bls: the ladder at 8 and
+# the pairing program).
 STATED_PLAN_SIZE = {"qc100": 10, "qc100f33": 10, "eddsa1024": 8,
-                    "ingress20": 8}
+                    "ingress20": 8, "qc100bls": 2}
 
 
 def _widths(name):
@@ -58,12 +60,14 @@ def _widths(name):
 
 def _stated_plan(sidecar):
     """The keys of PERF.md §4's plan: the per-signature ladder 8 ..
-    ``warm_max`` and then, under ``warm_rlc``, the one-MSM program at the
-    same buckets."""
+    ``warm_max``, then under ``warm_bls`` the pairing program, then under
+    ``warm_rlc`` the one-MSM program at the ladder's buckets."""
     buckets = [8]
     while buckets[-1] * 2 <= sidecar["warm_max"]:
         buckets.append(buckets[-1] * 2)
     keys = [f"warmup:{n}" for n in buckets]
+    if sidecar.get("warm_bls"):
+        keys.append("bls:pairing")
     if sidecar.get("warm_rlc"):
         keys += [f"rlc:{n}" for n in buckets]
     return keys
@@ -80,6 +84,9 @@ def booted(request, monkeypatch, tmp_path):
 
     monkeypatch.setattr(service.VerifyEngine, "_verify", all_true)
     monkeypatch.setattr(eddsa, "verify_batch_rlc", all_true)
+    monkeypatch.setattr(bls381, "selfcheck", lambda: None)
+    monkeypatch.setattr(bls381, "verify_common_apk",
+                        lambda apk, msg, agg: True)
     keys = []
     warmed = service._warmed
 
@@ -111,9 +118,14 @@ def test_warm_up_plan_is_the_stated_one(booted):
 
 @pytest.mark.parametrize("booted", sorted(CONFIGS), indirect=True)
 def test_traffic_width_takes_the_configured_route(booted):
-    name, _, engine = booted
+    name, keys, engine = booted
     widths = _widths(name)
     assert widths, f"no cell of BENCHMARK.json runs {name}"
+    if CONFIGS[name]["route"] == "bls_pairing":
+        # A BLS certificate takes the pairing program by its opcode,
+        # whatever its width: the program has to be warm.
+        assert "bls:pairing" in keys
+        return
     for n in widths:
         assert engine._shapes.route(n) == CONFIGS[name]["route"], n
 

@@ -8,8 +8,9 @@ Work split (mirrors the Ed25519 engine's host/device boundary):
   pairing on a CPU core); PERF.md §5 has what they cost on the chip's
   host beside the device program (``qc100bls.votes``).
 * DEVICE (the FLOPs): the Miller accumulation f <- f^2 * l_i over the 63
-  BLS_X bits and the ~1,600-multiplication final exponentiation, all as
-  batched Fq12 arithmetic on the Montgomery conv engine (field381.py).
+  BLS_X bits and the final exponentiation (its hard part by the curve
+  parameter: ~560 chained Fq12 products), all as batched Fq12 arithmetic
+  on the Montgomery conv engine (field381.py).
 
 An Fq12 element is a (..., 12, 48) int32 array — a flat degree-12
 polynomial over Fq (modulus w^12 - 2w^6 + 2, matching the host reference
@@ -104,10 +105,20 @@ def _frob_matrices():
 
 _FROB = _frob_matrices()
 
-# Final-exponentiation hard part: (q^4 - q^2 + 1) / r.
+# Final-exponentiation hard part: (q^4 - q^2 + 1) / r, factored through
+# the curve parameter x = -BLS_X as ((x-1)^2/3)(x+q)(x^2+q^2-1) + 1: a
+# 126-bit power, three powers by |x| and Frobenius maps.
 _HARD_EXP = (Q ** 4 - Q ** 2 + 1) // host.R
+_HARD_LAMBDA = (BLS_X + 1) ** 2 // 3      # (x-1)^2/3, exact: x = 1 mod 3
+# Window 3 costs the 126-bit power what window 4 does (6 + 42 x 4 = 174
+# products against 14 + 32 x 5) with half the product bodies in the
+# program.
+_LAMBDA_WINDOW = 3
 assert (Q ** 12 - 1) % host.R == 0
 assert (Q ** 6 - 1) * (Q ** 2 + 1) * _HARD_EXP == (Q ** 12 - 1) // host.R
+assert (BLS_X + 1) ** 2 % 3 == 0
+assert _HARD_EXP == \
+    _HARD_LAMBDA * (Q - BLS_X) * (BLS_X ** 2 + Q ** 2 - 1) + 1
 
 
 # ---------------------------------------------------------------------------
@@ -171,6 +182,23 @@ def fq12_pow_const(x: jnp.ndarray, exponent: int,
                           window)
 
 
+_ODD = np.arange(12)[:, None] % 2 == 1
+
+
+def _conj(y: jnp.ndarray) -> jnp.ndarray:
+    """y^(q^6) with no product: w^(q^6) = -w negates the odd
+    coefficients.  In the cyclotomic subgroup, where the final
+    exponentiation's hard part runs, that is y^-1.  A negation's value
+    reaches 64q; reduce_sum brings it back to weak form."""
+    return jnp.where(_ODD, F.reduce_sum(F.neg(y)), y)
+
+
+def _cyclotomic_exp_x(y: jnp.ndarray) -> jnp.ndarray:
+    """y^|x| = y^BLS_X: one scan of 64 square-and-multiply steps
+    (window 1: no table outside the scan), 128 products."""
+    return fq12_pow_const(y, BLS_X, window=1)
+
+
 # ---------------------------------------------------------------------------
 # Pairing pieces
 # ---------------------------------------------------------------------------
@@ -193,10 +221,17 @@ def miller_accumulate(lines: jnp.ndarray) -> jnp.ndarray:
 
 def final_exponentiate(f: jnp.ndarray) -> jnp.ndarray:
     """f^((q^12-1)/r): easy part via Frobenius + norm-inversion, hard part
-    as one windowed exponentiation by (q^4 - q^2 + 1)/r."""
+    f2^((q^4 - q^2 + 1)/r) by the curve parameter (_HARD_LAMBDA's
+    identity). f2 lies in the cyclotomic subgroup, so y^x = conj(y^|x|)."""
     f1 = fq12_mul(fq12_frobenius(f, 6), fq12_inv(f))      # f^(q^6 - 1)
     f2 = fq12_mul(fq12_frobenius(f1, 2), f1)              # ^(q^2 + 1)
-    return fq12_pow_const(f2, _HARD_EXP)
+    a = fq12_pow_const(f2, _HARD_LAMBDA, _LAMBDA_WINDOW)  # ^((x-1)^2/3)
+    b = fq12_mul(_conj(_cyclotomic_exp_x(a)),
+                 fq12_frobenius(a, 1))                    # ^(x + q)
+    c = fq12_mul(fq12_mul(_cyclotomic_exp_x(_cyclotomic_exp_x(b)),
+                          fq12_frobenius(b, 2)),
+                 _conj(b))                                # ^(x^2 + q^2 - 1)
+    return fq12_mul(c, f2)
 
 
 def is_one(f: jnp.ndarray) -> jnp.ndarray:

@@ -8,6 +8,7 @@ reference (offchain/bls12381.py).
 import numpy as np
 import pytest
 
+jax = pytest.importorskip("jax")
 jnp = pytest.importorskip("jax.numpy")
 
 from hotstuff_tpu.offchain import bls12381 as host
@@ -102,6 +103,51 @@ def test_fq12_frobenius_and_inverse():
     assert from_dev(D.fq12_inv(dx)) == host.fq12_inv(x)
 
 
+def cyclotomic_fq12():
+    """A random Fq12 value after the easy part of the final
+    exponentiation, f^((q^6 - 1)(q^2 + 1)): an element of the cyclotomic
+    subgroup, where the hard part's chain runs."""
+    x = rand_fq12()
+    f1 = host.fq12_mul(host.fq12_pow(x, host.Q ** 6), host.fq12_inv(x))
+    return host.fq12_mul(host.fq12_pow(f1, host.Q ** 2), f1)
+
+
+def test_hard_exponent_factors_through_the_curve_parameter():
+    """(q^4 - q^2 + 1)/r = ((x-1)^2/3)(x+q)(x^2+q^2-1) + 1 for the
+    curve's x = -BLS_X, in Python integers."""
+    q, x = host.Q, -host.BLS_X
+    assert (x - 1) ** 2 % 3 == 0
+    assert D._HARD_LAMBDA == (x - 1) ** 2 // 3
+    assert D._HARD_LAMBDA.bit_length() == 126
+    assert (q ** 4 - q ** 2 + 1) % host.R == 0
+    assert (q ** 4 - q ** 2 + 1) // host.R == \
+        D._HARD_LAMBDA * (x + q) * (x ** 2 + q ** 2 - 1) + 1
+
+
+def test_cyclotomic_exp_x_matches_host():
+    y = cyclotomic_fq12()
+    got = from_dev(jax.jit(D._cyclotomic_exp_x)(to_dev(y)))
+    assert got == host.fq12_pow(y, host.BLS_X)
+
+
+def test_conj_is_frobenius_6_and_the_cyclotomic_inverse():
+    y = cyclotomic_fq12()
+    conj = from_dev(D._conj(to_dev(y)))
+    assert conj == from_dev(D.fq12_frobenius(to_dev(y), 6))
+    assert conj == host.fq12_inv(y)
+    # a negation's value reaches 64q: the output is back in weak form
+    # and a product of it stays exact
+    assert from_dev(D.fq12_mul(D._conj(to_dev(y)), to_dev(y))) == \
+        host.FQ12_ONE
+
+
+@pytest.mark.slow  # ~3 min XLA compile on the CPU
+def test_final_exponentiate_matches_host():
+    x = rand_fq12()
+    got = from_dev(jax.jit(D.final_exponentiate)(to_dev(x)))
+    assert got == host.final_exponentiate(x)
+
+
 def test_miller_lines_match_host_miller():
     """Accumulating the host-precomputed lines reproduces the host Miller
     value (up to the BLS_X-sign inversion the device skips)."""
@@ -163,11 +209,11 @@ def test_pairing_muls_json_counts_the_traced_program():
     one pairing check) against the program: Fq multiplications counted
     in one traced lowering of ``pairings_check`` — every convolution is
     a third of a Montgomery multiply over its feature groups, times the
-    trips of the scans around it."""
+    trips of the scans around it.  The yardstick's ``hard_part`` counts
+    the windowed power by the 1,268-bit exponent; the program runs the
+    chain through the curve parameter, counted here from its constants."""
     import json
     import os
-
-    import jax
 
     def fq_muls(jaxpr, trips=1):
         total = 0
@@ -191,6 +237,17 @@ def test_pairing_muls_json_counts_the_traced_program():
         "pairing_muls.json")
     with open(path, encoding="utf-8") as f:
         model = json.load(f)
-    assert convs == 3 * model["fq_muls_total"]
+
+    def pow_products(exponent, window):   # F.pow_windowed's Fq12 products
+        steps = -(-exponent.bit_length() // window)
+        return 2 ** window - 2 + steps * (window + 1)
+
+    # the 126-bit power, three powers by |x|, four products and two
+    # Frobenius maps (a matrix product each); _conj multiplies nothing
+    chain = 144 * (pow_products(D._HARD_LAMBDA, D._LAMBDA_WINDOW)
+                   + 3 * pow_products(D.BLS_X, 1) + 4 + 2)
+    assert chain == 144 * 564
+    assert convs == 3 * (model["fq_muls_total"]
+                         - model["fq_muls"]["hard_part"] + chain)
     assert sum(model["fq_muls"].values()) == model["fq_muls_total"]
     assert model["bytes_in"] == 2 * D.N_STEPS * 2 * 12 * F.NLIMBS * 4

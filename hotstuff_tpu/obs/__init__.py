@@ -13,6 +13,12 @@ package makes every claim attributable to a *place in the pipeline*:
     clock — graftlint's ``unclosed-span`` checker enforces both that
     and the begin/end pairing discipline.
 
+``cpu``
+    The CPU time of the sidecar's Python threads by role (engine and
+    guard launch threads, pack worker, connection threads): a registry
+    each thread body enters and leaves, read only for OP_STATS
+    ``host``, with its interval since the previous reply.
+
 ``trace``
     The collector/merger: parses the C++ node's ``TRACE`` lines
     (proposal -> verify_submit -> verify_reply -> commit, keyed on

@@ -38,11 +38,13 @@ Everything else is free-form tags.
 
 The sink keeps spans in memory: a record is appended to a list under the
 lock — no ``json.dumps``, no I/O under it — and ``close()`` writes them.
-The buffer is bounded at ``BUFFER_SPANS`` records (8192: a traced 20 s
-window of one closed-loop connection leaves ~4,400, so no write-out
-falls inside it; ~1.4 MB of JSONL).  The call that fills it swaps the
-list out under the lock and serialises and writes it OUTSIDE the lock,
-under a separate I/O lock it only ever tries for: that one thread pays
+The buffer is bounded at ``BUFFER_SPANS`` records (65,536: the busiest
+traced 20 s window, ``ingress20.gate``'s, leaves ~58,000 spans and
+``qc100.solo``'s ~23,000, so no write-out falls inside a window; at the
+bound ~45 MB of span dicts in memory and ~14 MB of JSONL).  The call
+that fills it swaps the list out under the lock and serialises and
+writes it OUTSIDE the lock, under a separate I/O lock it only ever
+tries for: that one thread pays
 the write-out (PERF.md §6 has the measured cost), and a call that finds
 a write-out under way appends and returns, so no span site waits on
 another thread's ``json.dumps`` or file write — the buffer overshoots
@@ -120,7 +122,7 @@ class Tracer:
     profiler session holds the sidecar's stages on the trace's own clock.
     """
 
-    BUFFER_SPANS = 8192
+    BUFFER_SPANS = 65536
 
     def __init__(self, path: str | None, clock=None, annotation=None):
         self._path = path

@@ -79,6 +79,8 @@ import os
 import threading
 from time import monotonic
 
+from ..obs.cpu import ThreadCpu
+
 log = logging.getLogger("sidecar.guard")
 
 
@@ -441,7 +443,8 @@ class LaunchGuard:
     def __init__(self, deadlines: LaunchDeadlines | None = None,
                  stats: GuardStats | None = None, clock=monotonic,
                  max_reboots: int | None = None,
-                 max_bisect_probes: int | None = None):
+                 max_bisect_probes: int | None = None,
+                 cpu: ThreadCpu | None = None):
         self.deadlines = deadlines if deadlines is not None \
             else LaunchDeadlines()
         self.stats = stats if stats is not None else GuardStats()
@@ -452,6 +455,9 @@ class LaunchGuard:
             if max_bisect_probes is not None else int(_env_float(
                 "HOTSTUFF_TPU_GUARD_MAX_BISECT_PROBES", 64))
         self._clock = clock
+        # The sidecar's thread-CPU registry (obs/cpu.py): every launch
+        # thread counts under ``launch``; the engine shares this one.
+        self.cpu = cpu if cpu is not None else ThreadCpu()
         self._lock = threading.Lock()
         self._calls: set = set()
         self._last = threading.local()  # per calling thread: last_hop_s
@@ -487,6 +493,15 @@ class LaunchGuard:
                     call.done.set()
 
     def _run_call(self, call: _GuardedCall, thunk):
+        self.cpu.enter("launch")
+        try:
+            self._run_thunk(call, thunk)
+        finally:
+            # The thread's one CPU-clock read, after the caller was
+            # woken: outside ``thunk_s`` and the caller's hop.
+            self.cpu.leave()
+
+    def _run_thunk(self, call: _GuardedCall, thunk):
         t0 = self._clock()
         try:
             call.result = thunk()

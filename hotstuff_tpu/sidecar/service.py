@@ -284,6 +284,14 @@ class VerifyEngine:
 
         self._tracer = tracer if tracer is not None else Tracer.disabled()
         self._launches = 0   # launch counter: a launch's ``lid``
+        # The CPU of the sidecar's Python threads by role (obs/cpu.py),
+        # one registry with the guard's launch threads: OP_STATS
+        # ``host``, read only when asked.
+        from ..obs.cpu import CpuWindow, ThreadCpu
+
+        cpu = guard.cpu if guard is not None else ThreadCpu()
+        self._cpu = cpu
+        self._host = CpuWindow(cpu)
         # Device multi-digest pairing programs compile one shape per vote
         # count (minutes each); only counts warmed via _warmup_bls_multi
         # may launch on device — others verify on host so a surprise TC
@@ -353,8 +361,12 @@ class VerifyEngine:
         # bound how much work leaves the bounded class queues.
         from concurrent.futures import ThreadPoolExecutor
 
+        # The worker enters the CPU registry as ``pack`` and never
+        # leaves it: it ends with the engine, and the registry keeps a
+        # thread's last read.
         self._pack_pool = ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="verify-pack")
+            max_workers=1, thread_name_prefix="verify-pack",
+            initializer=cpu.enter, initargs=("pack",))
         self._inflight_n = 0  # launches executing on device (telemetry)
         self._stopped = threading.Event()
         # graftcadence: the resident continuous-batching ring
@@ -453,6 +465,9 @@ class VerifyEngine:
             # graftcadence: tick rate, occupancy hist, pad-fill ratio,
             # generation drops, queue-wait p50/p99 (sidecar/ring.py).
             snap["cadence"] = self._ring.snapshot()
+        # The Python threads' CPU by role, and its interval since the
+        # previous OP_STATS reply (obs/cpu.py CpuWindow).
+        snap["host"] = self._host.section(self._launches)
         return snap
 
     # graftlint: sanitizes=device-verdict
@@ -562,13 +577,17 @@ class VerifyEngine:
         with every in-flight generation answered), then the staged
         request-driven loop — the DEFAULT path and the ladder's landing
         zone."""
-        ring = self._ring
-        if ring is not None:
-            ring.run()
-            if self._stopped.is_set():
-                self._pack_pool.shutdown(wait=False)
-                return
-        self._run_staged()
+        self._cpu.enter("engine")
+        try:
+            ring = self._ring
+            if ring is not None:
+                ring.run()
+                if self._stopped.is_set():
+                    self._pack_pool.shutdown(wait=False)
+                    return
+            self._run_staged()
+        finally:
+            self._cpu.leave()
 
     def _run_staged(self):
         import collections
@@ -1557,7 +1576,17 @@ class _Handler(socketserver.BaseRequestHandler):
     of its reply returned, or the refusal — with ``decode`` and ``reply``
     children written here and ``queue`` by the engine; its reply rides
     the outbox as ``(frame, sent)`` (``_RequestSpan.framed``).  Untraced,
-    the outbox carries bare frames."""
+    the outbox carries bare frames.
+
+    The handler thread and its writer count as ``conn`` in the engine's
+    thread-CPU registry (obs/cpu.py), the handler from ``setup`` to
+    ``finish``."""
+
+    def setup(self):
+        self.server.engine._cpu.enter("conn")
+
+    def finish(self):
+        self.server.engine._cpu.leave()
 
     def handle(self):
         sock = self.request
@@ -1568,6 +1597,13 @@ class _Handler(socketserver.BaseRequestHandler):
             queue.Queue(maxsize=1024)
 
         def writer():
+            engine._cpu.enter("conn")
+            try:
+                write()
+            finally:
+                engine._cpu.leave()
+
+        def write():
             while True:
                 frame = outbox.get()
                 if frame is None:

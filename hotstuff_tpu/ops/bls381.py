@@ -3,10 +3,13 @@ final exponentiation, with host-precomputed Miller line values.
 
 Work split (mirrors the Ed25519 engine's host/device boundary):
 * HOST (python bigints): point decode/validation, hash-to-G2, public-key
-  aggregation, and the Miller loop's line values — O(64) affine
-  operations over Fq12 a pairing, tens of ms of Python each (~44 ms a
-  pairing on a CPU core); PERF.md §5 has what they cost on the chip's
-  host beside the device program (``qc100bls.votes``).
+  aggregation, and the Miller loop's line values — computed on the G2
+  twist over Fq2: 68 affine steps a pairing, each one Fq2 slope shared
+  by the line and the point update, the line mapped into Fq12 as five
+  sparse coefficients; a certificate's pairings walk in lockstep and
+  share one Fq inversion a step (~6 ms a pairing on a CPU core); PERF.md
+  §5 has what they cost on the chip's host beside the device program
+  (``qc100bls.votes``).
 * DEVICE (the FLOPs): the Miller accumulation f <- f^2 * l_i over the 63
   BLS_X bits and the final exponentiation (its hard part by the curve
   parameter: ~560 chained Fq12 products), all as batched Fq12 arithmetic
@@ -54,24 +57,119 @@ def host_fq12_to_mont_limbs(x) -> np.ndarray:
     return np.stack([F.to_limbs(c * F.R % Q) for c in x])
 
 
-def miller_lines(p_g1, q_g2) -> np.ndarray:
-    """Run the host Miller loop recording line values: (N_STEPS, 2, 12, 48)
-    Montgomery limbs. Slot 0 is the doubling line, slot 1 the addition
-    line (identity 1 on clear bits so the device body is uniform)."""
-    qt = host._twist(q_g2)
-    pf = host._cast_g1_fq12(p_g1)
-    one = host.FQ12_ONE
-    rpt = qt
-    out = np.zeros((N_STEPS, 2, 12, F.NLIMBS), np.int32)
-    for i, bit in enumerate(_BITS):
-        out[i, 0] = host_fq12_to_mont_limbs(host._linefunc(rpt, rpt, pf))
-        rpt = host._add(rpt, rpt, host._fq12)
-        if bit:
-            out[i, 1] = host_fq12_to_mont_limbs(host._linefunc(rpt, qt, pf))
-            rpt = host._add(rpt, qt, host._fq12)
+# Miller lines on the twist.  A G2 point (x, y) over Fq2 sits in E(Fq12)
+# as (x~ w^-2, y~ w^-3) (host._twist), where a~ = a0 - a1 + a1 w^6 is the
+# image of a0 + a1 u.  The slope there is lam~ w^-1 with lam the plain
+# Fq2 slope, so the line through R at P = (xP, yP) is
+#     -yP + (xP lam)~ w^-1 + (yR - lam xR)~ w^-3,
+# and the point update is the affine G2 step over Fq2 with the same lam.
+# Each w^-j is A w^k + B w^(k+6): w^-1 at w^5, w^11; w^-2 at w^4, w^10
+# (a vertical line's -xR~ w^-2); w^-3 at w^3, w^9.
+
+def _w_inv(j):
+    """(k, A R, B R mod Q) of w^-j = A w^k + B w^(k+6): the coefficients
+    in Montgomery form, so a line's coefficients come out in it."""
+    x = host.fq12_inv(tuple(int(i == j) for i in range(12)))
+    k = next(i for i, c in enumerate(x) if c)
+    assert k < 6 and all(c == 0 for i, c in enumerate(x)
+                         if i not in (k, k + 6))
+    return k, x[k] * F.R % Q, x[k + 6] * F.R % Q
+
+
+_W1_INV, _W2_INV, _W3_INV = _w_inv(1), _w_inv(2), _w_inv(3)
+_IDENTITY_ROW = F.to_limbs(F.R_MOD_Q)
+
+
+def _put(vals, at, w, a):
+    """Set the Montgomery coefficients of a~ * w^-j (``w`` = _w_inv(j))
+    at flat offset ``at``: (c0 + c1 w^6)(A w^k + B w^(k+6)) with
+    w^(k+12) = 2 w^(k+6) - 2 w^k."""
+    k, ar, br = w
+    a0, a1 = a
+    c0 = a0 - a1
+    vals[at + k] = (c0 * ar - 2 * a1 * br) % Q
+    vals[at + k + 6] = (c0 * br + a1 * (ar + 2 * br)) % Q
+
+
+def _lines_step(pts, others, ps, vals, at):
+    """One Miller step for every pair in lockstep: the line through
+    pts[j] and others[j] (the tangent where they are equal) evaluated at
+    ps[j] into ``vals`` at ``at[j]``, and pts[j] moved to their sum.
+    The cases are host._linefunc's and the outcomes host._add's: distinct
+    x, tangent, and vertical (x equal, y not: the line xP - xR, the sum
+    the identity).  The slopes' Fq2 denominators share ONE Fq inversion
+    (Montgomery's trick over their norms)."""
+    slopes = []   # (j, numerator, denominator) over Fq2
+    for j, (p1, p2) in enumerate(zip(pts, others)):
+        (x1, y1), (x2, y2) = p1, p2
+        if x1 != x2:
+            slopes.append((j, host.fq2_sub(y2, y1), host.fq2_sub(x2, x1)))
+        elif y1 == y2:
+            sq = host.fq2_mul(x1, x1)
+            slopes.append((j, (3 * sq[0] % Q, 3 * sq[1] % Q),
+                           host.fq2_add(y1, y1)))
         else:
-            out[i, 1] = host_fq12_to_mont_limbs(one)
+            vals[at[j]] = ps[j][0] * F.R % Q
+            _put(vals, at[j], _W2_INV, host.fq2_neg(x1))
+            pts[j] = None
+    if not slopes:
+        return
+    # prefix products of the norms, one pow, then back to front
+    norms = [(d[0] * d[0] + d[1] * d[1]) % Q for _, _, d in slopes]
+    prefix = [1]
+    for n in norms:
+        prefix.append(prefix[-1] * n % Q)
+    inv = pow(prefix[-1], -1, Q)
+    for i in range(len(slopes) - 1, -1, -1):
+        j, num, (d0, d1) = slopes[i]
+        ninv = inv * prefix[i] % Q
+        inv = inv * norms[i] % Q
+        lam = host.fq2_mul(num, (d0 * ninv % Q, -d1 * ninv % Q))
+        (x1, y1), (x2, _) = pts[j], others[j]
+        xp, yp = ps[j]
+        mu = host.fq2_sub(y1, host.fq2_mul(lam, x1))
+        vals[at[j]] = -yp * F.R % Q
+        _put(vals, at[j], _W1_INV, (xp * lam[0] % Q, xp * lam[1] % Q))
+        _put(vals, at[j], _W3_INV, mu)
+        nx = host.fq2_sub(host.fq2_sub(host.fq2_mul(lam, lam), x1), x2)
+        pts[j] = (nx, host.fq2_sub(host.fq2_mul(lam, host.fq2_sub(x1, nx)),
+                                   y1))
+
+
+def miller_lines_many(pairs):
+    """The Miller lines of several (G1 point, G2 point) pairs in one
+    lockstep pass: (len(pairs), N_STEPS, 2, 12, 48) Montgomery limbs.
+    Slot 0 of a step is the doubling line,
+    slot 1 the addition line (identity 1 on clear bits so the device
+    body is uniform).  The values are those of the host reference's loop
+    on the untwisted point (host._linefunc, host._add over Fq12), computed
+    on the twist over Fq2 with one inversion a step for all pairs."""
+    n = len(pairs)
+    ps = [p for p, _ in pairs]
+    qs = [q for _, q in pairs]
+    pts = list(qs)
+    row = N_STEPS * 2 * 12
+    vals = [0] * (n * row)
+    for i, bit in enumerate(_BITS):
+        at = [j * row + i * 24 for j in range(n)]
+        _lines_step(pts, pts, ps, vals, at)
+        if bit:
+            _lines_step(pts, qs, ps, vals, [a + 12 for a in at])
+    flat = [k for k, v in enumerate(vals) if v]
+    out = np.zeros((n * row, F.NLIMBS), np.int32)
+    out[flat] = np.frombuffer(
+        b"".join(vals[k].to_bytes(F.NLIMBS, "little") for k in flat),
+        np.uint8).reshape(-1, F.NLIMBS)
+    out = out.reshape(n, N_STEPS, 2, 12, F.NLIMBS)
+    out[:, [i for i, bit in enumerate(_BITS) if not bit], 1, 0] = \
+        _IDENTITY_ROW
     return out
+
+
+def miller_lines(p_g1, q_g2) -> np.ndarray:
+    """The Miller lines of one pair: (N_STEPS, 2, 12, 48) Montgomery
+    limbs (``miller_lines_many``)."""
+    return miller_lines_many([(p_g1, q_g2)])[0]
 
 
 # Frobenius matrices: FROB[k][i] = (w^i)^(q^k) as a host Fq12 element, so
@@ -299,9 +397,8 @@ def verify_common_apk(apk, msg: bytes, agg_sig, trace=NO_LAUNCH) -> bool:
     with trace.stage("hash_to_g2"):
         h = host.hash_to_g2(msg)
     with trace.stage("miller_lines") as tags:
-        lines = np.stack([miller_lines(apk, h),
-                          miller_lines(host.g1_neg(host.g1_generator()),
-                                       agg_sig)])
+        lines = miller_lines_many(
+            [(apk, h), (host.g1_neg(host.g1_generator()), agg_sig)])
         if tags is not None:
             tags["pairings"] = len(lines)
     with trace.stage("pairing") as tags:
@@ -337,13 +434,13 @@ def multi_pairing_rows(pks, msgs, agg_sig):
         return None
     if agg_sig is None or not host.g2_on_curve(agg_sig):
         return None
-    rows = []
+    pairs = []
     for pk, msg in zip(pks, msgs):
         if pk is None or not host.g1_on_curve(pk):
             return None
-        rows.append(miller_lines(pk, host.hash_to_g2(msg)))
-    rows.append(miller_lines(host.g1_neg(host.g1_generator()), agg_sig))
-    return rows
+        pairs.append((pk, host.hash_to_g2(msg)))
+    pairs.append((host.g1_neg(host.g1_generator()), agg_sig))
+    return list(miller_lines_many(pairs))
 
 
 def verify_aggregate_multi(pks, msgs, agg_sig) -> bool:

@@ -5,6 +5,8 @@ reference (offchain/bls12381.py).
 """
 
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -157,6 +159,164 @@ def test_miller_lines_match_host_miller():
     f_dev = from_dev(D.miller_accumulate(jnp.asarray(lines)[None]))
     f_host = host.miller_loop(host._twist(sig), host._cast_g1_fq12(pk))
     assert f_dev == host.fq12_inv(f_host)  # host returns the inverse
+
+
+def fq12_miller_lines(p_g1, q_g2) -> np.ndarray:
+    """The oracle: the Miller lines by the host reference's loop on the
+    untwisted point over Fq12 (two Fq12 inversions a step), each line's
+    coefficients in Montgomery limbs."""
+    qt = host._twist(q_g2)
+    pf = host._cast_g1_fq12(p_g1)
+    rpt = qt
+    out = np.zeros((D.N_STEPS, 2, 12, F.NLIMBS), np.int32)
+    for i, bit in enumerate(D._BITS):
+        out[i, 0] = D.host_fq12_to_mont_limbs(host._linefunc(rpt, rpt, pf))
+        rpt = host._add(rpt, rpt, host._fq12)
+        if bit:
+            out[i, 1] = D.host_fq12_to_mont_limbs(
+                host._linefunc(rpt, qt, pf))
+            rpt = host._add(rpt, qt, host._fq12)
+        else:
+            out[i, 1] = D.host_fq12_to_mont_limbs(host.FQ12_ONE)
+    return out
+
+
+@pytest.fixture(scope="module")
+def qc67():
+    """A 67-vote certificate of a seeded 100-validator committee: the
+    aggregate key, the digest, its hash to G2 and the votes' aggregate."""
+    rng = np.random.default_rng(67)
+    committee = [host.key_gen(b"validator %d" % i) for i in range(100)]
+    signers = rng.choice(100, 67, replace=False)
+    digest = bytes(rng.bytes(32))
+    h = host.hash_to_g2(digest)
+    apk = D.aggregate_keys([committee[i][1] for i in signers])
+    agg = host.aggregate([host.g2_mul(h, committee[i][0]) for i in signers])
+    return apk, digest, h, agg
+
+
+def line_pair(case, qc67):
+    apk, _, h, agg = qc67
+    if case == "apk_h":
+        return apk, h
+    if case == "neg_g1_agg":
+        return host.g1_neg(host.g1_generator()), agg
+    if case == "generators":
+        return host.g1_generator(), host.g2_generator()
+    rng = np.random.default_rng(4242)
+    k, k2 = (int.from_bytes(rng.bytes(32), "little") for _ in range(2))
+    return (host.g1_mul(host.g1_generator(), k),
+            host.g2_mul(host.g2_generator(), k2))
+
+
+@pytest.mark.parametrize(
+    "case", ["apk_h", "neg_g1_agg", "generators", "random"])
+def test_twist_lines_are_the_fq12_lines(case, qc67):
+    """The lines computed on the twist over Fq2 are the reference loop's
+    over Fq12, limb for limb: alone, and in a lockstep pass beside the
+    certificate's other pairing."""
+    p, q = line_pair(case, qc67)
+    want = fq12_miller_lines(p, q)
+    got = D.miller_lines(p, q)
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+    partner = (host.g1_neg(host.g1_generator()), qc67[3])
+    both = D.miller_lines_many([(p, q), partner])
+    assert np.array_equal(both[0], want)
+    assert np.array_equal(both[1], D.miller_lines(*partner))
+
+
+@contextlib.contextmanager
+def counted_inversions():
+    """Count the Fq inversions (``pow(x, -1, Q)``) the module takes."""
+    calls = []
+
+    def counting(base, exp, mod=None):
+        calls.append(exp)
+        return pow(base, exp, mod)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(D, "pow", counting, raising=False)
+        yield calls
+
+
+def test_the_lockstep_pass_halves_the_inversions(qc67):
+    """One Fq inversion a step for both pairings of a certificate (63
+    doublings + 5 additions = 68), twice that in two passes; and
+    ``verify_common_apk`` stages the lockstep pass's lines."""
+    apk, digest, h, agg = qc67
+    pairs = [(apk, h), (host.g1_neg(host.g1_generator()), agg)]
+    with counted_inversions() as shared:
+        lines = D.miller_lines_many(pairs)
+    with counted_inversions() as alone:
+        for pair in pairs:
+            D.miller_lines_many([pair])
+    assert set(shared) == set(alone) == {-1}
+    assert len(shared) == D.N_STEPS + sum(D._BITS) == 68
+    assert len(alone) == 2 * len(shared) == 136
+
+    class Stages:
+        tags = {}
+
+        @contextlib.contextmanager
+        def stage(self, name):
+            yield self.tags.setdefault(name, {})
+
+    staged = []
+
+    def program(x):
+        staged.append(np.asarray(x))
+        return np.True_
+
+    trace = Stages()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(D, "pairings_check_jit", program)
+        with counted_inversions() as taken:
+            assert D.verify_common_apk(apk, digest, agg, trace=trace)
+    assert trace.tags["miller_lines"] == {"pairings": 2}
+    assert taken == [-1] * 68
+    assert np.array_equal(staged[0], lines)
+
+
+def twist_step(p1, p2, p_g1):
+    """One ``_lines_step`` of one pair: (the line's 12 Montgomery
+    coefficients, the new point, the inversions taken)."""
+    pts, vals = [p1], [0] * 12
+    with counted_inversions() as taken:
+        D._lines_step(pts, [p2], [p_g1], vals, [0])
+    return vals, pts[0], len(taken)
+
+
+@pytest.mark.parametrize("case", ["distinct", "tangent", "vertical"])
+def test_twist_step_branches_match_the_fq12_step(case):
+    """Each of host._linefunc's cases on hand-made affine points: the
+    twist step's line is the Fq12 line and its point, untwisted, is
+    host._add's (the identity for a vertical line)."""
+    q = host.g2_mul(host.g2_generator(), 5)
+    other = {"distinct": host.g2_mul(host.g2_generator(), 7),
+             "tangent": q, "vertical": host.g2_neg(q)}[case]
+    p = host.g1_mul(host.g1_generator(), 11)
+    vals, new, taken = twist_step(q, other, p)
+    qt, ot, pf = host._twist(q), host._twist(other), host._cast_g1_fq12(p)
+    assert vals == [c * F.R % F.Q for c in host._linefunc(qt, ot, pf)]
+    assert host._twist(new) == host._add(qt, ot, host._fq12)
+    assert taken == (0 if case == "vertical" else 1)
+    assert (new is None) == (case == "vertical")
+
+
+def test_twist_step_shares_one_inversion_beside_a_vertical_line():
+    """Three pairs in one step: a vertical line takes no inversion, the
+    other two share one, and each pair's line is what it reads alone."""
+    g2 = host.g2_generator()
+    q, p = host.g2_mul(g2, 5), host.g1_mul(host.g1_generator(), 11)
+    pts = [q, q, q]
+    others = [host.g2_neg(q), q, host.g2_mul(g2, 7)]
+    vals = [0] * 36
+    with counted_inversions() as taken:
+        D._lines_step(pts, others, [p] * 3, vals, [0, 12, 24])
+    assert len(taken) == 1
+    for j in range(3):
+        line, new, _ = twist_step(q, others[j], p)
+        assert vals[12 * j:12 * j + 12] == line and pts[j] == new
 
 
 @pytest.mark.slow  # ~4 min XLA compile
